@@ -23,11 +23,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     quick = not args.full
 
-    # Persistent XLA cache: repeat benchmark invocations (CI, sweeps) pay
-    # the engine's compile wall once per jax version instead of per run.
-    from repro.core import compile_cache
-    compile_cache.enable()
-
     from benchmarks import (consolidation_bench, energy_overhead,
                             ensemble_bench, microbench_steps, pareto_bench,
                             roofline, scaling, sched_bench, sharing_perf,

@@ -35,7 +35,7 @@ def run(quick=True) -> list[dict]:
     params = engine.stack_params(points)
 
     # First call: trace + compile + run.  With the persistent XLA cache
-    # enabled (REPRO_XLA_CACHE_DIR / benchmarks.run) and populated this is
+    # (on from the engine import, DESIGN.md §7) populated, this is
     # already a disk hit; either way it is what a fresh process pays.
     t0 = time.time()
     res = engine.simulate_batch(spec, trace, params)

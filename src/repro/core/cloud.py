@@ -51,8 +51,7 @@ def cloud_info(spec: CloudSpec, params: CloudParams, st: CloudState,
     running = st.pstate == PM_RUNNING
     hosted = st.vstage != mc.VM_FREE
     queued = (st.task_state == TASK_PENDING) & (trace.arrival <= st.t)
-    per_pm_vms = jax.ops.segment_sum(
-        hosted.astype(jnp.int32), st.vm_host, num_segments=P)
+    per_pm_vms = mc.vms_per_pm(hosted, st.vm_host, P)
     total_cores = pm_cores * P
     running_cores = float(jnp.sum(jnp.where(running, pm_cores, 0.0)))
     used = jnp.where(running, pm_cores - st.free_cores, 0.0)

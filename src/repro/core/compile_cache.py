@@ -1,94 +1,45 @@
 """Persistent XLA compilation cache wiring (DESIGN.md §7).
 
 The engine's one-compile-many-scenarios design (DESIGN.md §1) moves the
-cost wall from *running* sweeps to *compiling* them: an 8-point
-``simulate_batch`` sweep traces one big ``lax.while_loop`` program whose
-XLA compile takes minutes on a laptop CPU while the run itself takes
-seconds.  The compile is pure function of the HLO, so it should be paid
-once per (jax version, program) — not once per process.
+cost wall from *running* sweeps to *compiling* them, and a compile is a
+pure function of the program, so it should be paid once per (jax version,
+program) — not once per process.  :mod:`repro.core.engine` therefore calls
+:func:`enable` on import:
 
-This module is the single switch that turns on jax's persistent
-compilation cache for every repro entry point:
+* where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and this
+  module sets no other directory;
+* otherwise the cache lives in ``.jax_cache`` at the root of the checkout
+  (listed in ``.gitignore``) — one fixed path, because the directory is
+  part of what a cache hit needs.
 
-* :func:`enable` — point jax at an on-disk cache directory and lower the
-  ``jax_persistent_cache_min_*`` thresholds so the engine's executables
-  (the only multi-second compiles in this codebase) are always persisted.
-  Idempotent; safe to call before or after other jax configuration.
-* :func:`enable_from_env` — opt-in hook: a no-op unless
-  ``REPRO_XLA_CACHE_DIR`` is exported.  :mod:`repro.core.engine` calls it
-  on import, so *any* process (pytest, a notebook, an experiment script)
-  gets cross-process cache hits by setting one environment variable.
-* ``benchmarks/run.py`` calls :func:`enable` unconditionally (opt out
-  with ``REPRO_XLA_CACHE=0``), and CI persists the cache directory across
-  workflow runs via ``actions/cache`` keyed on the jax version — see
-  ``.github/workflows/ci.yml`` and docs/experiments.md §"Persistent
-  compilation cache".
-
-With a warm cache a recompile request (e.g. a fresh process, or
-``jax.clear_caches()``) is served by deserializing the stored executable:
-the sweep's minutes-long compile wall drops to the trace+lower time
-(seconds).  ``benchmarks/sweep_bench.py`` measures and reports both walls
-separately (``cold_compile_wall_s`` vs ``warm_compile_wall_s``).
+``JAX_ENABLE_COMPILATION_CACHE=false`` turns the cache off (cold-compile
+timings).  jax's own persistence thresholds apply: executables that took
+at least a second to compile are written, i.e. every engine program and
+none of the trivial helper jits.
 """
 from __future__ import annotations
 
 import os
+import pathlib
 
-ENV_DIR = "REPRO_XLA_CACHE_DIR"
-ENV_TOGGLE = "REPRO_XLA_CACHE"
-
-_enabled_dir: str | None = None
-
-
-def default_dir() -> str:
-    """``$REPRO_XLA_CACHE_DIR`` if exported, else ``~/.cache/repro-xla``."""
-    return os.environ.get(ENV_DIR) or os.path.join(
-        os.path.expanduser("~"), ".cache", "repro-xla")
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def enable(cache_dir: str | None = None, *,
-           min_compile_secs: float = 1.0,
-           min_entry_bytes: int = 0) -> str | None:
-    """Turn on jax's persistent compilation cache at ``cache_dir``.
-
-    Returns the active cache directory (or ``None`` when disabled via
-    ``REPRO_XLA_CACHE=0``).  The ``min_*`` knobs are jax's persistence
-    thresholds: entries cheaper than ``min_compile_secs`` of compile time
-    or smaller than ``min_entry_bytes`` are not written.  The defaults
-    persist everything that takes >= 1 s to compile — i.e. every engine
-    executable, but not the trivial helper jits.
-    """
-    global _enabled_dir
-    if os.environ.get(ENV_TOGGLE, "1").lower() in ("0", "false", "off"):
-        return None
-    cache_dir = cache_dir or default_dir()
-    if _enabled_dir == cache_dir:
-        return cache_dir
-
+def enable() -> str:
+    """Turn jax's persistent compilation cache on at the directory chosen
+    above and return it.  Idempotent."""
+    env_dir = os.environ.get(ENV_DIR)
+    if env_dir:
+        return env_dir
     import jax
-
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      float(min_compile_secs))
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                      int(min_entry_bytes))
-    _enabled_dir = cache_dir
-    return cache_dir
-
-
-def enable_from_env() -> str | None:
-    """Opt-in activation: :func:`enable` iff ``REPRO_XLA_CACHE_DIR`` is set.
-
-    Called by :mod:`repro.core.engine` at import time so the cache needs
-    no code change to adopt — export the variable and every jitted engine
-    entry point in the process shares the on-disk cache.
-    """
-    if os.environ.get(ENV_DIR):
-        return enable()
-    return None
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_DIR))
+    return str(CHECKOUT_DIR)
 
 
 def active_dir() -> str | None:
-    """The directory :func:`enable` configured, or ``None``."""
-    return _enabled_dir
+    """The directory jax caches compiles in, or ``None`` when it is off."""
+    import jax
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    return jax.config.jax_compilation_cache_dir or None

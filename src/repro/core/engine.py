@@ -84,10 +84,9 @@ from .loop.state import (BIG as _BIG, KIND_MIGRATE, TASK_ACTIVE, TASK_DONE,
                          TASK_PENDING, TASK_REJECTED, CloudState)
 from repro.sched import registry as _policy_registry
 
-# Opt-in persistent XLA cache (REPRO_XLA_CACHE_DIR): makes the first
-# engine compile of a process a disk hit instead of a multi-minute trace
-# (DESIGN.md §7).  A no-op unless the env var is set.
-_compile_cache.enable_from_env()
+# Persistent XLA cache (DESIGN.md §7): the first engine compile of a
+# process is a disk hit when an earlier process compiled the same program.
+_compile_cache.enable()
 
 __all__ = [
     "CloudSpec", "CloudParams", "CloudState", "CloudResult", "Trace",

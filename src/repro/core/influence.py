@@ -13,6 +13,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .machine import vms_per_pm
+
 _BIG = jnp.int32(2**30)
 
 
@@ -88,6 +90,5 @@ def coupled_vm_counts(
     ``(in_group bool[V], vms_on_host i32[P])``.
     """
     in_group = same_group(labels, host_cpu, vm_spreader)
-    vms_on_host = jax.ops.segment_sum(
-        in_group.astype(jnp.int32), vm_host, num_segments=n_pm)
+    vms_on_host = vms_per_pm(in_group, vm_host, n_pm)
     return in_group, vms_on_host

@@ -66,9 +66,7 @@ def _eq6_views(ctx: StageCtx, st: CloudState, cpu_del: jax.Array):
     la = cpk.label_lookup(cp, labels_b, lay.cpu0 + vmh_b)
     lb = cpk.label_lookup(cp, labels_b, lay.vm0 + v_c)
     in_grp_b = is_vm & (la == lb)
-    vms_on_host = jax.ops.segment_sum(
-        in_grp_b.astype(jnp.int32), jnp.where(is_vm, vmh_b, P),
-        num_segments=P)
+    vms_on_host = mc.vms_per_pm(in_grp_b, jnp.where(is_vm, vmh_b, P), P)
     r_b = cpk.gather_flows(cp, r, 0.0)
     frac_b = (jnp.where(in_grp_b, r_b, 0.0)
               / jnp.maximum(cpu_del[vmh_b], 1e-30))

@@ -83,6 +83,19 @@ def pm_accepting(pstate: jnp.ndarray) -> jnp.ndarray:
     return pstate == PM_RUNNING
 
 
+def vms_per_pm(mask: jnp.ndarray, vm_host: jnp.ndarray,
+               n_pm: int) -> jnp.ndarray:
+    """i32[P] — how many VM slots selected by ``mask`` each PM hosts; a
+    host index outside ``[0, n_pm)`` counts nowhere.
+
+    A one-hot compare-and-sum, not a ``segment_sum``: the TPU compiler
+    aborts (``scatter_emitter`` operand check) on a scatter whose indices
+    and updates are one buffer — what a count over the initial state folds
+    to, where every slot is free and every ``vm_host`` is 0."""
+    on = vm_host[None, :] == jnp.arange(n_pm, dtype=vm_host.dtype)[:, None]
+    return jnp.sum(on & mask[None, :], axis=1, dtype=jnp.int32)
+
+
 def pm_future_capacity(pstate: jnp.ndarray) -> jnp.ndarray:
     """PMs that will be able to serve soon (running or booting) — used by the
     on-demand PM scheduler to decide whether more machines must be woken."""

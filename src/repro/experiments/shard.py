@@ -38,7 +38,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import engine
@@ -114,8 +113,8 @@ def _sharded_runner(spec, devs, treedef, flags):
         # compaction-bucket overflow (DESIGN.md §7)
         return engine._simulate_batch_jit(spec, trace, params, t_stop)
 
-    fn = shard_map(run, mesh=mesh, in_specs=(in_specs, P()),
-                   out_specs=(P("batch"), P("batch")), check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=(in_specs, P()),
+                       out_specs=(P("batch"), P("batch")), check_vma=False)
     return jax.jit(fn)
 
 
@@ -176,9 +175,10 @@ def _stream_runner(spec, devs, treedef, flags):
     if len(devs) > 1:
         mesh = Mesh(np.asarray(devs), ("batch",))
         pspecs = treedef.unflatten([P("batch") if f else P() for f in flags])
-        vstep = shard_map(vstep, mesh=mesh,
-                          in_specs=(P("batch"), P(), pspecs, P(), P(), P()),
-                          out_specs=P("batch"), check_rep=False)
+        vstep = jax.shard_map(
+            vstep, mesh=mesh,
+            in_specs=(P("batch"), P(), pspecs, P(), P(), P()),
+            out_specs=P("batch"), check_vma=False)
     return jax.jit(vstep, donate_argnums=(0,))
 
 

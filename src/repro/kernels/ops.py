@@ -1,21 +1,15 @@
 """Jitted public wrappers for the Pallas kernels.
 
-Each op auto-selects ``interpret=True`` off-TPU (this container is
-CPU-only; interpret mode executes the kernel body faithfully) and compiles
-via Mosaic on real TPUs.  ``FORCE_INTERPRET`` can be toggled for tests.
+Each op compiles via Mosaic on a TPU and runs the kernel body in interpret
+mode on any other backend (the CPU test suite); tests that pin a mode pass
+``interpret=`` to the kernel itself.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
-
-FORCE_INTERPRET: bool | None = None
 
 
 def _interpret() -> bool:
-    if FORCE_INTERPRET is not None:
-        return FORCE_INTERPRET
     return jax.default_backend() != "tpu"
 
 

@@ -13,7 +13,6 @@ configurations of the queue-serving machinery in
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import machine as mc
@@ -45,9 +44,7 @@ def wake_sleep_pass(spec, params, trace, st: CloudState) -> CloudState:
     off = st.pstate == PM_OFF
     wake = off & (jnp.cumsum(off.astype(jnp.int32)) <= k)
     # loadless running PMs sleep only when nothing is queued
-    hosted = jax.ops.segment_sum(
-        (st.vstage != mc.VM_FREE).astype(jnp.int32), st.vm_host,
-        num_segments=P)
+    hosted = mc.vms_per_pm(st.vstage != mc.VM_FREE, st.vm_host, P)
     idle = ((st.pstate == PM_RUNNING) & (hosted == 0) & ~queued.any())
 
     boot_s = table.duration[PM_SWITCHING_ON]
@@ -122,9 +119,7 @@ def _wake_sleep_trigger(spec, params, ctx, st):
     :func:`wake_sleep_pass` selects the old value (``wake``/``idle`` all
     False), so skipping is bitwise identity."""
     queued = (st.task_state == TASK_PENDING) & (ctx.trace.arrival <= st.t)
-    hosted = jax.ops.segment_sum(
-        (st.vstage != mc.VM_FREE).astype(jnp.int32), st.vm_host,
-        num_segments=spec.n_pm)
+    hosted = mc.vms_per_pm(st.vstage != mc.VM_FREE, st.vm_host, spec.n_pm)
     loadless = (st.pstate == PM_RUNNING) & (hosted == 0)
     return queued.any() | loadless.any()
 

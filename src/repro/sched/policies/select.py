@@ -8,7 +8,6 @@ the policies.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import machine as mc
@@ -23,8 +22,7 @@ def host_load_facts(spec, params, st: CloudState):
     running = st.pstate == PM_RUNNING
     used = jnp.asarray(params.pm_cores, jnp.float32) - st.free_cores
     movable = st.vstage == mc.VM_RUNNING
-    n_movable = jax.ops.segment_sum(movable.astype(jnp.int32), st.vm_host,
-                                    num_segments=spec.n_pm)
+    n_movable = mc.vms_per_pm(movable, st.vm_host, spec.n_pm)
     return running, used, movable, n_movable
 
 

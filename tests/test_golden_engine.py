@@ -48,7 +48,17 @@ def golden():
     assert FIXTURE.exists(), (
         f"{FIXTURE} missing — generate with tools/make_golden.py")
     with np.load(FIXTURE) as z:
-        return {k: z[k] for k in z.files}
+        arrays = {k: z[k] for k in z.files}
+    pre = make_golden.ENV_PREFIX
+    recorded = {k[len(pre):]: str(v) for k, v in arrays.items()
+                if k.startswith(pre)}
+    live = make_golden.environment()
+    assert recorded == live, (
+        f"golden fixture was recorded under {recorded or 'unknown versions'}"
+        f" but this run uses {live}: float bit patterns are only comparable "
+        f"within one jax/jaxlib/backend — re-baseline with "
+        f"tools/make_golden.py after checking the drift is numerics only")
+    return {k: v for k, v in arrays.items() if not k.startswith(pre)}
 
 
 @pytest.mark.parametrize("name,fn", make_golden.scenarios())

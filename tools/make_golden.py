@@ -9,12 +9,17 @@ array *bitwise* (float leaves compared by bit pattern, integer leaves by
 value), which is the regression harness behind the PR 4-6 "optimise
 without changing a single bit" protocol (DESIGN.md §7).
 
-Run it ONLY to re-baseline after an *intentional* semantic change:
+Run it ONLY to re-baseline after an *intentional* semantic change, or
+after a jax/jaxlib upgrade (XLA may reorder a reduction, which moves the
+low bits of a float):
 
-    PYTHONPATH=src python tools/make_golden.py
+    PYTHONPATH=src JAX_PLATFORMS=cpu python tools/make_golden.py
 
 and say so in the commit message — a diff in this file's output that is
-not accompanied by an intended semantics change is a bug.
+not accompanied by an intended semantics change or a version bump is a
+bug.  Bit patterns are only comparable under one jax/jaxlib/backend, so
+the fixture records them (:func:`environment`) and the replay refuses a
+mismatch with a message that says so.
 """
 from __future__ import annotations
 
@@ -146,8 +151,19 @@ def flatten_result(name: str, res) -> dict[str, np.ndarray]:
     return flat
 
 
+def environment() -> dict[str, str]:
+    """The versions and backend the fixture's bits belong to."""
+    import jaxlib
+    return {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+            "backend": jax.default_backend()}
+
+
+ENV_PREFIX = "_env."
+
+
 def main() -> int:
-    arrays = {}
+    arrays = {ENV_PREFIX + k: np.asarray(v)
+              for k, v in environment().items()}
     for name, fn in scenarios():
         _spec, res = fn()
         jax.block_until_ready(res.t_end)
