@@ -16,10 +16,6 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default="",
                     help="comma list of module names to run")
     ap.add_argument("--out", default="experiments/bench")
-    ap.add_argument("--profile", default="", metavar="DIR",
-                    help="capture a jax.profiler trace of each module into "
-                         "DIR (open in Perfetto: ui.perfetto.dev, or "
-                         "tensorboard --logdir DIR)")
     args = ap.parse_args(argv)
     quick = not args.full
 
@@ -50,20 +46,10 @@ def main(argv=None) -> int:
     outdir = Path(args.out)
     outdir.mkdir(parents=True, exist_ok=True)
     failures = 0
-    if args.profile:
-        import jax
-        Path(args.profile).mkdir(parents=True, exist_ok=True)
     for name, mod in modules.items():
         t0 = time.time()
         try:
-            if args.profile:
-                # one Perfetto-viewable trace per module: compile wall and
-                # per-iteration device ops land in separate lanes, so the
-                # event-loop hot path is readable at a glance
-                with jax.profiler.trace(str(Path(args.profile) / name)):
-                    rows = mod.run(quick=quick)
-            else:
-                rows = mod.run(quick=quick)
+            rows = mod.run(quick=quick)
             status = "ok"
         except Exception:
             rows = [{"error": traceback.format_exc()[-2000:]}]

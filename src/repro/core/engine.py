@@ -75,13 +75,15 @@ import numpy as np
 from . import compile_cache as _compile_cache
 from . import loop
 from . import machine as mc
+from . import tracing
 from .energy import (PM_OFF, PM_RUNNING, PM_SWITCHING_OFF, PM_SWITCHING_ON,
                      MeterParams, MeterState, MeterTopology, PowerStateTable,
                      meter_readings)
 from .fairshare import SCHEDULERS
 from .loop.migrate import migrate_one
 from .loop.state import (BIG as _BIG, KIND_MIGRATE, TASK_ACTIVE, TASK_DONE,
-                         TASK_PENDING, TASK_REJECTED, CloudState)
+                         TASK_PENDING, TASK_REJECTED, CloudState,
+                         LoopCounters)
 from repro.sched import registry as _policy_registry
 
 # Persistent XLA cache (DESIGN.md §7): the first engine compile of a
@@ -94,7 +96,7 @@ __all__ = [
     "simulate_batch", "simulate_batch_sharded", "start_migration",
     "make_allocation", "VM_SCHEDULERS", "PM_SCHEDULERS",
     "StreamCarry", "StreamResult", "simulate_stream", "init_stream",
-    "default_n_slots",
+    "default_n_slots", "LoopCounters",
 ]
 
 
@@ -314,6 +316,7 @@ class CloudResult(NamedTuple):
     n_events: jax.Array
     t_end: jax.Array
     overflow: jax.Array
+    counters: LoopCounters  # the loop's round and event-gate counts
 
     def readings(self, spec: "CloudSpec") -> dict[str, jax.Array]:
         """Named energy readings of the stack (see
@@ -400,12 +403,11 @@ def _simulate_impl(spec: CloudSpec, trace: Trace, params: CloudParams,
     t_stop = jnp.asarray(t_stop, jnp.float32)
 
     def cond(carry):
-        st, _ok = carry
-        return st.running & (st.n_events < spec.max_events)
+        return carry[0].running & (carry[0].n_events < spec.max_events)
 
-    st, ok = jax.lax.while_loop(
+    st, ok, counters = jax.lax.while_loop(
         cond, loop.make_body(spec, params, trace, t_stop),
-        (st0, jnp.bool_(True)))
+        (st0, jnp.bool_(True), LoopCounters.zero()))
     return CloudResult(
         state=st,
         completion=st.t_done,
@@ -416,6 +418,7 @@ def _simulate_impl(spec: CloudSpec, trace: Trace, params: CloudParams,
         n_events=st.n_events,
         t_end=st.t,
         overflow=st.overflow,
+        counters=counters,
     ), ok
 
 
@@ -448,6 +451,13 @@ def _warn_dense_rerun(spec: CloudSpec):
         RuntimeWarning, stacklevel=3)
 
 
+def _checked_rerun(spec: CloudSpec, ok) -> bool:
+    """:func:`_needs_dense_rerun` under its host span: reading the flag is
+    where the host waits for the device."""
+    with tracing.span(tracing.COMPACT_CHECK):
+        return _needs_dense_rerun(spec, ok)
+
+
 @functools.partial(jax.jit, static_argnames=("spec",),
                    donate_argnames=("state",))
 def _simulate_jit(spec: CloudSpec, trace: Trace,
@@ -470,15 +480,19 @@ def simulate(spec: CloudSpec, trace: Trace,
     active-set compaction up front — bit-identical either way (DESIGN.md
     §7).
     """
-    if params is None:
-        params = CloudParams.for_spec(spec)
-    if state is not None:
-        spec = dense_spec(spec)
-    res, ok = _simulate_jit(spec, trace, params, state, t_stop)
-    if _needs_dense_rerun(spec, ok):
-        _warn_dense_rerun(spec)
-        res, _ = _simulate_jit(dense_spec(spec), trace, params, None, t_stop)
-    return res
+    with tracing.entry("simulate"):
+        if params is None:
+            params = CloudParams.for_spec(spec)
+        if state is not None:
+            spec = dense_spec(spec)
+        with tracing.span(tracing.LAUNCH):
+            res, ok = _simulate_jit(spec, trace, params, state, t_stop)
+        if _checked_rerun(spec, ok):
+            _warn_dense_rerun(spec)
+            with tracing.span(tracing.DENSE_REPLAY):
+                res, _ = _simulate_jit(dense_spec(spec), trace, params, None,
+                                       t_stop)
+        return res
 
 
 simulate.clear_cache = _simulate_jit.clear_cache  # registry invalidation
@@ -532,11 +546,15 @@ def simulate_batch(spec: CloudSpec, trace: Trace, params: CloudParams,
     An active-set-compaction bucket overflow on any lane (DESIGN.md §7)
     replays the whole sweep with ``compact=0`` — bit-identical results.
     """
-    res, ok = _simulate_batch_jit(spec, trace, params, t_stop)
-    if _needs_dense_rerun(spec, ok):
-        _warn_dense_rerun(spec)
-        res, _ = _simulate_batch_jit(dense_spec(spec), trace, params, t_stop)
-    return res
+    with tracing.entry("simulate_batch"):
+        with tracing.span(tracing.LAUNCH):
+            res, ok = _simulate_batch_jit(spec, trace, params, t_stop)
+        if _checked_rerun(spec, ok):
+            _warn_dense_rerun(spec)
+            with tracing.span(tracing.DENSE_REPLAY):
+                res, _ = _simulate_batch_jit(dense_spec(spec), trace, params,
+                                             t_stop)
+        return res
 
 
 simulate_batch.clear_cache = _simulate_batch_jit.clear_cache
@@ -575,13 +593,15 @@ class StreamCarry(NamedTuple):
     TASK_DONE``, which makes it inert in every queue/horizon/termination
     mask.  ``compact_ok`` accumulates the active-set-compaction bucket
     check (DESIGN.md §7) across windows so the host can replay the whole
-    stream densely on overflow.  All leaves are donated to each window
-    step.
+    stream densely on overflow; ``counters`` sums the loop's
+    :class:`LoopCounters` across windows.  All leaves are donated to each
+    window step.
     """
 
     state: CloudState
     slots: Trace
     compact_ok: jax.Array
+    counters: LoopCounters
 
 
 class StreamResult(NamedTuple):
@@ -601,6 +621,9 @@ class StreamResult(NamedTuple):
     overflow: jax.Array
     window_t_end: jax.Array   # f32[n_windows] clock after each window
     window_energy: jax.Array  # f32[n_windows] total PM energy after each
+    counters: LoopCounters    # summed over windows; the deferred
+    #                           management passes make them differ from
+    #                           the monolithic run's (the answers do not)
 
     def readings(self, spec: "CloudSpec") -> dict[str, jax.Array]:
         """Named energy readings of the stack — same API as
@@ -633,7 +656,8 @@ def init_stream(spec: CloudSpec, n_slots: int,
     # *donates* the carry, and donating one buffer twice is an XLA error —
     # copy leaf-wise so every donated leaf owns its storage.
     return jax.tree.map(jnp.copy, StreamCarry(
-        state=st, slots=slots, compact_ok=jnp.bool_(True)))
+        state=st, slots=slots, compact_ok=jnp.bool_(True),
+        counters=LoopCounters.zero()))
 
 
 def _stream_step_impl(spec: CloudSpec, carry: StreamCarry, window: Trace,
@@ -662,72 +686,77 @@ def _stream_step_impl(spec: CloudSpec, carry: StreamCarry, window: Trace,
     Q = slots.n
 
     # ---- 1. insert: rank-matched scatter of valid tasks into free slots
-    free = slots.gid < 0
-    valid = window.gid >= 0
-    free_rank = jnp.cumsum(free) - 1          # each free slot's rank
-    slot_of_rank = jnp.full((Q,), Q, jnp.int32).at[
-        jnp.where(free, free_rank, Q)].set(
-        jnp.arange(Q, dtype=jnp.int32), mode="drop")
-    pos = jnp.cumsum(valid) - 1               # each incoming task's rank
-    take = valid & (pos < jnp.sum(free))
-    dest = jnp.where(take, slot_of_rank[jnp.clip(pos, 0, Q - 1)], Q)
-    slots = Trace(
-        arrival=slots.arrival.at[dest].set(window.arrival, mode="drop"),
-        cores=slots.cores.at[dest].set(window.cores, mode="drop"),
-        work=slots.work.at[dest].set(window.work, mode="drop"),
-        gid=slots.gid.at[dest].set(window.gid, mode="drop"),
-    )
-    st = st._replace(
-        task_state=st.task_state.at[dest].set(TASK_PENDING, mode="drop"),
-        task_vm=st.task_vm.at[dest].set(-1, mode="drop"),
-        t_done=st.t_done.at[dest].set(jnp.inf, mode="drop"),
-        overflow=st.overflow | jnp.any(valid & ~take),
-    )
+    with tracing.scope(tracing.STREAM_INSERT):
+        free = slots.gid < 0
+        valid = window.gid >= 0
+        free_rank = jnp.cumsum(free) - 1          # each free slot's rank
+        slot_of_rank = jnp.full((Q,), Q, jnp.int32).at[
+            jnp.where(free, free_rank, Q)].set(
+            jnp.arange(Q, dtype=jnp.int32), mode="drop")
+        pos = jnp.cumsum(valid) - 1               # each incoming task's rank
+        take = valid & (pos < jnp.sum(free))
+        dest = jnp.where(take, slot_of_rank[jnp.clip(pos, 0, Q - 1)], Q)
+        slots = Trace(
+            arrival=slots.arrival.at[dest].set(window.arrival, mode="drop"),
+            cores=slots.cores.at[dest].set(window.cores, mode="drop"),
+            work=slots.work.at[dest].set(window.work, mode="drop"),
+            gid=slots.gid.at[dest].set(window.gid, mode="drop"),
+        )
+        st = st._replace(
+            task_state=st.task_state.at[dest].set(TASK_PENDING,
+                                                  mode="drop"),
+            task_vm=st.task_vm.at[dest].set(-1, mode="drop"),
+            t_done=st.t_done.at[dest].set(jnp.inf, mode="drop"),
+            overflow=st.overflow | jnp.any(valid & ~take),
+        )
 
     # ---- 2. gated management replay
-    replay = jnp.isfinite(t_prev_next) & (st.t >= t_prev_next)
-    split = jnp.isfinite(t_next) & (st.t >= t_next)
-    stopped = jnp.isfinite(t_stop) & (st.t >= t_stop)
-    do_mp = replay & ~split
-    st_mp = loop.management_pass(spec, params, slots, st)
-    st = jax.tree.map(lambda a, b: jnp.where(do_mp, a, b), st_mp, st)
-    st = st._replace(running=do_mp & ~stopped)
+    with tracing.scope(tracing.STREAM_REPLAY):
+        replay = jnp.isfinite(t_prev_next) & (st.t >= t_prev_next)
+        split = jnp.isfinite(t_next) & (st.t >= t_next)
+        stopped = jnp.isfinite(t_stop) & (st.t >= t_stop)
+        do_mp = replay & ~split
+        st_mp = loop.management_pass(spec, params, slots, st)
+        st = jax.tree.map(lambda a, b: jnp.where(do_mp, a, b), st_mp, st)
+        st = st._replace(running=do_mp & ~stopped)
 
     # ---- 3. the staged loop up to the next hand-over
     def cond(c):
         s = c[0]
         return s.running & (s.n_events < spec.max_events)
 
-    st, compact_ok = jax.lax.while_loop(
+    st, compact_ok, counters = jax.lax.while_loop(
         cond, loop.make_body(spec, params, slots, t_stop, t_next),
-        (st, carry.compact_ok))
+        (st, carry.compact_ok, carry.counters))
 
     # ---- 4. flush terminal slots (compacted to the front), free them
-    term = ((st.task_state == TASK_DONE) | (st.task_state == TASK_REJECTED)
-            ) & (slots.gid >= 0)
-    out_idx = jnp.where(term, jnp.cumsum(term) - 1, Q)
-    out = {
-        "gid": jnp.full((Q,), -1, jnp.int32).at[out_idx].set(
-            slots.gid, mode="drop"),
-        "t_done": jnp.full((Q,), jnp.inf, jnp.float32).at[out_idx].set(
-            st.t_done, mode="drop"),
-        "rejected": jnp.zeros((Q,), bool).at[out_idx].set(
-            st.task_state == TASK_REJECTED, mode="drop"),
-        "t_end": st.t,
-        "energy": jnp.sum(st.meters.pm.energy),
-    }
-    slots = Trace(
-        arrival=jnp.where(term, jnp.inf, slots.arrival),
-        cores=jnp.where(term, 0.0, slots.cores),
-        work=jnp.where(term, 0.0, slots.work),
-        gid=jnp.where(term, -1, slots.gid),
-    )
-    st = st._replace(
-        task_state=jnp.where(term, TASK_DONE, st.task_state),
-        task_vm=jnp.where(term, -1, st.task_vm),
-        t_done=jnp.where(term, jnp.inf, st.t_done),
-    )
-    return StreamCarry(state=st, slots=slots, compact_ok=compact_ok), out
+    with tracing.scope(tracing.STREAM_FLUSH):
+        term = ((st.task_state == TASK_DONE)
+                | (st.task_state == TASK_REJECTED)) & (slots.gid >= 0)
+        out_idx = jnp.where(term, jnp.cumsum(term) - 1, Q)
+        out = {
+            "gid": jnp.full((Q,), -1, jnp.int32).at[out_idx].set(
+                slots.gid, mode="drop"),
+            "t_done": jnp.full((Q,), jnp.inf, jnp.float32).at[out_idx].set(
+                st.t_done, mode="drop"),
+            "rejected": jnp.zeros((Q,), bool).at[out_idx].set(
+                st.task_state == TASK_REJECTED, mode="drop"),
+            "t_end": st.t,
+            "energy": jnp.sum(st.meters.pm.energy),
+        }
+        slots = Trace(
+            arrival=jnp.where(term, jnp.inf, slots.arrival),
+            cores=jnp.where(term, 0.0, slots.cores),
+            work=jnp.where(term, 0.0, slots.work),
+            gid=jnp.where(term, -1, slots.gid),
+        )
+        st = st._replace(
+            task_state=jnp.where(term, TASK_DONE, st.task_state),
+            task_vm=jnp.where(term, -1, st.task_vm),
+            t_done=jnp.where(term, jnp.inf, st.t_done),
+        )
+    return StreamCarry(state=st, slots=slots, compact_ok=compact_ok,
+                       counters=counters), out
 
 
 @functools.partial(jax.jit, static_argnames=("spec",),
@@ -811,32 +840,41 @@ def simulate_stream(spec: CloudSpec, windows,
     simultaneously-live task population (default
     :func:`default_n_slots`); exhaustion sets ``overflow``.
     """
+    with tracing.entry("simulate_stream"):
+        return _simulate_stream(spec, windows, params, n_slots, t_stop)
+
+
+def _simulate_stream(spec, windows, params, n_slots, t_stop):
     if params is None:
         params = CloudParams.for_spec(spec)
     _check_meter_params(spec, params)
-    it, W = _as_window_iter(windows)
-    cur = next(iter(it), None) if W is None else next(it, None)
-    if cur is None:
-        raise ValueError("simulate_stream needs at least one window")
-    if W is None:  # generator input: first window fixes the shape
-        it, _ = _as_window_iter(_chain_one(cur, it), window_size=cur.n)
-        cur = next(it)
-    Q = default_n_slots(spec, cur.n) if n_slots is None else int(n_slots)
-    carry = init_stream(spec, Q, params)
-    t_stop = jnp.asarray(t_stop, jnp.float32)
-    # t_prev_next = 0 makes the first step run the monolithic pre-loop
-    # management pass (the clock starts at 0 >= 0).
-    t_prev_next = jnp.float32(0.0)
+    with tracing.span(tracing.STREAM_INIT):
+        it, W = _as_window_iter(windows)
+        cur = next(iter(it), None) if W is None else next(it, None)
+        if cur is None:
+            raise ValueError("simulate_stream needs at least one window")
+        if W is None:  # generator input: first window fixes the shape
+            it, _ = _as_window_iter(_chain_one(cur, it), window_size=cur.n)
+            cur = next(it)
+        Q = default_n_slots(spec, cur.n) if n_slots is None else int(n_slots)
+        carry = init_stream(spec, Q, params)
+        t_stop = jnp.asarray(t_stop, jnp.float32)
+        # t_prev_next = 0 makes the first step run the monolithic pre-loop
+        # management pass (the clock starts at 0 >= 0).
+        t_prev_next = jnp.float32(0.0)
     outs = []
     while cur is not None:
-        nxt = next(it, None)
-        t_next = (jnp.float32(jnp.inf) if nxt is None
-                  else _first_arrival(nxt))
-        carry, ys = _stream_step(spec, carry, cur, params,
-                                 t_prev_next, t_next, t_stop)
+        with tracing.span(tracing.STREAM_WINDOW):
+            with tracing.span(tracing.STREAM_NEXT_WINDOW):
+                nxt = next(it, None)
+                t_next = (jnp.float32(jnp.inf) if nxt is None
+                          else _first_arrival(nxt))
+            with tracing.span(tracing.LAUNCH):
+                carry, ys = _stream_step(spec, carry, cur, params,
+                                         t_prev_next, t_next, t_stop)
         outs.append(ys)
         t_prev_next, cur = t_next, nxt
-    if _needs_dense_rerun(spec, carry.compact_ok):
+    if _checked_rerun(spec, carry.compact_ok):
         # A window's active set outgrew the compaction bucket.  Replayable
         # inputs (WindowedTrace) restart the whole stream densely — the
         # carried state already consumed compacted windows, so a mid-stream
@@ -844,14 +882,16 @@ def simulate_stream(spec: CloudSpec, windows,
         # replayed; fail loudly rather than return silently-dense results.
         if hasattr(windows, "n_windows") and hasattr(windows, "window"):
             _warn_dense_rerun(spec)
-            return simulate_stream(dense_spec(spec), windows, params,
-                                   n_slots=Q, t_stop=t_stop)
+            with tracing.span(tracing.DENSE_REPLAY):
+                return _simulate_stream(dense_spec(spec), windows, params,
+                                        Q, t_stop)
         raise RuntimeError(
             "active-set compaction bucket overflowed mid-stream and the "
             "window source is a consumed generator that cannot be "
             "replayed; rerun with spec.compact=0 (dense) or pass a "
             "replayable WindowedTrace")
-    return _assemble_stream(spec, carry, outs)
+    with tracing.span(tracing.STREAM_ASSEMBLE):
+        return _assemble_stream(spec, carry, outs)
 
 
 def _chain_one(first, rest):
@@ -886,6 +926,7 @@ def _assemble_stream(spec: CloudSpec, carry: StreamCarry,
         overflow=st.overflow,
         window_t_end=jnp.stack([o["t_end"] for o in outs]),
         window_energy=jnp.stack([o["energy"] for o in outs]),
+        counters=carry.counters,
     )
 
 

@@ -103,7 +103,7 @@ def _jnp_fill_stats(provider, consumer, r, live, unfrozen, perf):
     return dp, dc
 
 
-def maxmin_rates(
+def maxmin_fill(
     provider: jax.Array,
     consumer: jax.Array,
     p_l: jax.Array,
@@ -113,8 +113,8 @@ def maxmin_rates(
     max_iters: int = 64,
     backend: str = "jnp",
     rel_eps: float = 1e-5,
-) -> jax.Array:
-    """Max-min fair rates by progressive filling.
+) -> tuple[jax.Array, jax.Array]:
+    """Max-min fair rates by progressive filling, and the rounds it took.
 
     All unfrozen flows rise at the same global increment until a constraint
     (provider capacity, consumer capacity, or the flow's own ``p_l``)
@@ -127,14 +127,15 @@ def maxmin_rates(
     kernel when the problem fits VMEM (``repro.kernels.maxmin.maxmin_solve``
     — the carried rate/freeze vectors never round-trip HBM between rounds),
     falling back to the round-wise Pallas ``fill_stats`` kernel above that
-    size; ``'jnp'`` uses segment_sum throughout.
+    size; ``'jnp'`` uses segment_sum throughout.  The fused kernel does not
+    report its rounds: it returns a round count of 0.
     """
     if backend == "pallas":
         from repro.kernels import ops as _kops
         if _kops.maxmin_solve_fits(provider.shape[0], perf.shape[0]):
             return _kops.maxmin_solve_pallas(
                 provider, consumer, p_l, live, perf,
-                max_iters=max_iters, rel_eps=rel_eps)
+                max_iters=max_iters, rel_eps=rel_eps), jnp.int32(0)
         fill_stats = _kops.fill_stats_pallas
     else:
         fill_stats = _jnp_fill_stats
@@ -161,18 +162,31 @@ def maxmin_rates(
         unfrozen = unfrozen & ~tight
         return i + 1, r, unfrozen
 
-    _, r, _ = jax.lax.while_loop(cond, body, (jnp.int32(0), r0, unfrozen0))
-    return jnp.where(live, r, 0.0)
+    rounds, r, _ = jax.lax.while_loop(cond, body,
+                                      (jnp.int32(0), r0, unfrozen0))
+    return jnp.where(live, r, 0.0), rounds
+
+
+def maxmin_rates(provider, consumer, p_l, live, perf, *, max_iters=64,
+                 backend="jnp", rel_eps=1e-5) -> jax.Array:
+    """Max-min fair rates by progressive filling (:func:`maxmin_fill`
+    without the round count)."""
+    return maxmin_fill(provider, consumer, p_l, live, perf,
+                       max_iters=max_iters, backend=backend,
+                       rel_eps=rel_eps)[0]
 
 
 # Low-level sharing-scheduler registry (paper §3.2.3 pluggable logic).
 # Every entry has the uniform signature
-# ``fn(provider, consumer, p_l, live, perf, *, backend, max_iters)`` so the
-# engine, the standalone sharing loop, and rates_for all select by name
-# through this one table instead of string branches.
+# ``fn(provider, consumer, p_l, live, perf, *, backend, max_iters)
+# -> (rates, rounds)`` so the engine, the standalone sharing loop, and
+# rates_for all select by name through this one table instead of string
+# branches; ``rounds`` (i32) is the solve's progressive-filling rounds,
+# which the engine counts (``LoopCounters.fill_rounds``), 0 for the
+# one-shot equal split.
 SCHEDULERS: dict[str, Callable] = {
-    "equal": equal_share_rates,
-    "maxmin": maxmin_rates,
+    "equal": lambda *a, **kw: (equal_share_rates(*a, **kw), jnp.int32(0)),
+    "maxmin": maxmin_fill,
 }
 
 
@@ -188,8 +202,8 @@ def rates_for(
     from .arrays import live_mask
 
     live = live_mask(cons, t)
-    r = SCHEDULERS[scheduler](cons.provider, cons.consumer, cons.p_l, live,
-                              perf, backend=backend)
+    r, _ = SCHEDULERS[scheduler](cons.provider, cons.consumer, cons.p_l,
+                                 live, perf, backend=backend)
     return r, live
 
 

@@ -32,6 +32,20 @@ def influence_labels(
     themselves.  ``max_rounds=0`` auto-bounds by the spreader count (the
     propagation diameter can never exceed it); each round is O(C) scatter-min.
     """
+    return influence_labels_rounds(provider, consumer, live, num_spreaders,
+                                   max_rounds=max_rounds)[0]
+
+
+def influence_labels_rounds(
+    provider: jax.Array,
+    consumer: jax.Array,
+    live: jax.Array,
+    num_spreaders: int,
+    *,
+    max_rounds: int = 0,
+) -> tuple[jax.Array, jax.Array]:
+    """:func:`influence_labels` and the propagation rounds it ran (i32),
+    the last of them the round that changed nothing."""
     S = num_spreaders
     if max_rounds <= 0:
         max_rounds = S
@@ -55,10 +69,10 @@ def influence_labels(
         i, _label, changed = state
         return jnp.logical_and(changed, i < max_rounds)
 
-    _, label, _ = jax.lax.while_loop(
+    rounds, label, _ = jax.lax.while_loop(
         cond, body, (jnp.int32(0), label0, jnp.bool_(True))
     )
-    return label
+    return label, rounds
 
 
 def group_sizes(labels: jax.Array) -> jax.Array:

@@ -19,7 +19,8 @@ State delta: ``t``/``t_c``/``n_events`` (the clock), ``meter_next`` (tick
 consumed), ``f_pr`` (drained flows), ``processed`` (provider utilisation
 counters).  Context delta: the full interval fact sheet (``r``, ``live``,
 ``thresh``, ``done``, ``dt``, ``t0``/``t_new``, ``has_event``, ``tick``,
-``period``, ``compact``) every later stage reads.
+``period``, ``compact``) every later stage reads, and the solve's
+``fill_rounds``.
 """
 from __future__ import annotations
 
@@ -107,16 +108,6 @@ def spreader_perf_at(spec, params, st: CloudState,
     return out.astype(jnp.float32)
 
 
-def rates(spec, st: CloudState, perf: jax.Array):
-    """One unified fair-share pass over the flat spreader space (§3.2)."""
-    thresh = live_threshold(st.f_total)
-    live = st.f_active & (st.t >= st.f_release) & (st.f_pr > thresh)
-    rate_fn = SCHEDULERS[spec.scheduler]
-    r = rate_fn(st.f_prov, st.f_cons, st.f_pl, live, perf,
-                backend=spec.backend, max_iters=spec.max_fill_iters)
-    return r, live, thresh
-
-
 def advance(ctx: StageCtx, st: CloudState):
     spec, params, trace = ctx.spec, ctx.params, ctx.trace
     lay = spec.layout
@@ -138,8 +129,9 @@ def advance(ctx: StageCtx, st: CloudState):
         f_pl_b = cpk.gather_flows(cp, st.f_pl, 0.0)
         f_rel_b = cpk.gather_flows(cp, st.f_release, jnp.inf)
         perf_b = spreader_perf_at(spec, params, st, cp.sidx)
-        r_b = rate_fn(cp.bprov, cp.bcons, f_pl_b, live_b, perf_b,
-                      backend=spec.backend, max_iters=spec.max_fill_iters)
+        r_b, fill_rounds = rate_fn(cp.bprov, cp.bcons, f_pl_b, live_b,
+                                   perf_b, backend=spec.backend,
+                                   max_iters=spec.max_fill_iters)
         r = cpk.scatter_flows(cp, F, r_b)
         flow_cand = [f_pr_b / jnp.maximum(r_b, 1e-30),   # completion  [FB]
                      f_rel_b - st.t]                     # latency     [FB]
@@ -148,8 +140,9 @@ def advance(ctx: StageCtx, st: CloudState):
     else:
         cp = None
         perf = spreader_perf(spec, params, st)
-        r = rate_fn(st.f_prov, st.f_cons, st.f_pl, live, perf,
-                    backend=spec.backend, max_iters=spec.max_fill_iters)
+        r, fill_rounds = rate_fn(st.f_prov, st.f_cons, st.f_pl, live, perf,
+                                 backend=spec.backend,
+                                 max_iters=spec.max_fill_iters)
         flow_cand = [st.f_pr / jnp.maximum(r, 1e-30),    # completion   [F]
                      st.f_release - st.t]                # latency      [F]
         flow_mask = [live & (r > 0),
@@ -246,7 +239,8 @@ def advance(ctx: StageCtx, st: CloudState):
     ctx = ctx._replace(r=r, live=live, thresh=thresh, done=done,
                        delivered=delivered, dt=dt,
                        t0=st.t, t_new=t_new, has_event=has_event,
-                       tick=tick, period=period, compact=cp)
+                       tick=tick, period=period, compact=cp,
+                       fill_rounds=fill_rounds)
     st = st._replace(t=t_new, t_c=t_c, n_events=st.n_events + 1,
                      meter_next=meter_next, f_pr=f_pr, processed=processed)
     return ctx, st

@@ -131,8 +131,10 @@ def scatter_flows(cp: Compact, n_flows: int, vals: jax.Array,
     return base.at[cp.fidx].set(vals, mode="drop")
 
 
-def influence_labels_compact(cp: Compact, live_b: jax.Array) -> jax.Array:
-    """Influence labels over the *compacted* spreader bucket.
+def influence_labels_compact(cp: Compact,
+                             live_b: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Influence labels over the *compacted* spreader bucket, and the
+    propagation rounds it ran.
 
     Labels are **dense** spreader indices (slot ``j`` starts at
     ``sidx[j]``), so the fixpoint equals the dense
@@ -163,9 +165,9 @@ def influence_labels_compact(cp: Compact, live_b: jax.Array) -> jax.Array:
         i, _label, changed = state
         return jnp.logical_and(changed, i < SB)
 
-    _, label, _ = jax.lax.while_loop(
+    rounds, label, _ = jax.lax.while_loop(
         cond, body, (jnp.int32(0), label0, jnp.bool_(True)))
-    return label
+    return label, rounds
 
 
 def label_lookup(cp: Compact, labels_b: jax.Array,

@@ -16,9 +16,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from ..energy import PM_SWITCHING_OFF, PM_SWITCHING_ON
 from . import advance, lifecycle, observe, pm_sched, power, vm_sched
-from .state import TASK_PENDING, CloudState, StageCtx, live_threshold
+from .state import (TASK_PENDING, CloudState, LoopCounters, StageCtx,
+                    live_threshold)
 
 STAGES = (
     advance.advance,        # §3.1/§3.2 sharing + clock-to-horizon + drain
@@ -28,6 +30,9 @@ STAGES = (
     pm_sched.pm_sched,      # §3.5.1 PM policy hook (registry dispatch)
     vm_sched.vm_sched,      # §3.5.1 VM policy hook (registry dispatch)
 )
+# Each stage runs under its jax.named_scope (tracing.STAGE_SCOPES, same
+# order): the scope names its ops in a profiler trace and changes nothing
+# else in the compiled program.
 
 # The management suffix of the pipeline (policy hooks).  Streaming windows
 # gate exactly these two stages off on the hand-over iteration (the one
@@ -87,9 +92,10 @@ def steps_per_iter(spec) -> int:
 
 
 def make_body(spec, params, trace, t_stop, t_next=None):
-    """The ``lax.while_loop`` body over a ``(state, compact_ok)`` carry:
-    K unrolled pipeline passes (coalesced event stepping, DESIGN.md §7)
-    guarded by an early-settled mask.
+    """The ``lax.while_loop`` body over a ``(state, compact_ok, counters)``
+    carry: K unrolled pipeline passes (coalesced event stepping, DESIGN.md
+    §7) guarded by an early-settled mask.  ``counters`` sums the
+    :class:`LoopCounters` each pass reports through its context.
 
     ``t_next`` (streaming windows only, DESIGN.md §8) is the first arrival
     of the next trace window; ``None`` — the monolithic engine — composes
@@ -109,11 +115,14 @@ def make_body(spec, params, trace, t_stop, t_next=None):
         ctx = StageCtx(spec=spec, params=params, trace=trace, t_stop=t_stop,
                        t_next=t_next, arrival_sorted=arrival_sorted)
         snap = (st.task_state, st.vstage, st.pstate, st.f_active)
-        for stage in STAGES[:-N_MANAGEMENT_STAGES]:
-            ctx, st = stage(ctx, st)
+        stages = list(zip(tracing.STAGE_SCOPES, STAGES))
+        for name, stage in stages[:-N_MANAGEMENT_STAGES]:
+            with tracing.scope(name):
+                ctx, st = stage(ctx, st)
         st_pre = st
-        for stage in STAGES[-N_MANAGEMENT_STAGES:]:
-            ctx, st = stage(ctx, st)
+        for name, stage in stages[-N_MANAGEMENT_STAGES:]:
+            with tracing.scope(name):
+                ctx, st = stage(ctx, st)
         if t_next is not None:
             # Hand-over iteration: the clock reached the next window's
             # first arrival, so the management stages ran without that
@@ -125,19 +134,21 @@ def make_body(spec, params, trace, t_stop, t_next=None):
                 lambda pre, post: jnp.where(defer, pre, post), st_pre, st)
         ok = (ctx.compact.ok if ctx.compact is not None
               else jnp.bool_(True))
-        return termination(ctx, st, snap), ok
+        with tracing.scope(tracing.TERMINATION):
+            st = termination(ctx, st, snap)
+        return st, ok, LoopCounters.of(ctx)
 
     K = steps_per_iter(spec)
 
     def skip(st):
-        return st, jnp.bool_(True)
+        return st, jnp.bool_(True), LoopCounters.zero()
 
     def body(carry):
-        st, ok = carry
+        st, ok, counters = carry
         # The first micro-step needs no settled guard: the loop condition
         # that admitted this body already asserted it.
-        st, ok1 = one_pass(st)
-        ok = ok & ok1
+        st, ok1, c1 = one_pass(st)
+        ok, counters = ok & ok1, counters.plus(c1)
         for _ in range(K - 1):
             # Guard via lax.cond: a settled state skips the pass outright
             # (single-scenario runs pay ~nothing; under vmap the cond
@@ -145,9 +156,9 @@ def make_body(spec, params, trace, t_stop, t_next=None):
             # tree-select formulation it replaces — bit-identical either
             # way, since a skipped pass returns the carry verbatim).
             cont = st.running & (st.n_events < spec.max_events)
-            st, ok2 = jax.lax.cond(cont, one_pass, skip, st)
-            ok = ok & ok2
-        return st, ok
+            st, ok2, c2 = jax.lax.cond(cont, one_pass, skip, st)
+            ok, counters = ok & ok2, counters.plus(c2)
+        return st, ok, counters
 
     return body
 
@@ -159,6 +170,9 @@ def management_pass(spec, params, trace, st: CloudState) -> CloudState:
     each arrival time."""
     ctx = StageCtx(spec=spec, params=params, trace=trace,
                    t_stop=jnp.float32(jnp.inf))
-    _, st = pm_sched.pm_sched(ctx, st)
-    _, st = vm_sched.vm_sched(ctx, st)
+    with tracing.scope(tracing.MANAGEMENT_PASS):
+        for name, stage in zip(tracing.STAGE_SCOPES[-N_MANAGEMENT_STAGES:],
+                               STAGES[-N_MANAGEMENT_STAGES:]):
+            with tracing.scope(name):
+                _, st = stage(ctx, st)
     return st

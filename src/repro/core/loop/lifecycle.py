@@ -31,7 +31,7 @@ def vm_lifecycle(ctx: StageCtx, st: CloudState):
     fired = (ctx.done[:ctx.spec.n_vm].any()
              | ((st.vstage == mc.VM_ALLOCATED)
                 & (st.vm_expiry <= ctx.t_new)).any())
-    return ctx, jax.lax.cond(
+    return ctx._replace(lifecycle_gate=fired), jax.lax.cond(
         fired, lambda s: _vm_lifecycle_body(ctx, s), lambda s: s, st)
 
 

@@ -8,7 +8,8 @@ interval and drives the paper's sampled meter on its tick.
 
 State delta: ``meters`` only.  Context delta: publishes the ``view`` so
 the policy stages (``pm_sched`` / ``vm_sched``) can read the same
-observation surface the meters consumed.
+observation surface the meters consumed, and the influence-label
+``label_rounds``.
 
 Everything in the view is read from *interval-start* facts: the rates in
 ``ctx.r``/``ctx.live`` were computed against the pre-advance state and are
@@ -33,14 +34,14 @@ import jax.numpy as jnp
 
 from .. import machine as mc
 from ..energy import MODEL_LINEAR, SimView, instantaneous_power, observe
-from ..influence import coupled_vm_counts, influence_labels
+from ..influence import coupled_vm_counts, influence_labels_rounds
 from . import compact as cpk
 from .state import TASK_PENDING, CloudState, StageCtx
 
 
 def _eq6_views(ctx: StageCtx, st: CloudState, cpu_del: jax.Array):
-    """(vm_rate_frac, vm_host, vms_on_host) — Eq. 6 group membership via
-    the influence components, dense or bucket-compacted."""
+    """(vm_rate_frac, vm_host, vms_on_host, label_rounds) — Eq. 6 group
+    membership via the influence components, dense or bucket-compacted."""
     spec = ctx.spec
     lay = spec.layout
     P, V = spec.n_pm, spec.n_vm
@@ -48,17 +49,18 @@ def _eq6_views(ctx: StageCtx, st: CloudState, cpu_del: jax.Array):
     cp = ctx.compact
 
     if cp is None:
-        labels = influence_labels(st.f_prov, st.f_cons, live, lay.S)
+        labels, rounds = influence_labels_rounds(st.f_prov, st.f_cons, live,
+                                                 lay.S)
         in_grp, vms_on_host = coupled_vm_counts(
             labels, lay.cpu0 + st.vm_host, lay.vm0 + jnp.arange(V),
             st.vm_host, P)
         vm_rate_frac = (jnp.where(in_grp, r[:V], 0.0)
                         / jnp.maximum(cpu_del[st.vm_host], 1e-30))
         vm_host = jnp.where(in_grp, st.vm_host, -1)
-        return vm_rate_frac, vm_host, vms_on_host
+        return vm_rate_frac, vm_host, vms_on_host, rounds
 
     live_b = cpk.gather_flows(cp, live, False)
-    labels_b = cpk.influence_labels_compact(cp, live_b)
+    labels_b, rounds = cpk.influence_labels_compact(cp, live_b)
     is_vm = cp.fvalid & (cp.fidx < V)
     v_scatter = jnp.where(is_vm, cp.fidx, V)          # V = scatter drop
     v_c = jnp.minimum(v_scatter, V - 1)
@@ -74,11 +76,12 @@ def _eq6_views(ctx: StageCtx, st: CloudState, cpu_del: jax.Array):
         frac_b, mode="drop")
     vm_host = jnp.full((V,), -1, jnp.int32).at[v_scatter].set(
         jnp.where(in_grp_b, vmh_b, -1), mode="drop")
-    return vm_rate_frac, vm_host, vms_on_host
+    return vm_rate_frac, vm_host, vms_on_host, rounds
 
 
-def build_view(ctx: StageCtx, st: CloudState) -> SimView:
-    """The meter stack's observation surface for the current interval.
+def build_view(ctx: StageCtx, st: CloudState):
+    """The meter stack's observation surface for the current interval, and
+    the influence-label rounds it took (``None`` without per-VM meters).
 
     The per-VM half wires Eq. 6 through :mod:`repro.core.influence`: a VM
     draws power iff its spreader sits in its host CPU spreader's influence
@@ -102,8 +105,10 @@ def build_view(ctx: StageCtx, st: CloudState) -> SimView:
                        table.p_max[st.pstate] - p_idle, 0.0)
 
     if spec.meters.vm_direct:
-        vm_rate_frac, vm_host, vms_on_host = _eq6_views(ctx, st, cpu_del)
+        vm_rate_frac, vm_host, vms_on_host, label_rounds = _eq6_views(
+            ctx, st, cpu_del)
     else:
+        label_rounds = None
         vms_on_host = jnp.zeros((P,), jnp.int32)
         vm_rate_frac = jnp.zeros((V,), jnp.float32)
         vm_host = jnp.full((V,), -1, jnp.int32)
@@ -115,11 +120,12 @@ def build_view(ctx: StageCtx, st: CloudState) -> SimView:
         vm_rate_frac=vm_rate_frac, vm_host=vm_host, vms_on_host=vms_on_host,
         n_hosted=hosted.sum().astype(jnp.float32),
         n_queued=queued.sum().astype(jnp.float32),
-        tick=ctx.tick, period=ctx.period)
+        tick=ctx.tick, period=ctx.period), label_rounds
 
 
 def observe_stage(ctx: StageCtx, st: CloudState):
-    view = build_view(ctx, st)
+    view, label_rounds = build_view(ctx, st)
     meters = observe(ctx.spec.meters, ctx.params.meter, view, ctx.dt,
                      st.meters)
-    return ctx._replace(view=view), st._replace(meters=meters)
+    return (ctx._replace(view=view, label_rounds=label_rounds),
+            st._replace(meters=meters))

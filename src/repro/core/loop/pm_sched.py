@@ -38,4 +38,4 @@ def pm_sched(ctx: StageCtx, st: CloudState):
         may,
         lambda s: jax.lax.switch(code, registry.stage_branches("pm", ctx), s),
         lambda s: s, st)
-    return ctx, st
+    return ctx._replace(pm_gate=may), st

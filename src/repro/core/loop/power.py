@@ -27,7 +27,7 @@ def pm_power(ctx: StageCtx, st: CloudState):
                  | (st.pstate == PM_SWITCHING_OFF))
     fired = (ctx.done[spec.n_vm:].any()
              | (switching & (st.pstate_end <= ctx.t_new)).any())
-    return ctx, jax.lax.cond(
+    return ctx._replace(power_gate=fired), jax.lax.cond(
         fired, lambda s: _pm_power_body(ctx, s), lambda s: s, st)
 
 

@@ -106,6 +106,41 @@ class CloudState(NamedTuple):
         return self.meters.pm_sampled
 
 
+class LoopCounters(NamedTuple):
+    """Work counts of the event loop, summed over its iterations (one set
+    per lane of a batched run).  Carried beside the loop state, never
+    inside :class:`CloudState`; exposed as ``res.counters``.
+
+    ``gate_opens`` counts, per event gate, the iterations in which the
+    gate's trigger fired and its stage body ran: ``vm_lifecycle``,
+    ``pm_power``, ``pm_sched``, ``vm_sched`` in that order.
+    """
+
+    fill_rounds: jax.Array   # i32 progressive-filling rounds (fair share)
+    label_rounds: jax.Array  # i32 influence-label propagation rounds
+    serve_rounds: jax.Array  # i32 queue-serving rounds of the VM policy
+    gate_opens: jax.Array    # i32[4] iterations each event gate opened
+
+    @classmethod
+    def zero(cls) -> "LoopCounters":
+        return cls(jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                   jnp.zeros((4,), jnp.int32))
+
+    @classmethod
+    def of(cls, ctx: "StageCtx") -> "LoopCounters":
+        """The counts one pipeline pass reported through ``ctx``."""
+        def count(x):
+            return jnp.int32(0) if x is None else jnp.asarray(x, jnp.int32)
+        return cls(count(ctx.fill_rounds), count(ctx.label_rounds),
+                   count(ctx.serve_rounds),
+                   jnp.stack([count(ctx.lifecycle_gate),
+                              count(ctx.power_gate), count(ctx.pm_gate),
+                              count(ctx.vm_gate)]))
+
+    def plus(self, other: "LoopCounters") -> "LoopCounters":
+        return jax.tree.map(jnp.add, self, other)
+
+
 class StageCtx(NamedTuple):
     """Read-mostly context threaded through one pipeline pass.
 
@@ -156,5 +191,16 @@ class StageCtx(NamedTuple):
     tick: jax.Array | None = None     # bool — sampled-meter tick fired
     period: jax.Array | None = None   # f32 metering period
 
+    fill_rounds: jax.Array | None = None  # i32 fair-share solve rounds
+
     # -- filled by the `observe` stage -----------------------------------
     view: Any = None             # energy.SimView of [t0, t_new]
+    label_rounds: jax.Array | None = None  # i32 influence-label rounds
+    #                                        (None: no per-VM meters)
+
+    # -- event gates of the later stages (LoopCounters.gate_opens) -------
+    lifecycle_gate: jax.Array | None = None  # bool vm_lifecycle body ran
+    power_gate: jax.Array | None = None      # bool pm_power body ran
+    pm_gate: jax.Array | None = None         # bool PM policy body ran
+    vm_gate: jax.Array | None = None         # bool VM policy body ran
+    serve_rounds: jax.Array | None = None    # i32 queue-serving rounds

@@ -16,7 +16,8 @@ to the selected operand.
 
 State delta: per dispatched request, the allocated VM slot (``vstage`` /
 ``vm_*``), its image-transfer flow, the host's ``free_cores``, and the
-task binding; per rejected request, its ``task_state``.
+task binding; per rejected request, its ``task_state``.  Context delta:
+the gate's verdict and the queue-serving rounds (``serve_rounds``).
 """
 from __future__ import annotations
 
@@ -132,8 +133,14 @@ def vm_sched(ctx: StageCtx, st: CloudState):
     # request queue.  Under vmap the cond lowers to a select (both sides
     # computed per lane), so batched sweeps stay one program.
     may = jax.lax.switch(code, registry.trigger_branches("vm", ctx), st)
-    st = jax.lax.cond(
-        may,
-        lambda s: jax.lax.switch(code, registry.stage_branches("vm", ctx), s),
-        lambda s: s, st)
-    return ctx, st
+
+    def run(s):
+        s2 = jax.lax.switch(code, registry.stage_branches("vm", ctx), s)
+        # serve_queue's rounds: every round but the last settles (starts or
+        # rejects) exactly one queued task; the last settles none.
+        settled = jnp.sum((s.task_state == TASK_PENDING)
+                          & (s2.task_state != TASK_PENDING))
+        return s2, settled.astype(jnp.int32) + 1
+
+    st, rounds = jax.lax.cond(may, run, lambda s: (s, jnp.int32(0)), st)
+    return ctx._replace(vm_gate=may, serve_rounds=rounds), st

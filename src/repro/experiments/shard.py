@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import engine
+from repro.core import engine, tracing
 
 
 def batch_flags(spec: engine.CloudSpec, trace: engine.Trace,
@@ -133,6 +133,11 @@ def simulate_batch_sharded(
     single point).  Valid rows are bit-identical either way; only the
     device layout changes.
     """
+    with tracing.entry("simulate_batch_sharded"):
+        return _simulate_batch_sharded(spec, trace, params, t_stop, devices)
+
+
+def _simulate_batch_sharded(spec, trace, params, t_stop, devices):
     trace = jax.tree.map(jnp.asarray, trace)
     params = jax.tree.map(jnp.asarray, params)
     n = batch_size(spec, trace, params)
@@ -143,17 +148,22 @@ def simulate_batch_sharded(
     flags = batch_flags(spec, trace, params)
     pad = pad_rows(n, d)
     if pad:
-        trace, params = _pad_batch((trace, params), flags, pad)
+        with tracing.span(tracing.SHARD_PAD):
+            trace, params = _pad_batch((trace, params), flags, pad)
     treedef = jax.tree.structure((trace, params))
     runner = _sharded_runner(spec, devs[:d], treedef, flags)
-    res, ok = runner((trace, params), jnp.asarray(t_stop, jnp.float32))
-    if engine._needs_dense_rerun(spec, ok[:n]):
+    with tracing.span(tracing.LAUNCH):
+        res, ok = runner((trace, params), jnp.asarray(t_stop, jnp.float32))
+    if engine._checked_rerun(spec, ok[:n]):
         engine._warn_dense_rerun(spec)
         runner = _sharded_runner(engine.dense_spec(spec), devs[:d],
                                  treedef, flags)
-        res, _ = runner((trace, params), jnp.asarray(t_stop, jnp.float32))
+        with tracing.span(tracing.DENSE_REPLAY):
+            res, _ = runner((trace, params),
+                            jnp.asarray(t_stop, jnp.float32))
     if pad:
-        res = jax.tree.map(lambda l: l[:n], res)
+        with tracing.span(tracing.SHARD_UNPAD):
+            res = jax.tree.map(lambda l: l[:n], res)
     return res
 
 
@@ -198,6 +208,12 @@ def simulate_stream_batch(
     Returns a :class:`~repro.core.engine.StreamResult` whose every leaf
     carries the batch as its leading axis.
     """
+    with tracing.entry("simulate_stream_batch"):
+        return _simulate_stream_batch(spec, windows, params, n_slots, t_stop,
+                                      devices)
+
+
+def _simulate_stream_batch(spec, windows, params, n_slots, t_stop, devices):
     params = jax.tree.map(jnp.asarray, params)
     paxes = engine._params_axes(spec, params)
     flags = tuple(a == 0 for a in
@@ -217,48 +233,68 @@ def simulate_stream_batch(
     d = shard_count(n, len(devs))
     pad = pad_rows(n, d) if d > 1 else 0
     if pad:
-        params = _pad_batch(params, flags, pad)
+        with tracing.span(tracing.SHARD_PAD):
+            params = _pad_batch(params, flags, pad)
     treedef = jax.tree.structure(params)
     runner = _stream_runner(spec, devs[:d] if d > 1 else devs[:1],
                             treedef, flags)
     paxes = engine._params_axes(spec, params)
 
-    it, W = engine._as_window_iter(windows)
-    cur = next(it, None)
-    if cur is None:
-        raise ValueError("simulate_stream_batch needs at least one window")
-    if W is None:
-        it, _ = engine._as_window_iter(engine._chain_one(cur, it),
-                                       window_size=cur.n)
-        cur = next(it)
-    Q = engine.default_n_slots(spec, cur.n) if n_slots is None else int(n_slots)
-    carry = jax.vmap(lambda pp: engine.init_stream(spec, Q, pp),
-                     in_axes=(paxes,))(params)
-    t_stop = jnp.asarray(t_stop, jnp.float32)
-    t_prev_next = jnp.float32(0.0)
+    with tracing.span(tracing.STREAM_INIT):
+        it, W = engine._as_window_iter(windows)
+        cur = next(it, None)
+        if cur is None:
+            raise ValueError(
+                "simulate_stream_batch needs at least one window")
+        if W is None:
+            it, _ = engine._as_window_iter(engine._chain_one(cur, it),
+                                           window_size=cur.n)
+            cur = next(it)
+        Q = (engine.default_n_slots(spec, cur.n) if n_slots is None
+             else int(n_slots))
+        carry = jax.vmap(lambda pp: engine.init_stream(spec, Q, pp),
+                         in_axes=(paxes,))(params)
+        t_stop = jnp.asarray(t_stop, jnp.float32)
+        t_prev_next = jnp.float32(0.0)
     outs = []
     while cur is not None:
-        nxt = next(it, None)
-        t_next = (jnp.float32(jnp.inf) if nxt is None
-                  else engine._first_arrival(nxt))
-        carry, ys = runner(carry, cur, params, t_prev_next, t_next, t_stop)
+        with tracing.span(tracing.STREAM_WINDOW):
+            with tracing.span(tracing.STREAM_NEXT_WINDOW):
+                nxt = next(it, None)
+                t_next = (jnp.float32(jnp.inf) if nxt is None
+                          else engine._first_arrival(nxt))
+            with tracing.span(tracing.LAUNCH):
+                carry, ys = runner(carry, cur, params, t_prev_next, t_next,
+                                   t_stop)
         outs.append(ys)
         t_prev_next, cur = t_next, nxt
 
-    if engine._needs_dense_rerun(spec, carry.compact_ok[:n]):
+    if engine._checked_rerun(spec, carry.compact_ok[:n]):
         # same policy as simulate_stream: replayable window sources restart
         # the whole sweep densely; consumed generators fail loudly
         if hasattr(windows, "n_windows") and hasattr(windows, "window"):
             engine._warn_dense_rerun(spec)
-            return simulate_stream_batch(
-                engine.dense_spec(spec), windows, params0,
-                n_slots=Q, t_stop=t_stop, devices=devices)
+            with tracing.span(tracing.DENSE_REPLAY):
+                return _simulate_stream_batch(
+                    engine.dense_spec(spec), windows, params0, Q, t_stop,
+                    devices)
         raise RuntimeError(
             "active-set compaction bucket overflowed mid-stream and the "
             "window source is a consumed generator that cannot be "
             "replayed; rerun with spec.compact=0 (dense) or pass a "
             "replayable WindowedTrace")
 
+    with tracing.span(tracing.STREAM_ASSEMBLE):
+        res = _assemble_stream_batch(carry, outs)
+    if pad:
+        with tracing.span(tracing.SHARD_UNPAD):
+            res = jax.tree.map(lambda l: l[:n], res)
+    return res
+
+
+def _assemble_stream_batch(carry, outs) -> engine.StreamResult:
+    """Scatter each lane's per-window flushes back onto the global task
+    axis."""
     gids = jnp.concatenate([o["gid"] for o in outs], axis=-1)
     t_done = jnp.concatenate([o["t_done"] for o in outs], axis=-1)
     rej = jnp.concatenate([o["rejected"] for o in outs], axis=-1)
@@ -274,7 +310,7 @@ def simulate_stream_batch(
 
     completion, rejected = jax.vmap(scatter)(gids, t_done, rej)
     st = carry.state
-    res = engine.StreamResult(
+    return engine.StreamResult(
         state=st,
         completion=completion,
         rejected=rejected,
@@ -286,10 +322,8 @@ def simulate_stream_batch(
         overflow=st.overflow,
         window_t_end=jnp.stack([o["t_end"] for o in outs], axis=-1),
         window_energy=jnp.stack([o["energy"] for o in outs], axis=-1),
+        counters=carry.counters,
     )
-    if pad:
-        res = jax.tree.map(lambda l: l[:n], res)
-    return res
 
 
 def run_batch(spec: engine.CloudSpec, trace: engine.Trace,

@@ -256,12 +256,18 @@ def stage_branches(layer: str, ctx) -> tuple[Callable, ...]:
     ``(st) -> st`` callable per code, in code order, each closed over the
     iteration's :class:`~repro.core.loop.state.StageCtx` (the context
     holds the jit-static ``CloudSpec``, so it is captured, not passed as a
-    switch operand)."""
+    switch operand).  Each branch runs under a ``jax.named_scope`` of its
+    policy name, so a profiler trace tells the policies' device time
+    apart (:mod:`repro.core.tracing`)."""
+    from repro.core import tracing
 
-    def bind(fn):
-        return lambda st: fn(ctx.spec, ctx.params, ctx, st)
+    def bind(p):
+        def branch(st):
+            with tracing.scope(p.name):
+                return p.fn(ctx.spec, ctx.params, ctx, st)
+        return branch
 
-    return tuple(bind(p.fn) for p in policies(layer))
+    return tuple(bind(p) for p in policies(layer))
 
 
 def trigger_branches(layer: str, ctx) -> tuple[Callable, ...]:

@@ -22,6 +22,12 @@ from repro.core import machine as mc
 from repro.core.energy import PM_OFF, PM_RUNNING, PM_SWITCHING_OFF
 
 
+def _answers(res):
+    """The result's leaves without its loop counters: policies that give
+    the same answers may still open their event gates differently."""
+    return jax.tree.leaves(res._replace(counters=None))
+
+
 def _cloud(**kw):
     base = dict(n_pm=2, n_vm=16, pm_cores=4.0, net_bw=100.0, repo_bw=200.0,
                 image_mb=100.0, boot_work=4.0, latency_s=0.0)
@@ -170,7 +176,7 @@ def test_consolidate_with_impossible_trigger_equals_ondemand_bitwise():
     params_c = dataclasses.replace(params_c,
                                    consolidate_idle_frac=jnp.float32(2.0))
     got = eng.simulate(spec_c, tr, params=params_c)
-    for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(got)):
+    for a, b in zip(_answers(ref), _answers(got)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

@@ -19,6 +19,12 @@ from repro.core import machine as mc
 from repro.core.energy import PM_OFF, PM_RUNNING, PM_SWITCHING_OFF
 
 
+def _answers(res):
+    """The result's leaves without its loop counters: policies that give
+    the same answers may still open their event gates differently."""
+    return jax.tree.leaves(res._replace(counters=None))
+
+
 def _trace(arrival, cores, runtime):
     arrival = jnp.asarray(arrival, jnp.float32)
     cores = jnp.asarray(cores, jnp.float32)
@@ -113,7 +119,7 @@ def test_evacuate_equals_consolidate_bitwise_on_single_vm_donor():
     ref = eng.simulate(spec_c, tr, params=params_c)
     spec_e, params_e = _cloud("evacuate")
     got = eng.simulate(spec_e, tr, params=params_e)
-    for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(got)):
+    for a, b in zip(_answers(ref), _answers(got)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -125,7 +131,7 @@ def test_evacuate_with_impossible_trigger_equals_ondemand_bitwise():
     params_e = dataclasses.replace(params_e,
                                    consolidate_idle_frac=jnp.float32(2.0))
     got = eng.simulate(spec_e, tr, params=params_e)
-    for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(got)):
+    for a, b in zip(_answers(ref), _answers(got)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -210,7 +216,7 @@ def test_defrag_on_single_pm_equals_ondemand_bitwise():
     ref = eng.simulate(spec_o, tr, params=params_o)
     spec_d, params_d = _cloud("defrag", n_pm=1)
     got = eng.simulate(spec_d, tr, params=params_d)
-    for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(got)):
+    for a, b in zip(_answers(ref), _answers(got)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

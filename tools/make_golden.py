@@ -143,9 +143,13 @@ def scenarios():
 
 
 def flatten_result(name: str, res) -> dict[str, np.ndarray]:
+    """Every answer leaf of ``res`` by its path.  The loop's work counts
+    (``res.counters``) are not answers and are left out."""
     flat = {}
     leaves = jax.tree_util.tree_flatten_with_path(res)[0]
     for path, leaf in leaves:
+        if getattr(path[0], "name", None) == "counters":
+            continue
         key = name + jax.tree_util.keystr(path)
         flat[key] = np.asarray(leaf)
     return flat
