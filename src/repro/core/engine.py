@@ -388,8 +388,9 @@ def init_state(spec: CloudSpec, trace: Trace,
 
 
 def _simulate_impl(spec: CloudSpec, trace: Trace, params: CloudParams,
-                   state: CloudState | None,
-                   t_stop: jax.Array) -> tuple[CloudResult, jax.Array]:
+                   state: CloudState | None, t_stop: jax.Array,
+                   axis_name: str | None = None
+                   ) -> tuple[CloudResult, jax.Array]:
     """Single-scenario engine: the staged pipeline (repro.core.loop) inside
     one ``lax.while_loop``.  Trace it once, run it for every parameter
     point — no python branch here depends on a params value.
@@ -397,7 +398,9 @@ def _simulate_impl(spec: CloudSpec, trace: Trace, params: CloudParams,
     Returns ``(result, compact_ok)``: the second element is the loop's
     accumulated active-set-compaction verdict (DESIGN.md §7) — ``False``
     means a bucket overflowed at some iteration and the run must be
-    replayed with ``spec.compact = 0`` (the host wrappers do)."""
+    replayed with ``spec.compact = 0`` (the host wrappers do).
+    ``axis_name`` names the ``vmap`` axis of a batched caller
+    (``loop.LANE_AXIS``), over which the loop picks its bucket tier."""
     st0 = init_state(spec, trace, params) if state is None else state
     st0 = loop.management_pass(spec, params, trace, st0)
     t_stop = jnp.asarray(t_stop, jnp.float32)
@@ -406,8 +409,10 @@ def _simulate_impl(spec: CloudSpec, trace: Trace, params: CloudParams,
         return carry[0].running & (carry[0].n_events < spec.max_events)
 
     st, ok, counters = jax.lax.while_loop(
-        cond, loop.make_body(spec, params, trace, t_stop),
-        (st0, jnp.bool_(True), LoopCounters.zero()))
+        cond, loop.make_body(spec, params, trace, t_stop,
+                             axis_name=axis_name),
+        loop.lane_carry((st0, jnp.bool_(True), LoopCounters.zero()),
+                        axis_name))
     return CloudResult(
         state=st,
         completion=st.t_done,
@@ -526,8 +531,9 @@ def _simulate_batch_jit(spec: CloudSpec, trace: Trace, params: CloudParams,
             "axis) in `trace` or `params`; use simulate() for a single "
             "scenario")
     run = jax.vmap(
-        lambda tr, pp: _simulate_impl(spec, tr, pp, None, t_stop),
-        in_axes=(taxes, paxes))
+        lambda tr, pp: _simulate_impl(spec, tr, pp, None, t_stop,
+                                      axis_name=loop.LANE_AXIS),
+        in_axes=(taxes, paxes), axis_name=loop.LANE_AXIS)
     return run(trace, params)
 
 
@@ -662,8 +668,10 @@ def init_stream(spec: CloudSpec, n_slots: int,
 
 def _stream_step_impl(spec: CloudSpec, carry: StreamCarry, window: Trace,
                       params: CloudParams, t_prev_next: jax.Array,
-                      t_next: jax.Array, t_stop: jax.Array):
-    """One window of the streaming engine (DESIGN.md §8).
+                      t_next: jax.Array, t_stop: jax.Array,
+                      axis_name: str | None = None):
+    """One window of the streaming engine (DESIGN.md §8).  ``axis_name``
+    as in :func:`_simulate_impl`.
 
     1. *Insert*: the window's valid tasks (``gid >= 0``) scatter into free
        slots in rank order (i-th incoming task -> i-th free slot); pool
@@ -726,8 +734,9 @@ def _stream_step_impl(spec: CloudSpec, carry: StreamCarry, window: Trace,
         return s.running & (s.n_events < spec.max_events)
 
     st, compact_ok, counters = jax.lax.while_loop(
-        cond, loop.make_body(spec, params, slots, t_stop, t_next),
-        (st, carry.compact_ok, carry.counters))
+        cond, loop.make_body(spec, params, slots, t_stop, t_next,
+                             axis_name=axis_name),
+        loop.lane_carry((st, carry.compact_ok, carry.counters), axis_name))
 
     # ---- 4. flush terminal slots (compacted to the front), free them
     with tracing.scope(tracing.STREAM_FLUSH):

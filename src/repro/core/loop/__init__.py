@@ -27,7 +27,8 @@ live in :mod:`repro.sched.policies` and reach the loop only through the
 open registry (:mod:`repro.sched.registry`): the core knows no policy by
 name.
 """
-from .driver import STAGES, make_body, management_pass, termination  # noqa: F401
+from .driver import (  # noqa: F401
+    LANE_AXIS, STAGES, lane_carry, make_body, management_pass, termination)
 from .state import (  # noqa: F401
     BIG, KIND_MIGRATE, TASK_ACTIVE, TASK_DONE, TASK_PENDING, TASK_REJECTED,
     CloudState, StageCtx)

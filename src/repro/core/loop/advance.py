@@ -19,8 +19,8 @@ State delta: ``t``/``t_c``/``n_events`` (the clock), ``meter_next`` (tick
 consumed), ``f_pr`` (drained flows), ``processed`` (provider utilisation
 counters).  Context delta: the full interval fact sheet (``r``, ``live``,
 ``thresh``, ``done``, ``dt``, ``t0``/``t_new``, ``has_event``, ``tick``,
-``period``, ``compact``) every later stage reads, and the solve's
-``fill_rounds``.
+``period``, ``compact``, ``compact_ok``) every later stage reads, and the
+solve's ``fill_rounds``.  The bucket tier comes in ``ctx.bucket``.
 """
 from __future__ import annotations
 
@@ -116,14 +116,13 @@ def advance(ctx: StageCtx, st: CloudState):
     thresh = live_threshold(st.f_total)
     live = st.f_active & (st.t >= st.f_release) & (st.f_pr > thresh)
     rate_fn = SCHEDULERS[spec.scheduler]
-    FB = cpk.compact_bucket(spec)
 
-    if FB:
+    if ctx.bucket is not None:
         # ---- compacted fair-share solve (DESIGN.md §7) ------------------
         # The solve sees the same live flows, capacities and rate limits in
         # the same index order, so its progressive-filling rounds — and the
         # resulting rates — are bit-identical to the dense call.
-        cp = cpk.build_compact(spec, st)
+        cp = cpk.build_tier(spec, st, ctx.bucket)
         live_b = cpk.gather_flows(cp, live, False)
         f_pr_b = cpk.gather_flows(cp, st.f_pr, 0.0)
         f_pl_b = cpk.gather_flows(cp, st.f_pl, 0.0)
@@ -219,7 +218,7 @@ def advance(ctx: StageCtx, st: CloudState):
     # compacted variant reduces the same (live) terms in the same flow
     # order and scatters per-spreader sums back (dropped terms are exact
     # ``+0.0`` contributions).
-    if FB:
+    if cp is not None:
         SBn = cp.sidx.shape[0]
         stats_b = jax.ops.segment_sum(
             jnp.stack([jnp.where(live_b, r_b, 0.0),
@@ -240,6 +239,7 @@ def advance(ctx: StageCtx, st: CloudState):
                        delivered=delivered, dt=dt,
                        t0=st.t, t_new=t_new, has_event=has_event,
                        tick=tick, period=period, compact=cp,
+                       compact_ok=None if cp is None else cp.ok,
                        fill_rounds=fill_rounds)
     st = st._replace(t=t_new, t_c=t_c, n_events=st.n_events + 1,
                      meter_next=meter_next, f_pr=f_pr, processed=processed)

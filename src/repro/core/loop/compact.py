@@ -33,6 +33,16 @@ non-negative, so no ``-0.0`` can flip a sign bit under ``x + 0.0``), a
 masked horizon lane contributes the ``BIG`` filler either way, and an
 untouched spreader keeps its singleton influence label.  See
 ``tests/test_compact.py`` for the replay proofs.
+
+**Tiers.**  Under the auto rule a watermark bucket of at least
+``4 * SMALL_FLOWS`` gets a second, small tier (:func:`compact_tiers`):
+``SMALL_FLOWS`` flows and twice as many spreaders.  Each flow references
+two spreaders, so the small tier holds every active set of at most
+``SMALL_FLOWS`` flows.  The loop driver picks the tier on every pass from
+the active-flow count (:mod:`repro.core.loop.driver`).  Any bucket at
+least as large as the active set gives the same bits, so the choice only
+moves the cost.  The small tier is built without a dense scatter
+(:func:`build_small`).
 """
 from __future__ import annotations
 
@@ -71,6 +81,29 @@ def compact_bucket(spec) -> int:
     return fb if 2 * fb <= F else 0
 
 
+# The small tier: flows and spreaders.  Every gather and scatter of the
+# compacted stages walks its bucket element by element on the TPU, so a
+# pass whose active set fits here costs a fraction of the watermark's.
+SMALL_FLOWS = 64
+SMALL_TIER = (SMALL_FLOWS, 2 * SMALL_FLOWS)
+
+
+def compact_tiers(spec) -> tuple[tuple[int, int], ...]:
+    """The ``(flow bucket, spreader bucket)`` tiers the compacted stages
+    may run on, smallest first; ``()`` runs them dense.
+
+    The auto rule (``spec.compact == -1``) adds :data:`SMALL_TIER` below a
+    watermark of at least ``4 * SMALL_FLOWS``; an explicit bucket pins one
+    tier.
+    """
+    fb = compact_bucket(spec)
+    if fb == 0:
+        return ()
+    if spec.compact == -1 and fb >= 4 * SMALL_FLOWS:
+        return (SMALL_TIER, (fb, fb))
+    return ((fb, fb),)
+
+
 class Compact(NamedTuple):
     """One iteration's active-set gather (built by the ``advance`` stage,
     threaded to ``observe`` through ``StageCtx.compact``)."""
@@ -84,34 +117,81 @@ class Compact(NamedTuple):
     ok: jax.Array      # bool — both buckets held every active entry
 
 
-def build_compact(spec, st) -> Compact:
-    """Gather the active flows and their referenced spreaders into the
-    spec-static buckets.  ``jnp.nonzero(size=...)`` returns indices in
-    ascending order, so compacted segment sums reduce the surviving terms
-    in exactly the dense index order (bit-identity, DESIGN.md §7)."""
-    FB = compact_bucket(spec)
-    SB = FB
-    lay = spec.layout
+def build_compact(spec, st, fb: int, sb: int) -> Compact:
+    """Gather the active flows and their referenced spreaders into buckets
+    of ``fb`` flows and ``sb`` spreaders.  ``jnp.nonzero(size=...)``
+    returns indices in ascending order, so compacted segment sums reduce
+    the surviving terms in exactly the dense index order (bit-identity,
+    DESIGN.md §7)."""
     F = spec.n_vm + spec.n_pm
-    S = lay.S
+    S = spec.layout.S
 
     bm = st.f_active
-    fidx = jnp.nonzero(bm, size=FB, fill_value=F)[0].astype(jnp.int32)
-    fvalid = fidx < F
-    fidx_c = jnp.minimum(fidx, F - 1)
-    prov_d = jnp.where(fvalid, st.f_prov[fidx_c], S)
-    cons_d = jnp.where(fvalid, st.f_cons[fidx_c], S)
+    fidx = jnp.nonzero(bm, size=fb, fill_value=F)[0].astype(jnp.int32)
+    fvalid, prov_d, cons_d = _endpoints(st, fidx, F, S)
 
     mark = jnp.zeros((S,), bool)
     mark = mark.at[prov_d].set(True, mode="drop")
     mark = mark.at[cons_d].set(True, mode="drop")
-    sidx = jnp.nonzero(mark, size=SB, fill_value=S)[0].astype(jnp.int32)
-    smap = jnp.full((S,), SB, jnp.int32).at[sidx].set(
-        jnp.arange(SB, dtype=jnp.int32), mode="drop")
+    sidx = jnp.nonzero(mark, size=sb, fill_value=S)[0].astype(jnp.int32)
+    ok = (jnp.sum(bm) <= fb) & (jnp.sum(mark) <= sb)
+    return _assemble(fidx, fvalid, sidx, prov_d, cons_d, S, ok)
 
-    bprov = jnp.where(fvalid, jnp.take(smap, prov_d, mode="clip"), SB)
-    bcons = jnp.where(fvalid, jnp.take(smap, cons_d, mode="clip"), SB)
-    ok = (jnp.sum(bm) <= FB) & (jnp.sum(mark) <= SB)
+
+def build_small(spec, st, fb: int, sb: int) -> Compact:
+    """:func:`build_compact` with no scatter over the dense flow or
+    spreader axis, for ``sb >= 2 * fb``.
+
+    ``jnp.nonzero`` counts its mask with a scatter of every entry; here
+    ``fidx[j]`` is the number of flows whose running active count is at
+    most ``j`` — the index of the ``j``-th active flow, ``F`` past the
+    last — and the spreaders are the ``2 * fb`` endpoint ids sorted and
+    de-duplicated.  Both give ``build_compact``'s ascending indices and
+    fill, bit for bit.
+    """
+    assert sb >= 2 * fb, (fb, sb)
+    F = spec.n_vm + spec.n_pm
+    S = spec.layout.S
+
+    bm = st.f_active
+    rank = jnp.cumsum(bm.astype(jnp.int32))
+    j = jnp.arange(fb, dtype=jnp.int32)
+    fidx = jnp.sum(rank[None, :] <= j[:, None], axis=1, dtype=jnp.int32)
+    fvalid, prov_d, cons_d = _endpoints(st, fidx, F, S)
+
+    ends = jnp.sort(jnp.concatenate([prov_d, cons_d]))
+    dup = jnp.concatenate([jnp.zeros((1,), bool), ends[1:] == ends[:-1]])
+    sidx = jnp.sort(jnp.where(dup, S, ends))   # S (the fill) sorts last
+    sidx = jnp.pad(sidx, (0, sb - 2 * fb), constant_values=S)
+    # 2 * fb endpoints always fit sb spreader slots
+    ok = jnp.sum(bm) <= fb
+    return _assemble(fidx, fvalid, sidx, prov_d, cons_d, S, ok)
+
+
+def build_tier(spec, st, tier: tuple[int, int]) -> Compact:
+    """The gather of one of :func:`compact_tiers`: the small tier without
+    a dense scatter, the watermark by ``jnp.nonzero``."""
+    build = build_small if tier == SMALL_TIER else build_compact
+    return build(spec, st, *tier)
+
+
+def _endpoints(st, fidx, F: int, S: int):
+    """(fvalid, provider ids, consumer ids) of a flow bucket; fill lanes
+    point at spreader ``S``."""
+    fvalid = fidx < F
+    fidx_c = jnp.minimum(fidx, F - 1)
+    prov_d = jnp.where(fvalid, st.f_prov[fidx_c], S)
+    cons_d = jnp.where(fvalid, st.f_cons[fidx_c], S)
+    return fvalid, prov_d, cons_d
+
+
+def _assemble(fidx, fvalid, sidx, prov_d, cons_d, S: int, ok) -> Compact:
+    """The bucket-slot maps of a gathered flow and spreader bucket."""
+    sb = sidx.shape[0]
+    smap = jnp.full((S,), sb, jnp.int32).at[sidx].set(
+        jnp.arange(sb, dtype=jnp.int32), mode="drop")
+    bprov = jnp.where(fvalid, jnp.take(smap, prov_d, mode="clip"), sb)
+    bcons = jnp.where(fvalid, jnp.take(smap, cons_d, mode="clip"), sb)
     return Compact(fidx=fidx, fvalid=fvalid, sidx=sidx, smap=smap,
                    bprov=bprov, bcons=bcons, ok=ok)
 

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from .. import tracing
 from ..energy import PM_SWITCHING_OFF, PM_SWITCHING_ON
 from . import advance, lifecycle, observe, pm_sched, power, vm_sched
+from .compact import compact_tiers
 from .state import (TASK_PENDING, CloudState, LoopCounters, StageCtx,
                     live_threshold)
 
@@ -42,6 +43,19 @@ STAGES = (
 # the arrival is present — same stage inputs, bit-identical outputs
 # (DESIGN.md §8).
 N_MANAGEMENT_STAGES = 2
+
+# The prefix of the pipeline that runs on the active-set bucket (DESIGN.md
+# §7): ``advance`` builds the gather, ``observe`` reads it.  With two
+# bucket tiers the driver runs these stages inside one ``lax.cond`` per
+# pass, on the smallest tier that holds the active flows; only the
+# bucket-independent facts (``StageCtx.facts``) leave it.
+N_COMPACTED_STAGES = 2
+
+# The batch axis name of ``vmap``-ed runs (``simulate_batch``, the shard
+# and stream-batch runners).  Under it the tier predicate is the largest
+# active count over the lanes, so the cond stays one branch for the whole
+# batch instead of lowering to a select of both tiers.
+LANE_AXIS = "lanes"
 
 
 def termination(ctx: StageCtx, st: CloudState, snap) -> CloudState:
@@ -91,11 +105,15 @@ def steps_per_iter(spec) -> int:
     return int(k) if k > 0 else DEFAULT_STEPS_PER_ITER
 
 
-def make_body(spec, params, trace, t_stop, t_next=None):
+def make_body(spec, params, trace, t_stop, t_next=None, axis_name=None):
     """The ``lax.while_loop`` body over a ``(state, compact_ok, counters)``
     carry: K unrolled pipeline passes (coalesced event stepping, DESIGN.md
     §7) guarded by an early-settled mask.  ``counters`` sums the
     :class:`LoopCounters` each pass reports through its context.
+
+    ``axis_name`` names the ``vmap`` axis the body runs under, if any
+    (:data:`LANE_AXIS`): the bucket tier is then chosen for all lanes at
+    once.
 
     ``t_next`` (streaming windows only, DESIGN.md §8) is the first arrival
     of the next trace window; ``None`` — the monolithic engine — composes
@@ -111,12 +129,42 @@ def make_body(spec, params, trace, t_stop, t_next=None):
     # horizon's O(log T) searchsorted runs against (a loop constant).
     arrival_sorted = jnp.sort(jnp.asarray(trace.arrival, jnp.float32))
 
+    stages = list(zip(tracing.STAGE_SCOPES, STAGES))
+    compacted = stages[:N_COMPACTED_STAGES]
+    rest = stages[N_COMPACTED_STAGES:-N_MANAGEMENT_STAGES]
+    tiers = compact_tiers(spec)
+
+    def run_compacted(ctx, st, tier):
+        ctx = ctx._replace(bucket=tier)
+        for name, stage in compacted:
+            with tracing.scope(name):
+                ctx, st = stage(ctx, st)
+        return ctx, st
+
+    def on_tier(ctx, tier):
+        def run(st):
+            out, st = run_compacted(ctx, st, tier)
+            return out.facts(), st
+        return run
+
     def one_pass(st: CloudState):
         ctx = StageCtx(spec=spec, params=params, trace=trace, t_stop=t_stop,
                        t_next=t_next, arrival_sorted=arrival_sorted)
         snap = (st.task_state, st.vstage, st.pstate, st.f_active)
-        stages = list(zip(tracing.STAGE_SCOPES, STAGES))
-        for name, stage in stages[:-N_MANAGEMENT_STAGES]:
+        if len(tiers) == 2:
+            # Bucket tiers (DESIGN.md §7): the smallest that holds every
+            # active flow, of every lane under vmap.  Either gives the
+            # same bits.
+            n_active = jnp.sum(st.f_active, dtype=jnp.int32)
+            if axis_name is not None:
+                n_active = jax.lax.pmax(n_active, axis_name)
+            small = n_active <= tiers[0][0]
+            facts, st = jax.lax.cond(small, on_tier(ctx, tiers[0]),
+                                     on_tier(ctx, tiers[1]), st)
+            ctx = ctx._replace(**facts)._replace(small_bucket=small)
+        else:
+            ctx, st = run_compacted(ctx, st, tiers[0] if tiers else None)
+        for name, stage in rest:
             with tracing.scope(name):
                 ctx, st = stage(ctx, st)
         st_pre = st
@@ -132,7 +180,7 @@ def make_body(spec, params, trace, t_stop, t_next=None):
             defer = jnp.isfinite(t_next) & (st_pre.t >= t_next)
             st = jax.tree.map(
                 lambda pre, post: jnp.where(defer, pre, post), st_pre, st)
-        ok = (ctx.compact.ok if ctx.compact is not None
+        ok = (ctx.compact_ok if ctx.compact_ok is not None
               else jnp.bool_(True))
         with tracing.scope(tracing.TERMINATION):
             st = termination(ctx, st, snap)
@@ -161,6 +209,21 @@ def make_body(spec, params, trace, t_stop, t_next=None):
         return st, ok, counters
 
     return body
+
+
+def lane_carry(carry, axis_name):
+    """``carry`` batched along the named ``vmap`` axis, values unchanged.
+
+    Under ``vmap`` the loop's continue flag differs per lane, so JAX
+    batches every carried leaf anyway.  Batching them before the loop lets
+    its batching rule settle in one pass over the body, where it would
+    otherwise batch the body (both bucket tiers and every policy branch)
+    again for each leaf that turns batched: set-up time, not run time.
+    """
+    if axis_name is None:
+        return carry
+    lane = jax.lax.axis_index(axis_name)
+    return jax.tree.map(lambda x: jnp.where(lane >= 0, x, x), carry)
 
 
 def management_pass(spec, params, trace, st: CloudState) -> CloudState:

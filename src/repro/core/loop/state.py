@@ -114,17 +114,20 @@ class LoopCounters(NamedTuple):
     ``gate_opens`` counts, per event gate, the iterations in which the
     gate's trigger fired and its stage body ran: ``vm_lifecycle``,
     ``pm_power``, ``pm_sched``, ``vm_sched`` in that order.
+    ``small_bucket_iters`` counts the iterations whose compacted stages
+    ran on the small bucket tier (``loop.compact.SMALL_TIER``).
     """
 
     fill_rounds: jax.Array   # i32 progressive-filling rounds (fair share)
     label_rounds: jax.Array  # i32 influence-label propagation rounds
     serve_rounds: jax.Array  # i32 queue-serving rounds of the VM policy
     gate_opens: jax.Array    # i32[4] iterations each event gate opened
+    small_bucket_iters: jax.Array  # i32 iterations on the small tier
 
     @classmethod
     def zero(cls) -> "LoopCounters":
         return cls(jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                   jnp.zeros((4,), jnp.int32))
+                   jnp.zeros((4,), jnp.int32), jnp.int32(0))
 
     @classmethod
     def of(cls, ctx: "StageCtx") -> "LoopCounters":
@@ -135,7 +138,8 @@ class LoopCounters(NamedTuple):
                    count(ctx.serve_rounds),
                    jnp.stack([count(ctx.lifecycle_gate),
                               count(ctx.power_gate), count(ctx.pm_gate),
-                              count(ctx.vm_gate)]))
+                              count(ctx.vm_gate)]),
+                   count(ctx.small_bucket))
 
     def plus(self, other: "LoopCounters") -> "LoopCounters":
         return jax.tree.map(jnp.add, self, other)
@@ -172,10 +176,17 @@ class StageCtx(NamedTuple):
     # lies beyond the monotone clock can only ever be PENDING.  ``None``
     # (e.g. the pre-loop management pass) keeps the dense arrival scan.
     arrival_sorted: jax.Array | None = None
+    # The ``(flows, spreaders)`` bucket tier the compacted stages run on
+    # this pass (one of ``loop.compact.compact_tiers``, chosen by the
+    # driver); ``None`` runs them dense.
+    bucket: tuple[int, int] | None = None
 
     # -- filled by the `advance` stage -----------------------------------
     compact: Any = None          # loop.compact.Compact of this iteration
-    #                              (None: compaction disabled for the spec)
+    #                              (None: dense; bucket-shaped, so it never
+    #                              leaves the driver's tier cond)
+    compact_ok: jax.Array | None = None  # bool — the bucket held the
+    #                                      active set (Compact.ok)
     r: jax.Array | None = None        # f32[F] fair-share rates this interval
     live: jax.Array | None = None     # bool[F] flows that progressed
     thresh: jax.Array | None = None   # f32[F] completion epsilon
@@ -204,3 +215,14 @@ class StageCtx(NamedTuple):
     pm_gate: jax.Array | None = None         # bool PM policy body ran
     vm_gate: jax.Array | None = None         # bool VM policy body ran
     serve_rounds: jax.Array | None = None    # i32 queue-serving rounds
+
+    # -- set by the driver -----------------------------------------------
+    small_bucket: jax.Array | None = None    # bool compacted stages ran on
+    #                                          the small tier
+
+    def facts(self) -> dict:
+        """The fields the stages filled, without the bucket-shaped
+        ``compact``: the shape-independent results of a pass, which is
+        what may leave a ``lax.cond`` over bucket tiers."""
+        first = self._fields.index("compact")
+        return {k: getattr(self, k) for k in self._fields[first + 1:]}

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import engine, tracing
+from repro.core import engine, loop, tracing
 
 
 def batch_flags(spec: engine.CloudSpec, trace: engine.Trace,
@@ -179,9 +179,11 @@ def _stream_runner(spec, devs, treedef, flags):
 
     def step(carry, window, params, t_prev_next, t_next, t_stop):
         return engine._stream_step_impl(spec, carry, window, params,
-                                        t_prev_next, t_next, t_stop)
+                                        t_prev_next, t_next, t_stop,
+                                        axis_name=loop.LANE_AXIS)
 
-    vstep = jax.vmap(step, in_axes=(0, None, paxes, None, None, None))
+    vstep = jax.vmap(step, in_axes=(0, None, paxes, None, None, None),
+                     axis_name=loop.LANE_AXIS)
     if len(devs) > 1:
         mesh = Mesh(np.asarray(devs), ("batch",))
         pspecs = treedef.unflatten([P("batch") if f else P() for f in flags])

@@ -61,6 +61,23 @@ def test_watermark_rule():
     assert cpk.compact_bucket(CloudSpec(n_pm=3, n_vm=24, compact=12)) == 16
     assert cpk.compact_bucket(CloudSpec(n_pm=3, n_vm=24, compact=64)) == 0
     assert cpk.compact_bucket(CloudSpec(n_pm=3, n_vm=24, compact=0)) == 0
+    # tiers: auto adds the small tier below a watermark of >= 4 x 64
+    # flows; an explicit bucket pins one tier; compact=0 runs dense
+    small = (64, 128)
+    assert cpk.SMALL_TIER == small
+    assert cpk.compact_tiers(CloudSpec(n_pm=20, n_vm=256)) == ((128, 128),)
+    assert cpk.compact_tiers(CloudSpec(n_pm=25, n_vm=512)) == (
+        small, (256, 256))
+    assert cpk.compact_tiers(CloudSpec(n_pm=64, n_vm=1024)) == (
+        small, (512, 512))
+    assert cpk.compact_tiers(CloudSpec(n_pm=500, n_vm=4096)) == (
+        small, (2048, 2048))
+    assert cpk.compact_tiers(
+        CloudSpec(n_pm=25, n_vm=512, compact=256)) == ((256, 256),)
+    assert cpk.compact_tiers(
+        CloudSpec(n_pm=25, n_vm=512, compact=64)) == ((64, 64),)
+    assert cpk.compact_tiers(CloudSpec(n_pm=25, n_vm=512, compact=0)) == ()
+    assert cpk.compact_tiers(CloudSpec(n_pm=3, n_vm=12)) == ()
 
 
 def test_build_compact_ascending_and_ok():
@@ -70,7 +87,7 @@ def test_build_compact_ascending_and_ok():
     st = engine.init_state(spec, _scenario()[1])
     f_active = jnp.zeros((16,), bool).at[jnp.asarray([9, 2, 11, 5])].set(True)
     st = st._replace(f_active=f_active)
-    cp = cpk.build_compact(spec, st)
+    cp = cpk.build_compact(spec, st, 8, 8)
     got = np.asarray(cp.fidx)[np.asarray(cp.fvalid)]
     np.testing.assert_array_equal(got, [2, 5, 9, 11])
     assert bool(cp.ok)
@@ -178,3 +195,106 @@ def test_trigger_registration_contract():
     for layer in ("vm", "pm"):
         for p in registry.policies(layer):
             assert p.trigger is None or callable(p.trigger)
+
+
+# ---------------------------------------------------------------------------
+# bucket tiers (DESIGN.md §7): the smallest spec on which auto compaction
+# holds both tiers, under a trace whose active set crosses 64 both ways
+# ---------------------------------------------------------------------------
+
+TIERED = CloudSpec(n_pm=25, n_vm=512)
+
+
+def _tier_trace(burst: int, T=160, seed=3) -> Trace:
+    """``burst`` one-core tasks within a second of t = 10, the rest spread
+    over 600 s: the live set rises past 64 flows and falls back."""
+    rng = np.random.default_rng(seed)
+    arr = np.sort(np.concatenate([10.0 + rng.uniform(0, 1, burst),
+                                  rng.uniform(0, 600, T - burst)]))
+    return Trace(arrival=jnp.asarray(arr.astype(np.float32)),
+                 cores=jnp.ones((T,), jnp.float32),
+                 work=jnp.asarray(rng.uniform(5, 50, T).astype(np.float32)))
+
+
+def _answers(res):
+    """Everything but the loop counters: ``small_bucket_iters`` reads 0
+    on the dense run by construction."""
+    return res._replace(counters=res.counters._replace(
+        small_bucket_iters=None))
+
+
+@pytest.fixture(scope="module")
+def dense_burst():
+    tr = _tier_trace(90)
+    return tr, jax.block_until_ready(
+        engine.simulate(dataclasses.replace(TIERED, compact=0), tr))
+
+
+def test_small_build_matches_build_compact():
+    spec = TIERED
+    S = spec.layout.S
+    F = spec.n_vm + spec.n_pm
+    st0 = engine.init_state(spec, _tier_trace(0, T=8))
+    small = jax.jit(lambda st: cpk.build_small(spec, st, *cpk.SMALL_TIER))
+    ref = jax.jit(lambda st: cpk.build_compact(spec, st, *cpk.SMALL_TIER))
+    rng = np.random.default_rng(0)
+    for n in (0, 1, 2, 17, 63, 64, 65, 200, F):
+        active = np.zeros(F, bool)
+        active[rng.choice(F, n, replace=False)] = True
+        # endpoints shared between flows, so de-duplication matters
+        st = st0._replace(
+            f_active=jnp.asarray(active),
+            f_prov=jnp.asarray(rng.integers(0, S, F).astype(np.int32)),
+            f_cons=jnp.asarray(rng.integers(0, 40, F).astype(np.int32)))
+        got, want = small(st), ref(st)
+        _assert_tree_bitwise(got, want, f"n={n}")
+        assert bool(got.ok) == (n <= 64)
+
+
+@pytest.mark.parametrize("entry", ["simulate", "simulate_stream",
+                                   "simulate_batch"])
+def test_tiered_matches_dense_bitwise(entry, dense_burst):
+    tr, res_d = dense_burst
+    assert cpk.compact_tiers(TIERED)[0] == cpk.SMALL_TIER
+    if entry == "simulate":
+        res = jax.block_until_ready(engine.simulate(TIERED, tr))
+        _assert_tree_bitwise(_answers(res), _answers(res_d), entry)
+        small = int(res.counters.small_bucket_iters)
+        assert 0 < small < int(res.n_events)  # both tiers ran
+    elif entry == "simulate_stream":
+        wt = chunk_trace(tr, 40)
+        dense = dataclasses.replace(TIERED, compact=0)
+        res = jax.block_until_ready(engine.simulate_stream(TIERED, wt))
+        want = jax.block_until_ready(engine.simulate_stream(dense, wt))
+        _assert_tree_bitwise(_answers(res), _answers(want), entry)
+        small = int(res.counters.small_bucket_iters)
+        assert 0 < small < int(res.n_events)
+    else:
+        # lanes of different occupancy: the burst lane sets the tier of
+        # the quiet one, which must not change its bits
+        quiet = _tier_trace(0)
+        res = jax.block_until_ready(engine.simulate_batch(
+            TIERED, engine.stack_traces([tr, quiet]),
+            CloudParams.for_spec(TIERED)))
+        res_q = engine.simulate(dataclasses.replace(TIERED, compact=0),
+                                quiet)
+        for i, want in enumerate((res_d, res_q)):
+            lane = jax.tree.map(lambda x: x[i], res)
+            _assert_tree_bitwise(_answers(lane), _answers(want),
+                                 f"lane {i}")
+        small = np.asarray(res.counters.small_bucket_iters)
+        n = np.asarray(res.n_events)
+        assert 0 < small[0] < n[0] and small[1] < n[1]
+
+
+def test_small_bucket_iters():
+    quiet = _tier_trace(0)
+    res = engine.simulate(TIERED, quiet)
+    # nothing exceeds 64 flows: every iteration ran on the small tier
+    assert int(res.counters.small_bucket_iters) == int(res.n_events)
+    pinned = engine.simulate(dataclasses.replace(TIERED, compact=256),
+                             quiet)
+    assert int(pinned.counters.small_bucket_iters) == 0
+    _assert_tree_bitwise(_answers(res), _answers(pinned), "pinned")
+    dense = engine.simulate(dataclasses.replace(TIERED, compact=0), quiet)
+    assert int(dense.counters.small_bucket_iters) == 0

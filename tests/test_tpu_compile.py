@@ -147,6 +147,19 @@ def test_simulate_batch_compiles_for_v5e(one_chip):
     engine._simulate_batch_jit.lower(spec, tr, pp, t_stop).compile()
 
 
+def test_tiered_batch_compiles_for_v5e(one_chip):
+    """Two compaction tiers under vmap: the tier ``lax.cond`` on the
+    lanes' ``pmax`` must compile, and stay a conditional."""
+    from repro.core.loop import compact as cpk
+    spec, base = engine.make_cloud(n_pm=25, n_vm=512)
+    assert len(cpk.compact_tiers(spec)) == 2
+    params = engine.stack_params([base, base])
+    trace = synthetic_trace(64, 4, seed=0)
+    tr, pp, t_stop = _cloud_args(params, trace, one_chip)
+    hlo = engine._simulate_batch_jit.lower(spec, tr, pp, t_stop).compile()
+    assert "conditional(" in hlo.as_text()
+
+
 def test_stream_step_compiles_for_v5e(one_chip):
     spec, params = engine.make_cloud(n_pm=5, n_vm=256, pm_sched="ondemand")
     W = 128
