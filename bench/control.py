@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
-"""The comparison's control: the plain reference computed in bfloat16,
+"""The comparison's control: the plain reference computed in bfloat16
+(the cell's reference module's ``replay(..., precision="bfloat16")``),
 put in the program's place, for every lane of every input of a cell.
 
     python3 bench/control.py --workload <cell> --seeds 1 2 3
@@ -19,30 +20,28 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import harness  # noqa: E402
 from bench.reference import compare  # noqa: E402
-from bench.reference.cloud import simulate  # noqa: E402
 
 
 def control_numbers(cell, seed: int) -> dict:
     """Worst numbers of the bfloat16 reference against the reference over
     the cell's whole input pool and every lane."""
-    from bench.drivers import common
     driver = harness.load_module(harness.BENCH / "drivers"
                                  / f"{cell.driver}.py")
     wl = driver.Workload(cell, seed, devices=None)
     wl.host = wl.traces()
+    ref = wl.reference
     calls = []
     for item, tr in enumerate(wl.host):
         answers = []
         for lane in wl.lane_list:
-            got = simulate(common.ref_cloud(cell.config, lane),
-                           tr["arrival"], tr["cores"], tr["work"],
-                           precision="bfloat16")
+            got = ref.replay(ref.cloud(cell.config, lane), tr,
+                             precision="bfloat16")
             answers.append({**got, "n_events": got["steps"]})
         calls.append(harness.Call(
             item=item, start=0.0, end=0.0, tasks=0, lanes=wl.lanes,
             events=[0], dense_replays=0, failed=0, error=None,
             answers=answers))
-    return compare.check(cell.checks, wl.reference_jobs(calls), calls)
+    return compare.check(cell.checks, ref, wl.reference_jobs(calls), calls)
 
 
 def main(argv=None) -> int:

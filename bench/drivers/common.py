@@ -1,6 +1,6 @@
-"""What the entry drivers share: building the program's cloud and the
-reference's from a configuration file, one timed call with its host
-spans, and the per-lane answers read back from a result."""
+"""What the entry drivers share: building the program's cloud and trace
+from what a configuration file and a generator hold, one timed call with
+its host spans, and the per-lane answers read back from a result."""
 from __future__ import annotations
 
 import dataclasses
@@ -10,7 +10,6 @@ import warnings
 import numpy as np
 
 from bench.harness import Call
-from bench.reference.cloud import Cloud
 
 VM_POLICIES = ("firstfit", "nonqueuing", "smallestfirst")
 PM_POLICIES = ("alwayson", "ondemand")
@@ -47,18 +46,28 @@ def power_table(config: dict, scale: float = 1.0):
     return power_scale_grid([scale], base=base)[0]
 
 
+def _as_field(default, value):
+    """``value`` in the type of a field's default, so that a JSON ``64``
+    reaches a float field as ``64.0`` and a count stays an integer."""
+    if isinstance(default, bool) or not isinstance(default, (int, float)):
+        return value
+    return type(default)(value)
+
+
 def engine_cloud(config: dict, lanes: list[Lane]):
-    """``(spec, params)`` of the program for these lanes; ``params`` is
-    stacked along a leading batch axis when there is more than one."""
+    """``(spec, params)`` of the program for these lanes, from every key of
+    the configuration's ``cluster`` (``engine.make_cloud`` refuses a name
+    it does not know); ``params`` is stacked along a leading batch axis
+    when there is more than one."""
     from repro.core import engine
     from repro.core.energy import MeterTopology, hvac_spec
-    c = config["cluster"]
+    defaults = {f.name: f.default for cls in (engine.CloudSpec,
+                                              engine.CloudParams)
+                for f in dataclasses.fields(cls)}
+    cluster = {k: _as_field(defaults.get(k), v)
+               for k, v in config["cluster"].items()}
     spec, base = engine.make_cloud(
-        n_pm=c["n_pm"], n_vm=c["n_vm"], pm_cores=float(c["pm_cores"]),
-        perf_core=float(c["perf_core"]), net_bw=float(c["net_bw"]),
-        repo_bw=float(c["repo_bw"]), image_mb=float(c["image_mb"]),
-        boot_work=float(c["boot_work"]), latency_s=float(c["latency_s"]),
-        max_events=int(config["max_events"]),
+        **cluster, max_events=int(config["max_events"]),
         meters=MeterTopology(indirect=(
             hvac_spec(config["meters"]["hvac_pue_minus_one"]),)))
     points = [dataclasses.replace(base, vm_sched=ln.vm_sched,
@@ -70,37 +79,31 @@ def engine_cloud(config: dict, lanes: list[Lane]):
     return spec, engine.stack_params(points)
 
 
-def ref_cloud(config: dict, lane: Lane) -> Cloud:
-    """The same scenario for the plain reference."""
-    c, pw = config["cluster"], config["power"]
-    return Cloud(
-        n_pm=int(c["n_pm"]), n_vm=int(c["n_vm"]),
-        pm_cores=float(c["pm_cores"]), perf_core=float(c["perf_core"]),
-        net_bw=float(c["net_bw"]), repo_bw=float(c["repo_bw"]),
-        image_mb=float(c["image_mb"]), boot_work=float(c["boot_work"]),
-        latency_s=float(c["latency_s"]),
-        p_min=(pw["off_w"], pw["switching_on_w"], pw["idle_w"],
-               pw["switching_off_w"]),
-        p_max=(pw["off_w"], pw["switching_on_w"], pw["max_w"],
-               pw["switching_off_w"]),
-        boot_s=float(pw["boot_s"]), shutdown_s=float(pw["shutdown_s"]),
-        idle_scale=lane.idle_scale,
-        pue_minus_one=float(config["meters"]["hvac_pue_minus_one"]),
-        vm_sched=lane.vm_sched, pm_sched=lane.pm_sched)
+def trace_of(arrays: dict):
+    """The program's ``engine.Trace`` of every array in ``arrays`` (a
+    generator's trace or window) that names one of its fields; the fields
+    it leaves out keep their defaults (``gid`` is None for a whole
+    trace)."""
+    import jax.numpy as jnp
+    from repro.core import engine
+    return engine.Trace(**{k: jnp.asarray(v) for k, v in arrays.items()
+                           if k in engine.Trace._fields})
 
 
 def pick(res):
-    """The device arrays a caller reads back from a result."""
+    """The device arrays a caller reads back from a result, with every
+    field of the loop's counters."""
     return {"completion": res.completion, "rejected": res.rejected,
             "pm_energy": res.meters.pm.energy_hi,
             "iaas_total": res.meters.total.energy_hi,
             "indirect": res.meters.indirect.energy_hi,
             "t_end": res.t_end, "n_events": res.n_events,
-            "overflow": res.overflow}
+            "overflow": res.overflow, "counters": res.counters._asdict()}
 
 
 def split_lanes(host: dict, n_lanes: int, batched: bool) -> list[dict]:
-    """Per-lane answers (numpy) from a read-back result."""
+    """Per-lane answers (numpy) from a read-back result; ``counters``
+    holds each counter's plain value (an int, or a list of ints)."""
     def lane(b):
         g = (lambda x: np.asarray(x)[b]) if batched else np.asarray
         return {"completion": g(host["completion"]),
@@ -110,8 +113,20 @@ def split_lanes(host: dict, n_lanes: int, batched: bool) -> list[dict]:
                 "hvac": float(g(host["indirect"])[0]),
                 "t_end": float(g(host["t_end"])),
                 "n_events": int(g(host["n_events"])),
-                "overflow": bool(g(host["overflow"]))}
+                "overflow": bool(g(host["overflow"])),
+                "counters": {k: g(v).tolist()
+                             for k, v in host["counters"].items()}}
     return [lane(b) for b in range(n_lanes)]
+
+
+def counter_per_event(calls, name: str) -> float | None:
+    """A loop counter summed over the lanes of the window's calls, over
+    their summed ``n_events``; None where no answer carries it."""
+    lanes = [a for c in calls if not c.error for a in c.answers]
+    events = sum(a["n_events"] for a in lanes)
+    if not events or any(name not in a.get("counters", {}) for a in lanes):
+        return None
+    return sum(a["counters"][name] for a in lanes) / events
 
 
 def lane_failed(ans: dict, max_events: int) -> bool:

@@ -6,7 +6,7 @@ which loads it without replaying the trace."""
 from __future__ import annotations
 
 from bench.drivers import common
-from bench.harness import BENCH, load_module
+from bench.harness import BENCH, load_module, load_reference
 
 
 class Workload:
@@ -17,6 +17,7 @@ class Workload:
         self.lane_list = common.lanes_of(cell.config, cell.traffic)
         self.lanes = len(self.lane_list)
         self.max_events = int(cell.config["max_events"])
+        self.reference = load_reference(cell)
 
     # -- inputs --------------------------------------------------------
     def traces(self) -> list[dict]:
@@ -31,16 +32,14 @@ class Workload:
 
     def setup(self):
         import jax
-        import jax.numpy as jnp
         from repro.core import engine
         self.engine = engine
         self.spec, self.params = common.engine_cloud(self.cell.config,
                                                      self.lane_list)
         self.host = self.traces()
         dev = self.devices[0]
-        self.pool = [jax.device_put(engine.Trace(
-            arrival=jnp.asarray(h["arrival"]), cores=jnp.asarray(h["cores"]),
-            work=jnp.asarray(h["work"])), dev) for h in self.host]
+        self.pool = [jax.device_put(common.trace_of(h), dev)
+                     for h in self.host]
         self.params = jax.device_put(self.params, dev)
         self.n_tasks = int(self.cell.traffic["n_tasks"])
 
@@ -73,7 +72,8 @@ class Workload:
     # -- the check -----------------------------------------------------
     def reference_jobs(self, calls) -> dict:
         items = sorted({c.item for c in calls})
-        return {(i, b): (common.ref_cloud(self.cell.config, ln), self.host[i])
+        return {(i, b): (self.reference.cloud(self.cell.config, ln),
+                         self.host[i])
                 for i in items for b, ln in enumerate(self.lane_list)}
 
     def release(self):

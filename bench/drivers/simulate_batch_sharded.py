@@ -12,16 +12,12 @@ class Workload(simulate.Workload):
     batched = True
 
     def setup(self):
-        import jax.numpy as jnp
         from repro.core import engine
         self.engine = engine
         self.spec, self.params = common.engine_cloud(self.cell.config,
                                                      self.lane_list)
         self.host = self.traces()
-        self.pool = [engine.Trace(arrival=jnp.asarray(h["arrival"]),
-                                  cores=jnp.asarray(h["cores"]),
-                                  work=jnp.asarray(h["work"]))
-                     for h in self.host]
+        self.pool = [common.trace_of(h) for h in self.host]
         self.n_tasks = int(self.cell.traffic["n_tasks"])
 
     def entry(self, trace, t_stop):

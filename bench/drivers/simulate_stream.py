@@ -8,7 +8,7 @@ window step and the final assembly run at their real shapes (as long as
 the slot pool holds all the call's tasks), without replaying the trace."""
 from __future__ import annotations
 
-from bench.drivers import simulate
+from bench.drivers import common, simulate
 from bench.harness import BENCH, load_module
 
 
@@ -26,17 +26,14 @@ class Workload(simulate.Workload):
 
     def setup(self):
         import jax
-        import jax.numpy as jnp
         from repro.core import engine
         self.engine = engine
-        self.spec, self.params = simulate.common.engine_cloud(
-            self.cell.config, self.lane_list)
+        self.spec, self.params = common.engine_cloud(self.cell.config,
+                                                     self.lane_list)
         self.host = self.traces()
         dev = self.devices[0]
-        self.pool = [[jax.device_put(engine.Trace(
-            arrival=jnp.asarray(w["arrival"]), cores=jnp.asarray(w["cores"]),
-            work=jnp.asarray(w["work"]), gid=jnp.asarray(w["gid"])), dev)
-            for w in wins] for wins in self.windows_host]
+        self.pool = [[jax.device_put(common.trace_of(w), dev) for w in wins]
+                     for wins in self.windows_host]
         self.params = jax.device_put(self.params, dev)
         self.n_tasks = int(self.cell.traffic["n_tasks"])
 

@@ -11,8 +11,25 @@ or metric lives in a file of its own and is found by the name that
   entry driver, trace lengths, the seed pool, the lanes of a sweep;
 * ``bench/generators/<name>.py``    — trace generators;
 * ``bench/drivers/<name>.py``       — one per program entry point;
+* ``bench/reference/<name>.py``     — the plain reference a traffic file
+  names under ``"reference"`` (``cloud`` when it names none):
+  ``cloud(config, lane)`` and ``replay(cloud, trace, ...)``;
 * ``bench/metrics/<metric>.py``     — one reader per metric;
 * ``bench/checks/<check>.json``     — the limits of the comparison.
+
+Three seams take a new deployment without an edit to the harness:
+
+* the reference module, named by the traffic file as above;
+* the program is built from what the files hold: every key of the
+  configuration's ``cluster`` goes to ``engine.make_cloud``, and every
+  array the generator returns that names a field of ``engine.Trace``
+  goes into the trace (``bench/drivers/common.py``);
+* a metric reader gets ``ctx`` with the window's ``calls``, whose lane
+  answers carry every field of the program's ``res.counters`` under
+  ``"counters"``, and in the traced run ``traced``, whose ``"stages"``
+  hold the device time per loop iteration of each stage scope and
+  ``"idle_by_label"`` the device idle time by the host span it fell in
+  (``bench/trace_reduce.py``, ``bench/stage_reduce.py``).
 """
 from __future__ import annotations
 
@@ -77,6 +94,15 @@ class Cell:
     @property
     def driver(self) -> str:
         return self.traffic["driver"]
+
+    @property
+    def reference(self) -> str:
+        return self.traffic.get("reference", "cloud")
+
+
+def load_reference(cell: Cell):
+    """The plain reference module the cell's traffic names."""
+    return load_module(BENCH / "reference" / f"{cell.reference}.py")
 
 
 def _applies(metric: dict, workload: str) -> bool:
@@ -214,7 +240,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     jobs = wl.reference_jobs(calls)
     wl.release()
     from bench.reference import compare
-    checks = compare.check(cell.checks, jobs, calls)
+    checks = compare.check(cell.checks, wl.reference, jobs, calls)
 
     ctx = dict(cell=cell, calls=calls, setup_s=setup_s, clock=clock,
                traced=traced)

@@ -43,6 +43,11 @@ simultaneous with it).
 ``simulate(..., precision="bfloat16")`` is the comparison's control: the
 same loop with every stored time, amount, rate and energy rounded to
 bfloat16 (the clock always moves forward by at least one bfloat16 step).
+
+As a reference module of the harness (a traffic file's ``"reference"``,
+this one by default) it exposes ``cloud(config, lane)``, the scenario of
+one lane of a cell, and ``replay(cloud, trace, ...)``, which runs it over
+the whole trace dict that the cell's generator made.
 """
 from __future__ import annotations
 
@@ -357,3 +362,34 @@ def simulate(cloud: Cloud, arrival, cores, work, *, precision="float64",
         "steps": steps,
         "overflow": overflow,
     }
+
+
+# ---- the interface the harness calls (bench/reference/compare.py)
+
+def cloud(config: dict, lane) -> Cloud:
+    """The scenario of a configuration file for one lane of a cell: its
+    ``vm_sched``, ``pm_sched`` and ``idle_scale``."""
+    c, pw = config["cluster"], config["power"]
+    return Cloud(
+        n_pm=int(c["n_pm"]), n_vm=int(c["n_vm"]),
+        pm_cores=float(c["pm_cores"]), perf_core=float(c["perf_core"]),
+        net_bw=float(c["net_bw"]), repo_bw=float(c["repo_bw"]),
+        image_mb=float(c["image_mb"]), boot_work=float(c["boot_work"]),
+        latency_s=float(c["latency_s"]),
+        p_min=(pw["off_w"], pw["switching_on_w"], pw["idle_w"],
+               pw["switching_off_w"]),
+        p_max=(pw["off_w"], pw["switching_on_w"], pw["max_w"],
+               pw["switching_off_w"]),
+        boot_s=float(pw["boot_s"]), shutdown_s=float(pw["shutdown_s"]),
+        idle_scale=lane.idle_scale,
+        pue_minus_one=float(config["meters"]["hvac_pue_minus_one"]),
+        vm_sched=lane.vm_sched, pm_sched=lane.pm_sched)
+
+
+def replay(cloud: Cloud, trace: dict, *, finish_frac=1e-6, tie_window=0.0,
+           precision="float64") -> dict:
+    """:func:`simulate` over a whole trace dict (``arrival``, ``cores``,
+    ``work``)."""
+    return simulate(cloud, trace["arrival"], trace["cores"], trace["work"],
+                    precision=precision, finish_frac=finish_frac,
+                    tie_window=tie_window)

@@ -1,8 +1,13 @@
 """The comparison that decides ``correct``.
 
 Every lane of every call of the window is compared with the plain
-reference (``bench/reference/cloud.py``) run once over the same trace and
-scenario.  Each number is the worst over all of them:
+reference that the cell names (a traffic file's ``"reference"``,
+``bench/reference/<name>.py``, ``cloud`` by default) run once over the
+same trace and scenario.  A reference module exposes ``cloud(config,
+lane)``, the scenario of one lane, and ``replay(cloud, trace, *,
+finish_frac, tie_window)`` over the whole trace dict, returning
+``completion``, ``rejected``, ``pm_energy``, ``iaas_total``, ``hvac`` and
+``t_end``.  Each number is the worst over all of them:
 
 * ``fate_mismatch`` — tasks whose fate differs: done, rejected, or
   neither (which tasks start and which are rejected);
@@ -32,8 +37,6 @@ window, so a wrong answer of any size beyond it fails all of them.
 from __future__ import annotations
 
 import numpy as np
-
-from bench.reference.cloud import simulate
 
 NUMBERS = ("fate_mismatch", "completion_rel", "pm_energy_rel",
            "energy_total_rel", "clock_rel")
@@ -81,18 +84,17 @@ def failed(n_tasks: int) -> dict:
 
 
 class References:
-    """The reference of each job, run once per reading and only when
-    asked for."""
+    """The reference of each job ``key: (cloud, trace)``, run by the
+    reference module once per reading and only when asked for."""
 
-    def __init__(self, jobs: dict):
-        self.jobs, self.done = jobs, {}
+    def __init__(self, reference, jobs: dict):
+        self.reference, self.jobs, self.done = reference, jobs, {}
 
     def __call__(self, key, reading=STRICT):
         if (key, reading) not in self.done:
             cloud, tr = self.jobs[key]
-            self.done[(key, reading)] = simulate(
-                cloud, tr["arrival"], tr["cores"], tr["work"],
-                finish_frac=reading[0], tie_window=reading[1])
+            self.done[(key, reading)] = self.reference.replay(
+                cloud, tr, finish_frac=reading[0], tie_window=reading[1])
         return self.done[(key, reading)]
 
 
@@ -113,10 +115,10 @@ def lane_numbers(ans, key, refs: References, limits: dict) -> dict:
     return got
 
 
-def check(limits: dict, jobs: dict, calls) -> dict:
+def check(limits: dict, reference, jobs: dict, calls) -> dict:
     """``{"correct", "numbers": {name: {"value", "limit"}}}`` over every
-    lane of every call."""
-    refs = References(jobs)
+    lane of every call, against the reference module ``reference``."""
+    refs = References(reference, jobs)
     worst = {n: 0 if n == "fate_mismatch" else 0.0 for n in NUMBERS}
     for c in calls:
         for lane in range(c.lanes):

@@ -24,6 +24,8 @@ reduces:
   innermost covering ``bench.*`` or ``repro.*`` host span; a trace with
   no ``repro.*`` span (a program without them) keeps the labels of
   ``trace_reduce``;
+* ``idle_by_label`` — all of those gaps' device idle time summed by
+  label, averaged over the devices;
 * ``entry`` — the program's outermost ``repro.<entry>`` span: its
   window, the device idle time inside it, the labels of the idle gaps
   that lie inside it, and the share of device busy time that lies inside
@@ -38,7 +40,6 @@ benchmark reads the trace, never the code under test.
 from __future__ import annotations
 
 import argparse
-import gzip
 import json
 import pathlib
 import re
@@ -144,10 +145,18 @@ def read(path) -> tuple[object, dict]:
     path = pathlib.Path(path)
     if path.is_dir():
         path = trace_reduce.newest_xplane(path)
-    raw = path.read_bytes()
-    if path.suffix == ".gz":
-        raw = gzip.decompress(raw)
+    raw = trace_reduce.raw_bytes(path)
     return ProfileData.from_serialized_xspace(raw), op_paths(raw)
+
+
+def stage_us(traced: dict | None, stage: str) -> float | None:
+    """Device microseconds per loop iteration of one stage scope in a
+    reduced trace, its policy bodies (``<stage>/<policy>``) included;
+    None where the trace holds no op of that scope."""
+    stages = (traced or {}).get("stages") or {}
+    times = [t for k, t in stages.items()
+             if k == stage or k.startswith(stage + "/")]
+    return 1e6 * sum(times) if times else None
 
 
 # ------------------------------------------------------------ attribution
@@ -283,12 +292,16 @@ def reduce(profile, paths: dict, iterations: int | None = None,
         return None
     n_dev = len(per_device)
     div = n_dev * (iterations or 1)
+    by_label = {}
+    for t, name in gaps:
+        by_label[name] = by_label.get(name, 0.0) + t / n_dev
     out = {
         "n_devices": n_dev,
         "stages": {k: v / div for k, v in
                    sorted(stage_t.items(), key=lambda kv: -kv[1])},
         "idle_gaps": [[name, t] for t, name in
                       sorted(gaps, key=lambda g: -g[0])[:top]],
+        "idle_by_label": by_label,
         "entry": None,
     }
     if entry is not None:

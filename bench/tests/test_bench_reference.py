@@ -2,11 +2,13 @@
 the CPU, at a tiny size, for every VM x PM scheduler pair the cells run."""
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
 from bench.drivers import common
 from bench.generators import gwa
+from bench.reference import cloud as reference
 from bench.reference import compare
 from bench.reference.cloud import Cloud, maxmin, simulate
 
@@ -32,7 +34,7 @@ def engine_runs():
         tr["arrival"] = (tr["arrival"] // 400 * 400).astype(np.float32)
         res = engine.simulate_batch(spec, engine.Trace(
             **{k: np.asarray(v) for k, v in tr.items()}), params)
-        host = {k: np.asarray(v) for k, v in common.pick(res).items()}
+        host = jax.device_get(common.pick(res))
         out[seed] = (tr, lanes, common.split_lanes(host, len(lanes), True))
     return out
 
@@ -44,7 +46,7 @@ def test_reference_matches_engine(engine_runs, vm, pm, seed):
     tr, lanes, answers = engine_runs[seed]
     b = lanes.index(common.Lane(vm, pm, 0.8))
     cloud = Cloud(n_pm=4, n_vm=32, vm_sched=vm, pm_sched=pm, idle_scale=0.8)
-    refs = compare.References({0: (cloud, tr)})
+    refs = compare.References(reference, {0: (cloud, tr)})
     got = compare.lane_numbers(answers[b], 0, refs, LIMITS)
     assert compare.passes(got, LIMITS), got
 

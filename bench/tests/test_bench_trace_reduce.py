@@ -1,12 +1,16 @@
-"""The trace reduction on a small profiler trace recorded on one TPU v5e
-(one sliced call of das2-500pm.trace1k), and its interval arithmetic."""
+"""The trace reduction on small profiler traces recorded on one TPU v5e
+(one sliced call of das2-500pm.trace1k, from a program without and with
+stage scopes and host spans), and its interval arithmetic."""
 import pathlib
 
 import pytest
 
-from bench import trace_reduce
+from bench import harness, trace_reduce
 
 TRACE = pathlib.Path(__file__).parent / "data" / "small.xplane.pb.gz"
+SCOPED = pathlib.Path(__file__).parent / "data" / "small_scoped.xplane.pb.gz"
+STAGE_METRICS = ("advance_us", "observe_us", "vm_lifecycle_us",
+                 "pm_sched_us", "vm_sched_us")
 
 
 @pytest.fixture(scope="module")
@@ -44,3 +48,38 @@ def test_self_time_and_union():
     assert trace_reduce._union([(3, 4), (0, 2), (1, 3)]) == [[0, 4]]
     assert trace_reduce.op_name("%fusion.3 = f32[8]{0} fusion(x)") == \
         "fusion.3"
+
+
+def _read(name, traced):
+    reader = harness.load_module(harness.BENCH / "metrics" / f"{name}.py")
+    return reader.read({"traced": traced, "calls": []})
+
+
+def test_capture_merges_the_stage_times():
+    """What ``capture`` reduces a traced call to carries the program's
+    stage times per iteration, which add up to ``iter_us``, and its
+    labelled idle time."""
+    red = trace_reduce.reduce_raw(trace_reduce.raw_bytes(SCOPED),
+                                  iterations=12)
+    iter_us = _read("iter_us", red)
+    assert sum(red["stages"].values()) * 1e6 == pytest.approx(iter_us,
+                                                              rel=0.05)
+    for name in STAGE_METRICS:
+        assert 0 < _read(name, red) < iter_us
+    assert red["entry"]["name"] == "repro.simulate"
+    assert any(n.startswith("repro.") for n, _ in
+               red["breakdown"]["idle_gaps"])
+    idle = red["window_s"] - red["busy_s"]
+    assert sum(red["idle_by_label"].values()) == pytest.approx(idle,
+                                                               rel=1e-6)
+    assert 0 < _read("entry_idle_ms", red) < idle * 1e3
+
+
+def test_unscoped_trace_gives_no_stage_metrics():
+    red = trace_reduce.reduce_raw(trace_reduce.raw_bytes(TRACE),
+                                  iterations=12)
+    assert red["entry"] is None
+    assert red["breakdown"]["idle_gaps"] == trace_reduce.reduce(
+        trace_reduce.load(TRACE))["breakdown"]["idle_gaps"]
+    for name in STAGE_METRICS + ("entry_idle_ms",):
+        assert _read(name, red) is None

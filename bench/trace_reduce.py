@@ -17,6 +17,13 @@ The trace of one sliced call (``capture``) is read with
 * ``program_runs`` — ``[start_s, end_s]`` of each execution (line
   ``XLA Modules``) of the program whose name holds the given word.
 
+``reduce_raw`` merges into these what ``bench/stage_reduce.py`` reads from
+the same trace's raw bytes: ``stages`` (device seconds per loop iteration
+of each of the program's stage scopes), ``entry`` (its entry span, or
+None), ``idle_by_label`` (device idle seconds by the host span they fell
+in), and the idle gaps labelled with the program's ``repro.*`` spans in
+place of the ``bench.*`` ones.
+
 Times are seconds on the profiler's clock; host and device events share
 it.
 """
@@ -142,16 +149,19 @@ def reduce(profile, program: str | None = None, top: int = 10) -> dict:
     }
 
 
+def raw_bytes(path) -> bytes:
+    """The serialized ``XSpace`` of an ``.xplane.pb`` file, gzipped or
+    not."""
+    import gzip
+    path = pathlib.Path(path)
+    raw = path.read_bytes()
+    return gzip.decompress(raw) if path.suffix == ".gz" else raw
+
+
 def load(path) -> object:
     """A ``ProfileData`` from an ``.xplane.pb`` file, gzipped or not."""
-    import gzip
-
     from jax.profiler import ProfileData
-    path = pathlib.Path(path)
-    if path.suffix == ".gz":
-        return ProfileData.from_serialized_xspace(
-            gzip.decompress(path.read_bytes()))
-    return ProfileData.from_file(str(path))
+    return ProfileData.from_serialized_xspace(raw_bytes(path))
 
 
 def newest_xplane(tdir) -> pathlib.Path:
@@ -162,9 +172,34 @@ def newest_xplane(tdir) -> pathlib.Path:
     return found[-1]
 
 
+def reduce_raw(raw: bytes, program: str | None = None,
+               iterations: int | None = None) -> dict:
+    """``reduce`` of a serialized trace merged with ``stage_reduce``'s
+    stages (per iteration when ``iterations`` is given), entry span and
+    labelled idle gaps; the reduction of a trace with no device op holds
+    ``None`` and empty lists."""
+    from jax.profiler import ProfileData
+
+    from bench import stage_reduce
+    profile = ProfileData.from_serialized_xspace(raw)
+    red = reduce(profile, program=program) or {
+        "busy_s": None, "window_s": None, "n_devices": 0,
+        "breakdown": {"device_ops": [], "idle_gaps": []},
+        "program_runs": []}
+    staged = stage_reduce.reduce(profile, stage_reduce.op_paths(raw),
+                                 iterations) or {
+        "stages": {}, "entry": None, "idle_by_label": {}, "idle_gaps": []}
+    red["iterations"] = iterations
+    red["stages"] = staged["stages"]
+    red["entry"] = staged["entry"]
+    red["idle_by_label"] = staged["idle_by_label"]
+    red["breakdown"]["idle_gaps"] = staged["idle_gaps"]
+    return red
+
+
 def capture(traced_call, tdir) -> dict:
     """Run ``traced_call`` under the profiler, writing the trace to
-    ``tdir``; return its reduction merged with what the call reports:
+    ``tdir``; return ``reduce_raw`` of it with what the call reports:
     ``iterations`` (loop iterations it ran) and ``program_word`` (a word
     of the program whose executions ``program_runs`` lists, or None)."""
     import jax
@@ -173,10 +208,6 @@ def capture(traced_call, tdir) -> dict:
         out = traced_call()
     finally:
         jax.profiler.stop_trace()
-    red = reduce(load(newest_xplane(tdir)),
-                 program=out.get("program_word")) or {
-        "busy_s": None, "window_s": None, "n_devices": 0,
-        "breakdown": {"device_ops": [], "idle_gaps": []},
-        "program_runs": []}
-    red["iterations"] = out["iterations"]
-    return red
+    return reduce_raw(raw_bytes(newest_xplane(tdir)),
+                      program=out.get("program_word"),
+                      iterations=out["iterations"])
