@@ -205,6 +205,8 @@ class CloudParams:
     image_mb: object = 100.0      # VM image size (paper §4.2.2 uses 100 MB)
     boot_work: object = 10.0      # core-seconds of boot processing
     vm_mem_mb: object = 1024.0    # serialized memory state (migration)
+    pm_mem: object = 256.0        # GB of memory per PM (read only when the
+    #                               trace carries ``mem``, DESIGN.md §7)
     latency_s: object = 0.001
     metering_period: object = 0.0  # 0 => exact integration only (no ticks)
     hidden_work_on: object = 40.0  # core-s consumed while switching on (complex)
@@ -271,12 +273,21 @@ class Trace(NamedTuple):
     ``i32[T]`` array for a slot-table window where recycled slots hold
     arbitrary ids and ``-1`` marks a free/padded slot.  ``None`` is not a
     pytree leaf, so monolithic traces batch/vmap exactly as before.
+
+    ``mem`` and ``util`` (DESIGN.md §7) give a VM request a second
+    resource dimension, GB of memory that placement must find free on the
+    host, and the share of its cores the VM uses once booted (its task
+    runs at ``util * cores * perf_core``).  ``None`` leaves each out, and
+    the engine then compiles the program it compiled without them.
     """
 
     arrival: jax.Array  # f32[T] submission times (sorted not required)
     cores: jax.Array    # f32[T]
-    work: jax.Array     # f32[T] total processing units (= runtime*cores*perf)
+    work: jax.Array     # f32[T] total processing units (= runtime*cores*perf,
+    #                     times util where the trace has util)
     gid: jax.Array | None = None  # i32[T] global ids (streaming); -1 = free
+    mem: jax.Array | None = None   # f32[T] GB of memory the VM reserves
+    util: jax.Array | None = None  # f32[T] share of its cores the VM uses
 
     @property
     def n(self) -> int:
@@ -362,6 +373,9 @@ def init_state(spec: CloudSpec, trace: Trace,
     pstate0 = jnp.broadcast_to(
         jnp.where(start_running, PM_RUNNING, PM_OFF), (P,)).astype(jnp.int8)
     period = jnp.asarray(params.metering_period, jnp.float32)
+    mem = {} if trace.mem is None else dict(
+        free_mem=jnp.full((P,), jnp.asarray(params.pm_mem, jnp.float32)),
+        vm_mem=jnp.zeros((V,), jnp.float32), mem_bound=jnp.int32(0))
     return CloudState(
         t=jnp.float32(0.0), t_c=jnp.float32(0.0), n_events=jnp.int32(0),
         f_pr=zf, f_total=zf, f_pl=zf + _BIG, f_prov=zi, f_cons=zi,
@@ -384,6 +398,7 @@ def init_state(spec: CloudSpec, trace: Trace,
         processed=jnp.zeros((lay.S,), jnp.float32),
         overflow=jnp.bool_(False),
         running=jnp.bool_(True),
+        **mem,
     )
 
 
@@ -645,16 +660,20 @@ def default_n_slots(spec: CloudSpec, window: int) -> int:
 
 
 def init_stream(spec: CloudSpec, n_slots: int,
-                params: CloudParams | None = None) -> StreamCarry:
+                params: CloudParams | None = None,
+                like: Trace | None = None) -> StreamCarry:
     """The streaming engine's initial carry: an empty slot table and a
     :func:`init_state` whose every task slot is free (inert ``TASK_DONE``,
-    ``arrival == inf``)."""
+    ``arrival == inf``).  The slot table carries ``mem`` and ``util``
+    where the window ``like`` does."""
     Q = int(n_slots)
+    zq = jnp.zeros((Q,), jnp.float32)
     slots = Trace(
         arrival=jnp.full((Q,), jnp.inf, jnp.float32),
-        cores=jnp.zeros((Q,), jnp.float32),
-        work=jnp.zeros((Q,), jnp.float32),
+        cores=zq, work=zq,
         gid=jnp.full((Q,), -1, jnp.int32),
+        mem=None if like is None or like.mem is None else zq,
+        util=None if like is None or like.util is None else zq,
     )
     st = init_state(spec, slots, params)
     st = st._replace(task_state=jnp.full((Q,), TASK_DONE, jnp.int8))
@@ -704,12 +723,8 @@ def _stream_step_impl(spec: CloudSpec, carry: StreamCarry, window: Trace,
         pos = jnp.cumsum(valid) - 1               # each incoming task's rank
         take = valid & (pos < jnp.sum(free))
         dest = jnp.where(take, slot_of_rank[jnp.clip(pos, 0, Q - 1)], Q)
-        slots = Trace(
-            arrival=slots.arrival.at[dest].set(window.arrival, mode="drop"),
-            cores=slots.cores.at[dest].set(window.cores, mode="drop"),
-            work=slots.work.at[dest].set(window.work, mode="drop"),
-            gid=slots.gid.at[dest].set(window.gid, mode="drop"),
-        )
+        slots = jax.tree.map(
+            lambda a, b: a.at[dest].set(b, mode="drop"), slots, window)
         st = st._replace(
             task_state=st.task_state.at[dest].set(TASK_PENDING,
                                                   mode="drop"),
@@ -753,12 +768,7 @@ def _stream_step_impl(spec: CloudSpec, carry: StreamCarry, window: Trace,
             "t_end": st.t,
             "energy": jnp.sum(st.meters.pm.energy),
         }
-        slots = Trace(
-            arrival=jnp.where(term, jnp.inf, slots.arrival),
-            cores=jnp.where(term, 0.0, slots.cores),
-            work=jnp.where(term, 0.0, slots.work),
-            gid=jnp.where(term, -1, slots.gid),
-        )
+        slots = _free_slots(slots, term)
         st = st._replace(
             task_state=jnp.where(term, TASK_DONE, st.task_state),
             task_vm=jnp.where(term, -1, st.task_vm),
@@ -778,6 +788,15 @@ def _stream_step(spec: CloudSpec, carry: StreamCarry, window: Trace,
     trace re-traces nothing after the first window."""
     return _stream_step_impl(spec, carry, window, params,
                              t_prev_next, t_next, t_stop)
+
+
+def _free_slots(slots: Trace, free: jax.Array) -> Trace:
+    """``slots`` with the entries under ``free`` reset to a free slot:
+    ``arrival == inf``, ``gid == -1``, every other field 0."""
+    fill = Trace(arrival=jnp.inf, cores=0.0, work=0.0, gid=-1, mem=0.0,
+                 util=0.0)
+    return Trace(*(None if a is None else jnp.where(free, f, a)
+                   for a, f in zip(slots, fill)))
 
 
 def _as_window_iter(windows, window_size=None):
@@ -808,17 +827,11 @@ def _as_window_iter(windows, window_size=None):
                         f"window of {w.n} tasks exceeds the stream's "
                         f"window size {W}; all windows must share one "
                         f"shape (pad the last window, as chunk_trace does)")
-                pad = W - w.n
-                w = Trace(
-                    arrival=jnp.concatenate(
-                        [w.arrival, jnp.full((pad,), jnp.inf, jnp.float32)]),
-                    cores=jnp.concatenate(
-                        [w.cores, jnp.zeros((pad,), jnp.float32)]),
-                    work=jnp.concatenate(
-                        [w.work, jnp.zeros((pad,), jnp.float32)]),
-                    gid=jnp.concatenate(
-                        [w.gid, jnp.full((pad,), -1, jnp.int32)]),
-                )
+                tail = jax.tree.map(lambda a: jnp.zeros((W - w.n,), a.dtype),
+                                    w)
+                tail = _free_slots(tail, jnp.ones((W - w.n,), bool))
+                w = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), w,
+                                 tail)
             yield w
 
     return gen(), window_size
@@ -866,7 +879,7 @@ def _simulate_stream(spec, windows, params, n_slots, t_stop):
             it, _ = _as_window_iter(_chain_one(cur, it), window_size=cur.n)
             cur = next(it)
         Q = default_n_slots(spec, cur.n) if n_slots is None else int(n_slots)
-        carry = init_stream(spec, Q, params)
+        carry = init_stream(spec, Q, params, like=cur)
         t_stop = jnp.asarray(t_stop, jnp.float32)
         # t_prev_next = 0 makes the first step run the monolithic pre-loop
         # management pass (the clock starts at 0 >= 0).

@@ -63,11 +63,12 @@ def equal_share_rates(
     live: jax.Array,
     perf: jax.Array,
     *,
-    backend: str = "jnp",   # registry-uniform signature; unused
-    max_iters: int = 0,     # registry-uniform signature; unused
+    backend: str = "jnp",    # registry-uniform signature; unused
+    max_iters: int = 0,      # registry-uniform signature; unused
+    flow_caps: bool = False,  # registry-uniform signature; unused
 ) -> jax.Array:
     """rate = min(perf[prov]/n_prov, perf[cons]/n_cons, p_l)."""
-    del backend, max_iters
+    del backend, max_iters, flow_caps
     offer_p, offer_c = _equal_share_offers(provider, consumer, live, perf)
     r = jnp.minimum(jnp.minimum(offer_p, offer_c), p_l)
     return jnp.where(live, r, 0.0)
@@ -113,34 +114,50 @@ def maxmin_fill(
     max_iters: int = 64,
     backend: str = "jnp",
     rel_eps: float = 1e-5,
-) -> tuple[jax.Array, jax.Array]:
-    """Max-min fair rates by progressive filling, and the rounds it took.
+    flow_caps: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Max-min fair rates by progressive filling, the rounds it took, and
+    whether it stopped at ``max_iters`` with a flow still unfrozen.
 
     All unfrozen flows rise at the same global increment until a constraint
     (provider capacity, consumer capacity, or the flow's own ``p_l``)
-    saturates; saturated flows freeze; repeat.  Terminates when every flow is
-    frozen — each round freezes at least one flow, and the number of distinct
-    bottleneck levels is bounded by the spreader count, so ``max_iters``
-    bounds compile-time work without changing results in practice.
+    saturates; saturated flows freeze; repeat.  Each round freezes at least
+    one flow.
+
+    ``flow_caps`` (static) says that a flow's own cap ``p_l`` may lie below
+    the share of its spreaders, as a VM's utilisation cap does (DESIGN.md
+    §7).  The round's increment is then the smallest spreader share alone;
+    each flow rises by it or up to its cap, whichever is less; every flow
+    at its cap freezes, and a flow at a spreader that reached its share
+    freezes only where no flow at that spreader stopped below the
+    increment (such a spreader still has room).  Rounds are then bounded by
+    the spreader levels, not by the number of distinct caps.  Without caps
+    below the shares no flow stops early, and the rule is the one without
+    ``flow_caps``: the same increments and freezes.
 
     ``backend='pallas'`` solves the whole progressive filling in one fused
     kernel when the problem fits VMEM (``repro.kernels.maxmin.maxmin_solve``
     — the carried rate/freeze vectors never round-trip HBM between rounds),
     falling back to the round-wise Pallas ``fill_stats`` kernel above that
-    size; ``'jnp'`` uses segment_sum throughout.  The fused kernel does not
-    report its rounds: it returns a round count of 0.
+    size or under ``flow_caps``; ``'jnp'`` uses segment_sum throughout.
+    The fused kernel does not report its rounds: it returns a round count
+    of 0 and no truncation.
     """
     if backend == "pallas":
         from repro.kernels import ops as _kops
-        if _kops.maxmin_solve_fits(provider.shape[0], perf.shape[0]):
+        if (not flow_caps
+                and _kops.maxmin_solve_fits(provider.shape[0],
+                                            perf.shape[0])):
             return _kops.maxmin_solve_pallas(
                 provider, consumer, p_l, live, perf,
-                max_iters=max_iters, rel_eps=rel_eps), jnp.int32(0)
+                max_iters=max_iters, rel_eps=rel_eps), jnp.int32(0), \
+                jnp.bool_(False)
         fill_stats = _kops.fill_stats_pallas
     else:
         fill_stats = _jnp_fill_stats
 
     C = provider.shape[0]
+    S = perf.shape[0]
     r0 = jnp.zeros((C,), jnp.float32)
     unfrozen0 = live
 
@@ -151,41 +168,60 @@ def maxmin_fill(
     def body(state):
         i, r, unfrozen = state
         dp, dc = fill_stats(provider, consumer, r, live, unfrozen, perf)
-        df = jnp.minimum(dp[provider], dc[consumer])
-        df = jnp.minimum(df, jnp.maximum(p_l - r, 0.0))
+        sp, sc = dp[provider], dc[consumer]
+        room = jnp.maximum(p_l - r, 0.0)
+        df = jnp.minimum(sp, sc)
+        if not flow_caps:
+            df = jnp.minimum(df, room)
         df = jnp.where(unfrozen, df, _BIG)
         delta = jnp.min(df)
         delta = jnp.where(jnp.isfinite(delta) & (delta < _BIG), delta, 0.0)
-        r = jnp.where(unfrozen, r + delta, r)
-        # freeze flows whose own constraint bound the round
-        tight = df <= delta * (1.0 + rel_eps) + 1e-12
+        level = delta * (1.0 + rel_eps) + 1e-12
+        if not flow_caps:
+            r = jnp.where(unfrozen, r + delta, r)
+            # freeze flows whose own constraint bound the round
+            tight = df <= level
+        else:
+            r = jnp.where(unfrozen, r + jnp.minimum(delta, room), r)
+            # a spreader where a flow stopped at its cap below the
+            # increment kept room: its other flows rise again next round
+            stopped = (unfrozen & (room < delta)).astype(jnp.float32)
+            spare = _segment_sum(jnp.concatenate([stopped, stopped]),
+                                 jnp.concatenate([provider, consumer + S]),
+                                 2 * S) > 0
+            tight = ((room <= level)
+                     | ((sp <= level) & ~spare[provider])
+                     | ((sc <= level) & ~spare[S + consumer]))
         unfrozen = unfrozen & ~tight
         return i + 1, r, unfrozen
 
-    rounds, r, _ = jax.lax.while_loop(cond, body,
-                                      (jnp.int32(0), r0, unfrozen0))
-    return jnp.where(live, r, 0.0), rounds
+    rounds, r, unfrozen = jax.lax.while_loop(cond, body,
+                                             (jnp.int32(0), r0, unfrozen0))
+    return jnp.where(live, r, 0.0), rounds, unfrozen.any()
 
 
 def maxmin_rates(provider, consumer, p_l, live, perf, *, max_iters=64,
-                 backend="jnp", rel_eps=1e-5) -> jax.Array:
+                 backend="jnp", rel_eps=1e-5, flow_caps=False) -> jax.Array:
     """Max-min fair rates by progressive filling (:func:`maxmin_fill`
     without the round count)."""
     return maxmin_fill(provider, consumer, p_l, live, perf,
                        max_iters=max_iters, backend=backend,
-                       rel_eps=rel_eps)[0]
+                       rel_eps=rel_eps, flow_caps=flow_caps)[0]
 
 
 # Low-level sharing-scheduler registry (paper §3.2.3 pluggable logic).
 # Every entry has the uniform signature
-# ``fn(provider, consumer, p_l, live, perf, *, backend, max_iters)
-# -> (rates, rounds)`` so the engine, the standalone sharing loop, and
-# rates_for all select by name through this one table instead of string
-# branches; ``rounds`` (i32) is the solve's progressive-filling rounds,
-# which the engine counts (``LoopCounters.fill_rounds``), 0 for the
+# ``fn(provider, consumer, p_l, live, perf, *, backend, max_iters,
+# flow_caps) -> (rates, rounds, truncated)`` so the engine, the standalone
+# sharing loop, and rates_for all select by name through this one table
+# instead of string branches; ``rounds`` (i32) is the solve's
+# progressive-filling rounds and ``truncated`` (bool) whether it stopped
+# at ``max_iters`` with a flow unfrozen, which the engine counts
+# (``LoopCounters.fill_rounds``, ``fill_truncated``); 0 and False for the
 # one-shot equal split.
 SCHEDULERS: dict[str, Callable] = {
-    "equal": lambda *a, **kw: (equal_share_rates(*a, **kw), jnp.int32(0)),
+    "equal": lambda *a, **kw: (equal_share_rates(*a, **kw), jnp.int32(0),
+                               jnp.bool_(False)),
     "maxmin": maxmin_fill,
 }
 
@@ -202,7 +238,7 @@ def rates_for(
     from .arrays import live_mask
 
     live = live_mask(cons, t)
-    r, _ = SCHEDULERS[scheduler](cons.provider, cons.consumer, cons.p_l,
+    r, *_ = SCHEDULERS[scheduler](cons.provider, cons.consumer, cons.p_l,
                                  live, perf, backend=backend)
     return r, live
 

@@ -116,21 +116,26 @@ def advance(ctx: StageCtx, st: CloudState):
     thresh = live_threshold(st.f_total)
     live = st.f_active & (st.t >= st.f_release) & (st.f_pr > thresh)
     rate_fn = SCHEDULERS[spec.scheduler]
+    # utilisation caps lie below their VM spreader's share (DESIGN.md §7)
+    flow_caps = getattr(trace, "util", None) is not None
 
     if ctx.bucket is not None:
         # ---- compacted fair-share solve (DESIGN.md §7) ------------------
         # The solve sees the same live flows, capacities and rate limits in
         # the same index order, so its progressive-filling rounds — and the
-        # resulting rates — are bit-identical to the dense call.
-        cp = cpk.build_tier(spec, st, ctx.bucket)
+        # resulting rates — are bit-identical to the dense call.  The
+        # driver hands over the gather it built to choose the tier, if any.
+        cp = (cpk.build_tier(spec, st, ctx.bucket) if ctx.compact is None
+              else ctx.compact)
         live_b = cpk.gather_flows(cp, live, False)
         f_pr_b = cpk.gather_flows(cp, st.f_pr, 0.0)
         f_pl_b = cpk.gather_flows(cp, st.f_pl, 0.0)
         f_rel_b = cpk.gather_flows(cp, st.f_release, jnp.inf)
         perf_b = spreader_perf_at(spec, params, st, cp.sidx)
-        r_b, fill_rounds = rate_fn(cp.bprov, cp.bcons, f_pl_b, live_b,
-                                   perf_b, backend=spec.backend,
-                                   max_iters=spec.max_fill_iters)
+        r_b, fill_rounds, truncated = rate_fn(
+            cp.bprov, cp.bcons, f_pl_b, live_b, perf_b,
+            backend=spec.backend, max_iters=spec.max_fill_iters,
+            flow_caps=flow_caps)
         r = cpk.scatter_flows(cp, F, r_b)
         flow_cand = [f_pr_b / jnp.maximum(r_b, 1e-30),   # completion  [FB]
                      f_rel_b - st.t]                     # latency     [FB]
@@ -139,9 +144,10 @@ def advance(ctx: StageCtx, st: CloudState):
     else:
         cp = None
         perf = spreader_perf(spec, params, st)
-        r, fill_rounds = rate_fn(st.f_prov, st.f_cons, st.f_pl, live, perf,
-                                 backend=spec.backend,
-                                 max_iters=spec.max_fill_iters)
+        r, fill_rounds, truncated = rate_fn(
+            st.f_prov, st.f_cons, st.f_pl, live, perf,
+            backend=spec.backend, max_iters=spec.max_fill_iters,
+            flow_caps=flow_caps)
         flow_cand = [st.f_pr / jnp.maximum(r, 1e-30),    # completion   [F]
                      st.f_release - st.t]                # latency      [F]
         flow_mask = [live & (r > 0),
@@ -240,7 +246,7 @@ def advance(ctx: StageCtx, st: CloudState):
                        t0=st.t, t_new=t_new, has_event=has_event,
                        tick=tick, period=period, compact=cp,
                        compact_ok=None if cp is None else cp.ok,
-                       fill_rounds=fill_rounds)
+                       fill_rounds=fill_rounds, fill_truncated=truncated)
     st = st._replace(t=t_new, t_c=t_c, n_events=st.n_events + 1,
                      meter_next=meter_next, f_pr=f_pr, processed=processed)
     return ctx, st

@@ -104,6 +104,17 @@ def compact_tiers(spec) -> tuple[tuple[int, int], ...]:
     return ((fb, fb),)
 
 
+def dense_reachable(spec, n_tasks: int) -> bool:
+    """Whether a pass under the auto rule can hold more active flows than
+    the largest tier: one flow per VM slot that holds a task, at most
+    ``min(n_vm, n_tasks)``, and one hidden consumer per PM.  Only then does
+    the driver build its in-program dense branch (a spec-static fact, so a
+    cell that cannot reach it compiles no such branch)."""
+    tiers = compact_tiers(spec)
+    return (spec.compact == -1 and bool(tiers)
+            and min(spec.n_vm, n_tasks) + spec.n_pm > tiers[-1][0])
+
+
 class Compact(NamedTuple):
     """One iteration's active-set gather (built by the ``advance`` stage,
     threaded to ``observe`` through ``StageCtx.compact``)."""

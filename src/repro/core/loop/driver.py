@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from .. import tracing
 from ..energy import PM_SWITCHING_OFF, PM_SWITCHING_ON
 from . import advance, lifecycle, observe, pm_sched, power, vm_sched
-from .compact import compact_tiers
+from .compact import build_compact, compact_tiers, dense_reachable
 from .state import (TASK_PENDING, CloudState, LoopCounters, StageCtx,
                     live_threshold)
 
@@ -133,6 +133,10 @@ def make_body(spec, params, trace, t_stop, t_next=None, axis_name=None):
     compacted = stages[:N_COMPACTED_STAGES]
     rest = stages[N_COMPACTED_STAGES:-N_MANAGEMENT_STAGES]
     tiers = compact_tiers(spec)
+    # Under the auto rule a pass whose active set outgrows the largest
+    # tier runs the compacted stages dense in the same program, where the
+    # trace and the slots can hold such a set (DESIGN.md §7).
+    dense = dense_reachable(spec, trace.n)
 
     def run_compacted(ctx, st, tier):
         ctx = ctx._replace(bucket=tier)
@@ -141,29 +145,55 @@ def make_body(spec, params, trace, t_stop, t_next=None, axis_name=None):
                 ctx, st = stage(ctx, st)
         return ctx, st
 
-    def on_tier(ctx, tier):
+    def on_tier(ctx, tier, cp=None, is_dense=None):
         def run(st):
-            out, st = run_compacted(ctx, st, tier)
+            out, st = run_compacted(ctx._replace(compact=cp), st, tier)
+            if is_dense is not None:
+                out = out._replace(dense_bucket=jnp.bool_(is_dense))
+                if tier is None:
+                    out = out._replace(compact_ok=jnp.bool_(True))
             return out.facts(), st
+        return run
+
+    def on_largest(ctx):
+        top = tiers[-1]
+        if not dense:
+            return on_tier(ctx, top)
+
+        def run(st):
+            # the largest tier when its gather holds every active flow and
+            # spreader of every lane, else dense: the same bits either way
+            cp = build_compact(spec, st, *top)
+            fits = cp.ok.astype(jnp.int32)
+            if axis_name is not None:
+                fits = jax.lax.pmin(fits, axis_name)
+            return jax.lax.cond(fits > 0, on_tier(ctx, top, cp, False),
+                                on_tier(ctx, None, None, True), st)
         return run
 
     def one_pass(st: CloudState):
         ctx = StageCtx(spec=spec, params=params, trace=trace, t_stop=t_stop,
                        t_next=t_next, arrival_sorted=arrival_sorted)
         snap = (st.task_state, st.vstage, st.pstate, st.f_active)
+        n_active = jnp.sum(st.f_active, dtype=jnp.int32)
+        ctx = ctx._replace(live_flows=n_active)
         if len(tiers) == 2:
             # Bucket tiers (DESIGN.md §7): the smallest that holds every
             # active flow, of every lane under vmap.  Either gives the
             # same bits.
-            n_active = jnp.sum(st.f_active, dtype=jnp.int32)
             if axis_name is not None:
                 n_active = jax.lax.pmax(n_active, axis_name)
             small = n_active <= tiers[0][0]
-            facts, st = jax.lax.cond(small, on_tier(ctx, tiers[0]),
-                                     on_tier(ctx, tiers[1]), st)
+            facts, st = jax.lax.cond(
+                small, on_tier(ctx, tiers[0], is_dense=False if dense
+                               else None),
+                on_largest(ctx), st)
             ctx = ctx._replace(**facts)._replace(small_bucket=small)
+        elif tiers:
+            facts, st = on_largest(ctx)(st)
+            ctx = ctx._replace(**facts)
         else:
-            ctx, st = run_compacted(ctx, st, tiers[0] if tiers else None)
+            ctx, st = run_compacted(ctx, st, None)
         for name, stage in rest:
             with tracing.scope(name):
                 ctx, st = stage(ctx, st)

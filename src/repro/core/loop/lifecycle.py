@@ -8,8 +8,8 @@ wire -> resume the saved task on the destination host) and the §3.4.2
 allocation-expiry self-defence.
 
 State delta: the VM-flow prefix of every ``f_*`` array, ``vstage``,
-``vm_host`` (migration arrivals), ``free_cores`` (released cores),
-``task_state`` / ``t_done`` (completions).
+``vm_host`` (migration arrivals), ``free_cores`` / ``free_mem`` (released
+cores and memory), ``task_state`` / ``t_done`` (completions).
 """
 from __future__ import annotations
 
@@ -71,9 +71,13 @@ def _vm_lifecycle_body(ctx: StageCtx, st: CloudState) -> CloudState:
     tid = jnp.maximum(st.vm_task, 0)
     twork = trace.work[tid]
     tcores = trace.cores[tid]
+    # a VM that uses a share of its cores runs at that share (DESIGN.md §7)
+    util = getattr(trace, "util", None)
+    cap = (tcores * params.perf_core if util is None
+           else util[tid] * tcores * params.perf_core)
     v_pr = jnp.where(boot_done, twork, v_pr)
     v_total = jnp.where(boot_done, twork, v_total)
-    v_pl = jnp.where(boot_done, tcores * params.perf_core, v_pl)
+    v_pl = jnp.where(boot_done, cap, v_pl)
     v_kind = jnp.where(boot_done, KIND_TASK, v_kind)
     vstage = jnp.where(boot_done, mc.VM_RUNNING, vstage)
 
@@ -81,21 +85,24 @@ def _vm_lifecycle_body(ctx: StageCtx, st: CloudState) -> CloudState:
     new_host = jnp.where(mig_done, st.vm_mig_dst, host)
     v_pr = jnp.where(mig_done, st.vm_saved_pr, v_pr)
     v_total = jnp.where(mig_done, jnp.maximum(st.vm_saved_pr, 1e-9), v_total)
-    v_pl = jnp.where(mig_done, tcores * params.perf_core, v_pl)
+    v_pl = jnp.where(mig_done, cap, v_pl)
     v_kind = jnp.where(mig_done, KIND_TASK, v_kind)
     v_prov = jnp.where(mig_done, lay.cpu0 + new_host, v_prov)
     v_cons = jnp.where(mig_done, lay.vm0 + vm_slot, v_cons)
     vstage = jnp.where(mig_done, mc.VM_RUNNING, vstage)
 
-    # task done -> destroy VM, release cores, complete task.  Cores freed
-    # by completion and by allocation expiry (§3.4.2, applied below) share
-    # one 2-column scatter-add; the columns reduce independently, so each
+    # task done -> destroy VM, release cores (and memory), complete task.
+    # Cores freed by completion and by allocation expiry (§3.4.2, applied
+    # below) share one scatter-add with the memory freed by completion
+    # (allocations hold none); the columns reduce independently, so each
     # matches its standalone segment_sum bit-for-bit.
     expired = (st.vstage == mc.VM_ALLOCATED) & (st.vm_expiry <= t_new)
-    freed = jax.ops.segment_sum(
-        jnp.stack([jnp.where(task_done, st.vm_cores, 0.0),
-                   jnp.where(expired, st.vm_cores, 0.0)], axis=-1),
-        host, num_segments=P)
+    cols = [jnp.where(task_done, st.vm_cores, 0.0),
+            jnp.where(expired, st.vm_cores, 0.0)]
+    if st.free_mem is not None:
+        cols.append(jnp.where(task_done, st.vm_mem, 0.0))
+    freed = jax.ops.segment_sum(jnp.stack(cols, axis=-1), host,
+                                num_segments=P)
     free_cores = st.free_cores + freed[:, 0]
     task_state = st.task_state
     t_done_arr = st.t_done
@@ -118,6 +125,8 @@ def _vm_lifecycle_body(ctx: StageCtx, st: CloudState) -> CloudState:
     free_cores = free_cores + freed[:, 1]
     vstage = jnp.where(expired, mc.VM_FREE, vstage)
 
+    if st.free_mem is not None:
+        st = st._replace(free_mem=st.free_mem + freed[:, 2])
     return st._replace(
         f_pr=f_pr, f_total=f_total, f_pl=f_pl, f_prov=f_prov, f_cons=f_cons,
         f_release=f_release, f_kind=f_kind, f_active=f_active,

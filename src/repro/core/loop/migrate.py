@@ -11,8 +11,9 @@ live-migrating VM ``v`` to PM ``dst``" that every caller shares —
   iteration, multi-VM evacuation folds up to ``spec.max_migrations``
   moves through :func:`migrate_many` so a donor drains in one pass.
 
-Cores move src -> dst immediately (allocation semantics); the VM's flow
-slot becomes the serialized memory state moving over the source NIC.
+Cores (and, with a memory dimension, the VM's memory) move src -> dst
+immediately (allocation semantics); the VM's flow slot becomes the
+serialized memory state moving over the source NIC.
 Refused (``ok=False``) lanes are bit-identical no-ops, which is what lets
 policy branches stay masked data under ``vmap``/``lax.switch``.
 """
@@ -29,8 +30,9 @@ def migrate_one(spec, params, st: CloudState, v, dst, ok) -> CloudState:
     """Begin live-migrating VM slot ``v`` to PM ``dst``, masked by ``ok``.
 
     Feasibility is re-checked here (the VM must be RUNNING and the
-    destination must have the cores free), so callers may pass optimistic
-    masks: an infeasible move degrades to a bitwise no-op.
+    destination must have the cores free, and its memory where the state
+    has a memory dimension), so callers may pass optimistic masks: an
+    infeasible move degrades to a bitwise no-op.
     """
     lay = spec.layout
     v = jnp.asarray(v, jnp.int32)
@@ -38,6 +40,11 @@ def migrate_one(spec, params, st: CloudState, v, dst, ok) -> CloudState:
     src = st.vm_host[v]
     ok = ok & (st.vstage[v] == mc.VM_RUNNING) & \
         (st.free_cores[dst] >= st.vm_cores[v])
+    if st.free_mem is not None:
+        ok = ok & (st.free_mem[dst] >= st.vm_mem[v])
+        st = st._replace(free_mem=(
+            st.free_mem.at[src].add(jnp.where(ok, st.vm_mem[v], 0.0))
+            .at[dst].add(jnp.where(ok, -st.vm_mem[v], 0.0))))
 
     def w(arr, val):
         return arr.at[v].set(jnp.where(ok, val, arr[v]))
@@ -64,7 +71,7 @@ def migrate_one(spec, params, st: CloudState, v, dst, ok) -> CloudState:
 def migrate_many(spec, params, st: CloudState, vs, dsts, ok) -> CloudState:
     """Fold up to ``K = len(vs)`` masked moves through :func:`migrate_one`
     sequentially (a length-``K`` ``lax.scan``), so later moves see the
-    ``free_cores`` earlier moves already committed — K moves into one
+    ``free_cores`` (and ``free_mem``) earlier moves already committed — K moves into one
     destination cannot overcommit it even if the caller's plan was
     optimistic."""
     vs = jnp.asarray(vs, jnp.int32).reshape(-1)

@@ -92,6 +92,15 @@ class CloudState(NamedTuple):
     overflow: jax.Array    # bool — VM slot pool exhausted at some dispatch
     running: jax.Array     # bool
 
+    # The memory dimension (DESIGN.md §7), present only when the trace
+    # carries ``mem``; ``None`` is not a pytree leaf, so a trace without it
+    # carries and compiles exactly the state above.
+    free_mem: jax.Array | None = None   # f32[P] GB free per PM
+    vm_mem: jax.Array | None = None     # f32[V] GB each VM slot holds
+    mem_bound: jax.Array | None = None  # i32 dispatches whose fit on both
+    #                                     dimensions chose another PM than
+    #                                     a fit on cores alone (cumulative)
+
     # Pre-meter-stack views (the default stack's per-PM direct meters).
     @property
     def energy_hi(self) -> jax.Array:
@@ -115,7 +124,13 @@ class LoopCounters(NamedTuple):
     gate's trigger fired and its stage body ran: ``vm_lifecycle``,
     ``pm_power``, ``pm_sched``, ``vm_sched`` in that order.
     ``small_bucket_iters`` counts the iterations whose compacted stages
-    ran on the small bucket tier (``loop.compact.SMALL_TIER``).
+    ran on the small bucket tier (``loop.compact.SMALL_TIER``),
+    ``dense_iters`` those that ran them dense because the active set
+    outgrew the largest tier, and ``live_flows`` sums the active flows
+    each iteration started with.  ``mem_bound`` counts dispatches whose
+    fit on cores and memory chose another PM than a fit on cores alone;
+    ``fill_truncated`` fair-share solves that stopped at
+    ``spec.max_fill_iters`` with a flow unfrozen.
     """
 
     fill_rounds: jax.Array   # i32 progressive-filling rounds (fair share)
@@ -123,11 +138,15 @@ class LoopCounters(NamedTuple):
     serve_rounds: jax.Array  # i32 queue-serving rounds of the VM policy
     gate_opens: jax.Array    # i32[4] iterations each event gate opened
     small_bucket_iters: jax.Array  # i32 iterations on the small tier
+    dense_iters: jax.Array   # i32 iterations on the in-program dense branch
+    live_flows: jax.Array    # i32 active flows, summed over iterations
+    mem_bound: jax.Array     # i32 memory-bound dispatches
+    fill_truncated: jax.Array  # i32 solves cut at max_fill_iters
 
     @classmethod
     def zero(cls) -> "LoopCounters":
-        return cls(jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                   jnp.zeros((4,), jnp.int32), jnp.int32(0))
+        z = jnp.int32(0)
+        return cls(z, z, z, jnp.zeros((4,), jnp.int32), z, z, z, z, z)
 
     @classmethod
     def of(cls, ctx: "StageCtx") -> "LoopCounters":
@@ -139,7 +158,9 @@ class LoopCounters(NamedTuple):
                    jnp.stack([count(ctx.lifecycle_gate),
                               count(ctx.power_gate), count(ctx.pm_gate),
                               count(ctx.vm_gate)]),
-                   count(ctx.small_bucket))
+                   count(ctx.small_bucket), count(ctx.dense_bucket),
+                   count(ctx.live_flows), count(ctx.mem_bound),
+                   count(ctx.fill_truncated))
 
     def plus(self, other: "LoopCounters") -> "LoopCounters":
         return jax.tree.map(jnp.add, self, other)
@@ -203,6 +224,8 @@ class StageCtx(NamedTuple):
     period: jax.Array | None = None   # f32 metering period
 
     fill_rounds: jax.Array | None = None  # i32 fair-share solve rounds
+    fill_truncated: jax.Array | None = None  # bool the solve stopped at
+    #                                          max_fill_iters unfinished
 
     # -- filled by the `observe` stage -----------------------------------
     view: Any = None             # energy.SimView of [t0, t_new]
@@ -215,10 +238,14 @@ class StageCtx(NamedTuple):
     pm_gate: jax.Array | None = None         # bool PM policy body ran
     vm_gate: jax.Array | None = None         # bool VM policy body ran
     serve_rounds: jax.Array | None = None    # i32 queue-serving rounds
+    mem_bound: jax.Array | None = None       # i32 memory-bound dispatches
 
     # -- set by the driver -----------------------------------------------
     small_bucket: jax.Array | None = None    # bool compacted stages ran on
     #                                          the small tier
+    dense_bucket: jax.Array | None = None    # bool compacted stages ran
+    #                                          dense above the largest tier
+    live_flows: jax.Array | None = None      # i32 active flows at entry
 
     def facts(self) -> dict:
         """The fields the stages filled, without the bucket-shaped

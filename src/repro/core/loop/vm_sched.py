@@ -15,9 +15,11 @@ old data-masked selection because ``jnp.where`` on a concrete flag folds
 to the selected operand.
 
 State delta: per dispatched request, the allocated VM slot (``vstage`` /
-``vm_*``), its image-transfer flow, the host's ``free_cores``, and the
-task binding; per rejected request, its ``task_state``.  Context delta:
-the gate's verdict and the queue-serving rounds (``serve_rounds``).
+``vm_*``), its image-transfer flow, the host's ``free_cores`` (and
+``free_mem``), and the task binding; per rejected request, its
+``task_state``.  Context delta: the gate's verdict, the queue-serving
+rounds (``serve_rounds``) and the memory-bound dispatches
+(``mem_bound``).
 """
 from __future__ import annotations
 
@@ -42,6 +44,11 @@ def serve_queue(spec, params, trace, st: CloudState, *,
     can currently fit (the paper's non-queuing cloud) instead of leaving
     it queued.  Oversized requests (larger than one PM) are always
     rejected.
+
+    When the trace carries ``mem`` (DESIGN.md §7), a host fits a request
+    only with both its cores and its memory free, a request larger than a
+    PM in either is oversized, and a dispatch takes both; ``mem_bound``
+    counts the dispatches whose host differs from a fit on cores alone.
     """
     lay = spec.layout
     P, V, T = spec.n_pm, spec.n_vm, trace.n
@@ -53,6 +60,7 @@ def serve_queue(spec, params, trace, st: CloudState, *,
     # the plain first-index ``argmin``: identical choice, identical
     # program.
     gid = getattr(trace, "gid", None)
+    mem = getattr(trace, "mem", None)
 
     def queued_mask(task_state):
         return (task_state == TASK_PENDING) & (trace.arrival <= st.t)
@@ -77,6 +85,11 @@ def serve_queue(spec, params, trace, st: CloudState, *,
 
         oversize = h_cores > params.pm_cores  # can never fit -> reject always
         fit = mc.pm_accepting(st2.pstate) & (st2.free_cores >= h_cores)
+        if mem is not None:
+            h_mem = mem[head]
+            oversize = oversize | (h_mem > params.pm_mem)
+            pm_cores_only = jnp.argmax(fit).astype(jnp.int32)
+            fit = fit & (st2.free_mem >= h_mem)
         any_fit = fit.any()
         pm = jnp.argmax(fit).astype(jnp.int32)  # first fit
         vfree = st2.vstage == mc.VM_FREE
@@ -118,6 +131,13 @@ def serve_queue(spec, params, trace, st: CloudState, *,
             f_kind=wv(st2.f_kind, KIND_IMAGE_XFER),
             overflow=st2.overflow | overflow,
         )
+        if mem is not None:
+            st2 = st2._replace(
+                vm_mem=wv(st2.vm_mem, h_mem),
+                free_mem=st2.free_mem.at[pm].add(
+                    jnp.where(do_dispatch, -h_mem, 0.0)),
+                mem_bound=st2.mem_bound + (
+                    do_dispatch & (pm != pm_cores_only)).astype(jnp.int32))
         progressed = do_dispatch | do_reject
         return st2, progressed
 
@@ -142,5 +162,9 @@ def vm_sched(ctx: StageCtx, st: CloudState):
                           & (s2.task_state != TASK_PENDING))
         return s2, settled.astype(jnp.int32) + 1
 
+    st0 = st
     st, rounds = jax.lax.cond(may, run, lambda s: (s, jnp.int32(0)), st)
-    return ctx._replace(vm_gate=may, serve_rounds=rounds), st
+    mem_bound = (None if st.mem_bound is None
+                 else st.mem_bound - st0.mem_bound)
+    return ctx._replace(vm_gate=may, serve_rounds=rounds,
+                        mem_bound=mem_bound), st

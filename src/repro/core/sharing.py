@@ -98,7 +98,7 @@ def run_sharing(
 
     def rates_of(p_r, t):
         live = exists & (p_r > thresh) & (t >= prob.t_start)
-        r, _ = rate_fn(prob.provider, prob.consumer, prob.limit, live,
+        r, *_ = rate_fn(prob.provider, prob.consumer, prob.limit, live,
                        prob.perf, backend=backend, max_iters=max_fill_iters)
         return r, live
 

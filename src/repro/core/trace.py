@@ -123,6 +123,8 @@ class WindowedTrace(NamedTuple):
     cores: object    # f32[n_windows, W]
     work: object     # f32[n_windows, W]
     gid: object      # i32[n_windows, W]; -1 = pad
+    mem: object = None   # f32[n_windows, W] where the trace has mem
+    util: object = None  # f32[n_windows, W] where the trace has util
 
     @property
     def n_windows(self) -> int:
@@ -139,8 +141,7 @@ class WindowedTrace(NamedTuple):
 
     def window(self, k: int) -> Trace:
         """Window ``k`` as a gid-carrying :class:`Trace`."""
-        return Trace(arrival=self.arrival[k], cores=self.cores[k],
-                     work=self.work[k], gid=self.gid[k])
+        return Trace(*(None if x is None else x[k] for x in self))
 
     def windows(self):
         """Iterate the windows in stream order (``__iter__`` stays the
@@ -174,25 +175,25 @@ def chunk_trace(trace: Trace, window: int) -> WindowedTrace:
 
     gid = (np.asarray(trace.gid, np.int32) if trace.gid is not None
            else np.arange(T, dtype=np.int32))
-    cores = np.asarray(trace.cores, np.float32)
-    work = np.asarray(trace.work, np.float32)
-    if np.any(np.diff(arrival) < 0):
-        order = np.argsort(arrival, kind="stable")
-        arrival, cores, work, gid = (arrival[order], cores[order],
-                                     work[order], gid[order])
+    order = (np.argsort(arrival, kind="stable")
+             if np.any(np.diff(arrival) < 0) else np.arange(T))
     n_windows = -(-T // W)
     pad = n_windows * W - T
 
     def chunk(x, fill, dtype):
-        x = np.asarray(x, dtype)
+        if x is None:
+            return None
+        x = np.asarray(x, dtype)[order]
         x = np.concatenate([x, np.full((pad,), fill, dtype)])
         return jnp.asarray(x.reshape(n_windows, W))
 
     return WindowedTrace(
         arrival=chunk(arrival, np.inf, np.float32),
-        cores=chunk(cores, 0.0, np.float32),
-        work=chunk(work, 0.0, np.float32),
+        cores=chunk(trace.cores, 0.0, np.float32),
+        work=chunk(trace.work, 0.0, np.float32),
         gid=chunk(gid, -1, np.int32),
+        mem=chunk(trace.mem, 0.0, np.float32),
+        util=chunk(trace.util, 0.0, np.float32),
     )
 
 
@@ -204,8 +205,6 @@ def filter_fitting(trace: Trace, pm_cores: float) -> Trace:
     import numpy as np2
 
     keep = np2.asarray(trace.cores) <= pm_cores
-    return Trace(
-        arrival=jnp.asarray(np2.asarray(trace.arrival)[keep]),
-        cores=jnp.asarray(np2.asarray(trace.cores)[keep]),
-        work=jnp.asarray(np2.asarray(trace.work)[keep]),
-    )
+    return Trace(*(None if x is None or name == "gid"
+                   else jnp.asarray(np2.asarray(x)[keep])
+                   for name, x in zip(Trace._fields, trace)))

@@ -254,7 +254,8 @@ def _simulate_stream_batch(spec, windows, params, n_slots, t_stop, devices):
             cur = next(it)
         Q = (engine.default_n_slots(spec, cur.n) if n_slots is None
              else int(n_slots))
-        carry = jax.vmap(lambda pp: engine.init_stream(spec, Q, pp),
+        carry = jax.vmap(lambda pp: engine.init_stream(spec, Q, pp,
+                                                       like=cur),
                          in_axes=(paxes,))(params)
         t_stop = jnp.asarray(t_stop, jnp.float32)
         t_prev_next = jnp.float32(0.0)

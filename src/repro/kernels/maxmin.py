@@ -245,11 +245,13 @@ def maxmin_solve(provider, consumer, p_l, live, perf, *,
                  interpret: bool = False):
     """Max-min fair rates by progressive filling, solved in one kernel.
 
-    Same round recurrence as ``repro.core.fairshare.maxmin_rates`` /
-    :func:`repro.kernels.ref.maxmin_solve_ref`, but the carried rate and
-    freeze vectors stay VMEM-resident across rounds instead of round-
-    tripping through HBM per ``while_loop`` iteration.  Guard call sites
-    with :func:`solve_fits`.
+    Same round recurrence as ``repro.core.fairshare.maxmin_rates``
+    without ``flow_caps`` / :func:`repro.kernels.ref.maxmin_solve_ref`,
+    but the carried rate and freeze vectors stay VMEM-resident across
+    rounds instead of round-tripping through HBM per ``while_loop``
+    iteration.  Guard call sites with :func:`solve_fits`; where flows' own
+    caps may lie below their spreaders' shares, ``maxmin_fill`` runs the
+    round-wise :func:`fill_stats` under its ``flow_caps`` rule instead.
     """
     C = provider.shape[0]
     S = perf.shape[0]

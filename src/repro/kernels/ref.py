@@ -43,7 +43,7 @@ def maxmin_solve_ref(provider, consumer, p_l, live, perf, *,
     ``repro.kernels.maxmin.maxmin_solve`` kernel.
 
     Identical round recurrence to ``repro.core.fairshare.maxmin_rates``
-    with the pure-jnp fill stats.
+    (without ``flow_caps``) with the pure-jnp fill stats.
     """
     C = provider.shape[0]
     r0 = jnp.zeros((C,), jnp.float32)

@@ -155,8 +155,8 @@ def smallestfirst(spec, params, ctx, st: CloudState) -> CloudState:
 
 
 DISPATCH_DELTA = ("task_state", "task_vm", "vstage", "vm_task", "vm_host",
-                  "vm_cores", "vm_expiry", "free_cores",
-                  "overflow") + FLOW_FIELDS
+                  "vm_cores", "vm_expiry", "free_cores", "free_mem",
+                  "vm_mem", "mem_bound", "overflow") + FLOW_FIELDS
 
 registry.register(
     "vm", "firstfit", firstfit, code=0, requires=DISPATCH_DELTA,

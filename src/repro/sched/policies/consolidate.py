@@ -34,12 +34,13 @@ from repro.core.loop.state import CloudState
 from .. import registry
 from .baseline import WAKE_SLEEP_DELTA, wake_sleep_pass
 from .select import (feasible_destinations, host_load_facts,
-                     idle_dominated_donor, smallest_victim_on)
+                     idle_dominated_donor, mem_of, smallest_victim_on)
 
 # wake/sleep inherited, plus one masked migration's rewrite of the victim
-# slot, both hosts' cores, and the loop-liveness flag
+# slot, both hosts' cores (and memory), and the loop-liveness flag
 MIGRATION_DELTA = WAKE_SLEEP_DELTA + (
-    "vstage", "vm_mig_dst", "vm_saved_pr", "free_cores", "running")
+    "vstage", "vm_mig_dst", "vm_saved_pr", "free_cores", "free_mem",
+    "running")
 
 
 def consolidation_step(spec, params, st: CloudState) -> CloudState:
@@ -49,7 +50,8 @@ def consolidation_step(spec, params, st: CloudState) -> CloudState:
     on_src, v = smallest_victim_on(st, movable, src)
     need = st.vm_cores[v]
 
-    fit = feasible_destinations(running, used, st.free_cores, src, need)
+    fit = feasible_destinations(running, used, st.free_cores, src, need,
+                                st.free_mem, mem_of(st, v))
     dst = jnp.argmin(jnp.where(fit, st.free_cores, jnp.inf)).astype(jnp.int32)
 
     do = donor.any() & on_src.any() & fit.any()

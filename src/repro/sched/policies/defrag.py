@@ -32,7 +32,8 @@ from repro.core.loop.state import TASK_PENDING, CloudState
 from .. import registry
 from .baseline import wake_sleep_pass
 from .consolidate import MIGRATION_DELTA
-from .select import feasible_destinations, host_load_facts, smallest_victim_on
+from .select import (feasible_destinations, host_load_facts, mem_of,
+                     smallest_victim_on)
 
 
 def defrag_step(spec, params, trace, st: CloudState) -> CloudState:
@@ -48,7 +49,8 @@ def defrag_step(spec, params, trace, st: CloudState) -> CloudState:
     need = st.vm_cores[v]
 
     # bin-packing target: the *most-loaded* running host the victim fits
-    fit = feasible_destinations(running, used, st.free_cores, src, need)
+    fit = feasible_destinations(running, used, st.free_cores, src, need,
+                                st.free_mem, mem_of(st, v))
     dst = jnp.argmax(jnp.where(fit, used, -jnp.inf)).astype(jnp.int32)
 
     do = ~queued.any() & donor.any() & on_src.any() & fit.any()

@@ -7,8 +7,9 @@ Evacuation generalises the masked-migration machinery to up to
 ``CloudSpec.max_migrations`` moves per iteration: when the idle-dominance
 trigger fires, the donor's running VMs (smallest first, the cheapest
 serialized states) are *all* re-placed in one pass, each onto the
-best-fit running host that still has the cores free **after** the moves
-planned before it — the plan threads cumulative ``free_cores`` through a
+best-fit running host that still has the cores (and memory) free
+**after** the moves planned before it — the plan threads cumulative
+``free_cores`` and ``free_mem`` through a
 scan, and :func:`repro.core.loop.migrate.migrate_many` re-checks the same
 invariant while applying, so a K-deep plan can never overcommit a
 destination.  The drained donor is powered down by the inherited
@@ -30,7 +31,7 @@ from .. import registry
 from .baseline import wake_sleep_pass
 from .consolidate import MIGRATION_DELTA
 from .select import (feasible_destinations, host_load_facts,
-                     idle_dominated_donor)
+                     idle_dominated_donor, mem_of)
 
 
 def evacuation_step(spec, params, st: CloudState) -> CloudState:
@@ -47,18 +48,23 @@ def evacuation_step(spec, params, st: CloudState) -> CloudState:
     vs = order[:K].astype(jnp.int32)
     valid = on_src[vs]
 
-    # plan destinations sequentially: each move sees the free cores left
-    # by the moves before it (same best-fit + load-ordering rule as
-    # consolidation, against the iteration-start loads)
+    # plan destinations sequentially: each move sees the free cores (and
+    # memory) left by the moves before it (same best-fit + load-ordering
+    # rule as consolidation, against the iteration-start loads)
     def plan(free, v):
+        free_c, free_m = free
         need = st.vm_cores[v]
-        fit = feasible_destinations(running, used, free, src, need)
-        dst = jnp.argmin(jnp.where(fit, free, jnp.inf)).astype(jnp.int32)
+        need_m = mem_of(st, v)
+        fit = feasible_destinations(running, used, free_c, src, need,
+                                    free_m, need_m)
+        dst = jnp.argmin(jnp.where(fit, free_c, jnp.inf)).astype(jnp.int32)
         ok = fit.any()
-        free = free.at[dst].add(jnp.where(ok, -need, 0.0))
-        return free, (dst, ok)
+        free_c = free_c.at[dst].add(jnp.where(ok, -need, 0.0))
+        if free_m is not None:
+            free_m = free_m.at[dst].add(jnp.where(ok, -need_m, 0.0))
+        return (free_c, free_m), (dst, ok)
 
-    _, (dsts, fits) = jax.lax.scan(plan, st.free_cores, vs)
+    _, (dsts, fits) = jax.lax.scan(plan, (st.free_cores, st.free_mem), vs)
     ok = valid & fits & donor.any()
     return migrate_many(spec, params, st, vs, dsts, ok)
 

@@ -42,15 +42,26 @@ def idle_dominated_donor(params, st: CloudState, running, used, n_movable):
     return donor, src
 
 
-def feasible_destinations(running, used, free_cores, src, need):
+def feasible_destinations(running, used, free_cores, src, need,
+                          free_mem=None, need_mem=None):
     """Mask of hosts a victim of ``need`` cores may move to: RUNNING, has
-    the cores free, is not the source, and is *at least as loaded* as the
-    source — the load-ordering guard that makes every move strictly
+    the cores free (and ``need_mem`` of ``free_mem``, where the state has
+    a memory dimension), is not the source, and is *at least as loaded*
+    as the source — the load-ordering guard that makes every move strictly
     packing (never spreading) and breaks migration ping-pong between two
     equally loaded hosts."""
     P = running.shape[0]
-    return (running & (free_cores >= need) & (jnp.arange(P) != src)
-            & (used >= used[src]))
+    fit = (running & (free_cores >= need) & (jnp.arange(P) != src)
+           & (used >= used[src]))
+    if free_mem is not None:
+        fit = fit & (free_mem >= need_mem)
+    return fit
+
+
+def mem_of(st: CloudState, v):
+    """The memory VM slot ``v`` holds, or ``None`` without a memory
+    dimension."""
+    return None if st.vm_mem is None else st.vm_mem[v]
 
 
 def smallest_victim_on(st: CloudState, movable, src):

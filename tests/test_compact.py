@@ -298,3 +298,94 @@ def test_small_bucket_iters():
     _assert_tree_bitwise(_answers(res), _answers(pinned), "pinned")
     dense = engine.simulate(dataclasses.replace(TIERED, compact=0), quiet)
     assert int(dense.counters.small_bucket_iters) == 0
+
+
+# ---------------------------------------------------------------------------
+# the in-program dense branch (DESIGN.md §7): under the auto rule a pass
+# whose active set outgrows the largest tier runs dense in the same
+# program, where the slots and the trace can hold such a set
+# ---------------------------------------------------------------------------
+
+# auto watermark next_pow2(4 * 8 + 32) = 64 flows, one tier; 96 one-core
+# tasks within a second outgrow it on 8 PMs of 64 cores
+DENSE = CloudSpec(n_pm=8, n_vm=256)
+
+
+def _dense_trace(T=140, burst=96, seed=4) -> Trace:
+    rng = np.random.default_rng(seed)
+    arr = np.sort(np.concatenate([5.0 + rng.uniform(0, 1, burst),
+                                  rng.uniform(0, 900, T - burst)]))
+    cores = rng.choice([1.0, 2.0], T)
+    util = rng.uniform(0.05, 1.0, T)
+    return Trace(arrival=jnp.asarray(arr.astype(np.float32)),
+                 cores=jnp.asarray(cores.astype(np.float32)),
+                 work=jnp.asarray((rng.uniform(20, 400, T) * util * cores)
+                                  .astype(np.float32)),
+                 mem=jnp.asarray((cores * 4.0).astype(np.float32)),
+                 util=jnp.asarray(util.astype(np.float32)))
+
+
+def test_dense_branch_reachability():
+    assert cpk.compact_tiers(DENSE) == ((64, 64),)
+    assert cpk.dense_reachable(DENSE, 140)
+    assert not cpk.dense_reachable(DENSE, 56)   # 56 + 8 flows fit 64
+    assert not cpk.dense_reachable(dataclasses.replace(DENSE, compact=64),
+                                   140)         # explicit: host replay
+    assert not cpk.dense_reachable(dataclasses.replace(DENSE, compact=0),
+                                   140)
+    das2 = CloudSpec(n_pm=500, n_vm=4096)       # trace1k: 1000 + 500
+    assert not cpk.dense_reachable(das2, 1000)
+    assert cpk.dense_reachable(das2, 4608)      # the stream's slot pool
+    grid = CloudSpec(n_pm=100, n_vm=1024)       # the sweeps: 250 + 100
+    assert not cpk.dense_reachable(grid, 250)
+
+
+def _no_replay(run):
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)
+        return jax.block_until_ready(run())
+
+
+@pytest.fixture(scope="module")
+def dense_runs():
+    tr = _dense_trace()
+    want = jax.block_until_ready(
+        engine.simulate(dataclasses.replace(DENSE, compact=0), tr))
+    return tr, want
+
+
+def _dense_answers(res):
+    """Everything but the counters of the bucket tiers, which the dense
+    run reads 0 by construction."""
+    return res._replace(counters=res.counters._replace(
+        small_bucket_iters=None, dense_iters=None))
+
+
+@pytest.mark.parametrize("entry", ["simulate", "simulate_batch"])
+def test_dense_branch_matches_dense_bitwise(dense_runs, entry):
+    tr, want = dense_runs
+    if entry == "simulate":
+        res = _no_replay(lambda: engine.simulate(DENSE, tr))
+    else:
+        params = engine.stack_params([CloudParams.for_spec(DENSE)] * 2)
+        res = jax.tree.map(lambda x: x[1], _no_replay(
+            lambda: engine.simulate_batch(DENSE, tr, params)))
+    _assert_tree_bitwise(_dense_answers(res), _dense_answers(want), entry)
+    dense, n = int(res.counters.dense_iters), int(res.n_events)
+    assert 0 < dense < n                          # both branches ran
+    assert int(res.counters.live_flows) > 64 * dense
+    assert int(res.counters.fill_truncated) == 0
+
+
+def test_stream_from_a_generator_above_the_watermark(dense_runs):
+    """A window generator cannot be replayed: above the watermark the
+    stream runs dense in the program, and gives the monolithic bits."""
+    tr, want = dense_runs
+    wt = chunk_trace(tr, 32)
+    res = _no_replay(lambda: engine.simulate_stream(
+        DENSE, (w for w in wt.windows())))
+    assert int(res.counters.dense_iters) > 0
+    for name in ("completion", "rejected", "t_end", "n_events"):
+        np.testing.assert_array_equal(_bits(getattr(res, name)),
+                                      _bits(getattr(want, name)), name)
+    _assert_tree_bitwise(res.meters, want.meters, "meters")

@@ -5,7 +5,8 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.fairshare import equal_share_rates, maxmin_rates
+from repro.core.fairshare import (equal_share_rates, maxmin_fill,
+                                  maxmin_rates)
 from repro.core.influence import group_sizes, influence_labels
 from repro.core.network import make_topology, transfers_problem
 from repro.core.sharing import SharingProblem, run_sharing, run_sharing_tau
@@ -88,6 +89,115 @@ def test_maxmin_property(data):
                    rng.uniform(0.05, 2.0, nC), 1e30).astype(np.float32)
     r = _maxmin(provider, consumer, p_l, perf)
     _check_maxmin_optimality(provider, consumer, p_l, perf, r)
+
+
+def _vm_problem(rng, n_hosts, n_flows, load):
+    """Flows of VMs on hosts: flow ``i`` runs from its host's CPU (a
+    spreader of 64) to its own VM spreader (``max(cores, 1)``), capped at
+    ``util * cores`` with a continuous utilisation, as a utilisation-capped
+    VM is; hosts hold VMs up to ``load`` of their cores."""
+    sizes = np.array([1.0, 2.0, 4.0, 8.0])
+    host = rng.randint(0, n_hosts, n_flows)
+    cores = sizes[rng.randint(0, 4, n_flows)]
+    util = np.clip(rng.beta(0.6, 2.4, n_flows), 0.01, 1.0)
+    used = np.zeros(n_hosts)
+    keep = np.zeros(n_flows, bool)
+    for i in range(n_flows):
+        if used[host[i]] + cores[i] <= load * 64:
+            used[host[i]] += cores[i]
+            keep[i] = True
+    host, cores, util = host[keep], cores[keep], util[keep]
+    n = host.shape[0]
+    perf = np.concatenate([np.full(n_hosts, 64.0), np.maximum(cores, 1.0)])
+    return (host, n_hosts + np.arange(n), (util * cores).astype(np.float32),
+            perf.astype(np.float32), cores)
+
+
+def _water_fill(host, cap, capacity):
+    """Exact max-min rates of capped flows sharing their host as one link."""
+    r = np.zeros(cap.shape[0])
+    for h in np.unique(host):
+        idx = np.flatnonzero(host == h)
+        left, todo = float(capacity[h]), list(idx[np.argsort(cap[idx])])
+        while todo:
+            level = left / len(todo)
+            if cap[todo[0]] > level:
+                r[todo] = level
+                break
+            r[todo[0]] = cap[todo[0]]
+            left -= cap[todo[0]]
+            todo.pop(0)
+    return r
+
+
+def _fill(provider, consumer, p_l, perf, flow_caps):
+    live = jnp.ones(len(provider), bool)
+    r, rounds, truncated = maxmin_fill(
+        jnp.asarray(provider, jnp.int32), jnp.asarray(consumer, jnp.int32),
+        jnp.asarray(p_l, jnp.float32), live, jnp.asarray(perf, jnp.float32),
+        flow_caps=flow_caps)
+    return np.asarray(r), int(rounds), bool(truncated)
+
+
+def test_maxmin_distinct_caps_truncate_without_flow_caps():
+    """Utilisation-capped VM flows on 8 hosts loaded to 68 %: the round
+    rule that freezes only the flows at the round's global minimum freezes
+    one distinct cap a round and stops at ``max_iters`` far from the
+    answer; the rule for per-flow caps converges in a few rounds."""
+    rng = np.random.RandomState(0)
+    prov, cons, p_l, perf, _ = _vm_problem(rng, 8, 400, 0.68)
+    exact = _water_fill(prov, p_l.astype(np.float64), perf)
+    r, rounds, truncated = _fill(prov, cons, p_l, perf, flow_caps=False)
+    assert rounds == 64 and truncated
+    assert (r < 0.99 * exact).sum() > 20
+    r, rounds, truncated = _fill(prov, cons, p_l, perf, flow_caps=True)
+    assert not truncated and rounds <= 8
+    np.testing.assert_allclose(r, exact, rtol=1e-5)
+
+
+@settings(max_examples=40, deadline=None)
+@given(st.data())
+def test_maxmin_flow_caps_property(data):
+    """Random utilisation caps, hosts from idle to saturated: the rule for
+    per-flow caps gives exact water-filling per host, never stops at the
+    round limit, and takes at most one round per distinct VM size and per
+    host level."""
+    rng = np.random.RandomState(data.draw(st.integers(0, 2**31 - 1)))
+    n_hosts = data.draw(st.integers(1, 8))
+    load = data.draw(st.sampled_from([0.3, 0.7, 1.0]))
+    prov, cons, p_l, perf, cores = _vm_problem(
+        rng, n_hosts, data.draw(st.integers(1, 200)), load)
+    # raise caps on some hosts until they saturate
+    hot = rng.rand(n_hosts) < 0.5
+    p_l = np.where(hot[prov], np.maximum(p_l, cores * 0.9),
+                   p_l).astype(np.float32)
+    r, rounds, truncated = _fill(prov, cons, p_l, perf, flow_caps=True)
+    exact = _water_fill(prov, np.minimum(p_l, perf[cons]).astype(np.float64),
+                        perf)
+    np.testing.assert_allclose(r, exact, rtol=2e-5, atol=1e-6)
+    assert not truncated
+    assert rounds <= len(np.unique(prov)) + len(np.unique(cores)) + 1
+
+
+@settings(max_examples=40, deadline=None)
+@given(st.data())
+def test_maxmin_flow_caps_general_property(data):
+    """On random sharing graphs, with and without caps below the shares,
+    the rule for per-flow caps still gives max-min fair rates."""
+    nS = data.draw(st.integers(2, 8))
+    nC = data.draw(st.integers(1, 16))
+    rng = np.random.RandomState(data.draw(st.integers(0, 2**31 - 1)))
+    provider = rng.randint(0, nS, nC)
+    consumer = rng.randint(0, nS, nC)
+    perf = rng.uniform(0.5, 8.0, nS).astype(np.float32)
+    p_l = np.where(rng.rand(nC) < 0.6,
+                   rng.uniform(0.05, 2.0, nC), 1e30).astype(np.float32)
+    r, _, truncated = _fill(provider, consumer, p_l, perf, flow_caps=True)
+    assert not truncated
+    _check_maxmin_optimality(provider, consumer, p_l, perf, r)
+    if (p_l >= 1e30).all():
+        np.testing.assert_array_equal(
+            r, _fill(provider, consumer, p_l, perf, flow_caps=False)[0])
 
 
 def test_equal_share_simple():

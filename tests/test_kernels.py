@@ -98,6 +98,39 @@ def test_maxmin_solve_matches_engine_scheduler():
                                rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("C,S,seed", [(64, 80, 0), (300, 340, 1)])
+def test_maxmin_distinct_caps_pallas_round_rule(C, S, seed):
+    """Distinct per-flow caps: under ``flow_caps`` the Pallas backend runs
+    the round-wise ``fill_stats`` kernel under the engine's round rule
+    (the fused solve keeps the rule without caps below the shares) and
+    gives the jnp rates; where every cap lies at or above its consumer's
+    share, the fused solve gives them too."""
+    from repro.core.fairshare import maxmin_fill
+    rng = np.random.RandomState(seed)
+    hosts = S - C
+    provider = jnp.asarray(rng.randint(0, hosts, C), jnp.int32)
+    consumer = jnp.asarray(hosts + np.arange(C), jnp.int32)
+    vm = rng.choice([1.0, 2.0, 4.0, 8.0], C)
+    perf = jnp.asarray(np.concatenate([np.full(hosts, 16.0), vm]),
+                       jnp.float32)
+    live = jnp.asarray(rng.rand(C) < 0.9)
+    caps = jnp.asarray(vm * np.clip(rng.beta(0.6, 2.4, C), 0.01, 1),
+                       jnp.float32)
+    want, _, cut = maxmin_fill(provider, consumer, caps, live, perf,
+                               flow_caps=True)
+    got, _, cut_p = maxmin_fill(provider, consumer, caps, live, perf,
+                                backend="pallas", flow_caps=True)
+    assert not bool(cut) and not bool(cut_p)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    above = jnp.asarray(vm * (1.0 + rng.rand(C)), jnp.float32)
+    want = maxmin_fill(provider, consumer, above, live, perf)[0]
+    got = maxmin_solve(provider, consumer, above, live, perf,
+                       interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
 # ---------------------------------------------------------------------------
 # event-horizon masked min
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import engine as eng
 from repro.core import machine as mc
@@ -239,3 +240,50 @@ def test_tenant_energy_partitions_vm_meters():
     np.testing.assert_allclose(te[1], vm[1] + vm[2], rtol=1e-6)
     # owned shares partition the attributed total; unowned slots drop
     np.testing.assert_allclose(te.sum(), vm.sum(), rtol=1e-6)
+
+
+# ------------------------------------------------------- memory dimension
+
+def _mem_trace(d_mem):
+    """The consolidation trace with memory: PM0 keeps A's 200 GB after C
+    ends, so D (``d_mem`` GB) may move there only if it fits the rest."""
+    tr = _consolidation_trace()
+    return tr._replace(mem=jnp.asarray([200.0, 20.0, 20.0, d_mem],
+                                       jnp.float32))
+
+
+@pytest.mark.parametrize("pm_sched", ["consolidate", "defrag", "evacuate"])
+@pytest.mark.parametrize("d_mem,moves", [(100.0, False), (50.0, True)])
+def test_no_migration_overfills_memory(pm_sched, d_mem, moves):
+    """Every migration policy moves a VM only where its memory is free:
+    at every snapshot each PM's free memory is its size less what its VMs
+    hold, and never negative; D moves to PM0 exactly when it fits."""
+    spec, params = eng.make_cloud(n_pm=2, n_vm=8, pm_cores=100.0,
+                                  pm_mem=256.0, pm_sched=pm_sched)
+    tr = _mem_trace(d_mem)
+    for t_stop in (100.0, 235.0, 300.0, 450.0, 600.0, 1000.0, 3000.0):
+        st = eng.simulate(spec, tr, params=params, t_stop=t_stop).state
+        free = np.asarray(st.free_mem)
+        held = np.zeros(2)
+        on = np.asarray(st.vstage) != mc.VM_FREE
+        np.add.at(held, np.asarray(st.vm_host)[on], np.asarray(st.vm_mem)[on])
+        assert (free >= 0).all(), (t_stop, free)
+        np.testing.assert_allclose(free + held, 256.0)
+    mid = eng.simulate(spec, tr, params=params, t_stop=600.0).state
+    d_vm = int(np.asarray(mid.task_vm)[3])
+    assert (int(np.asarray(mid.vm_host)[d_vm]) == 0) == moves
+    res = eng.simulate(spec, tr, params=params)
+    assert (np.asarray(res.state.task_state) == eng.TASK_DONE).all()
+
+
+def test_migrate_one_refuses_a_host_without_the_memory():
+    from repro.core.loop.migrate import migrate_one
+    spec, params = eng.make_cloud(n_pm=2, n_vm=8, pm_cores=100.0,
+                                  pm_mem=256.0)
+    tr = _mem_trace(100.0)
+    st = eng.simulate(spec, tr, params=params, t_stop=300.0).state
+    d_vm = int(np.asarray(st.task_vm)[3])
+    assert int(np.asarray(st.vm_host)[d_vm]) == 1
+    moved = migrate_one(spec, params, st, d_vm, 0, jnp.bool_(True))
+    for a, b in zip(jax.tree.leaves(moved), jax.tree.leaves(st)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

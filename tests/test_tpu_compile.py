@@ -173,3 +173,18 @@ def test_stream_step_compiles_for_v5e(one_chip):
     t = _shape(jnp.float32(0.0), one_chip)
     engine._stream_step.lower(spec, on(carry), on(window), on(params),
                               t, t, t).compile()
+
+
+def test_region_program_compiles_for_v5e(one_chip):
+    """Memory, utilisation caps and the in-program dense branch (a pass
+    whose active set outgrows the watermark runs dense): the whole engine
+    must compile, with the tier choice a conditional."""
+    from repro.core.loop import compact as cpk
+    spec, params = engine.make_cloud(n_pm=8, n_vm=256, pm_mem=256.0)
+    trace = synthetic_trace(140, 4, seed=0)
+    trace = trace._replace(mem=trace.cores * 4.0,
+                           util=jnp.full_like(trace.cores, 0.2))
+    assert cpk.dense_reachable(spec, trace.n)
+    tr, pp, t_stop = _cloud_args(params, trace, one_chip)
+    hlo = engine._simulate_jit.lower(spec, tr, pp, None, t_stop).compile()
+    assert "conditional(" in hlo.as_text()
