@@ -66,9 +66,10 @@ def equal_share_rates(
     backend: str = "jnp",    # registry-uniform signature; unused
     max_iters: int = 0,      # registry-uniform signature; unused
     flow_caps: bool = False,  # registry-uniform signature; unused
+    owned: jax.Array | None = None,  # registry-uniform signature; unused
 ) -> jax.Array:
     """rate = min(perf[prov]/n_prov, perf[cons]/n_cons, p_l)."""
-    del backend, max_iters, flow_caps
+    del backend, max_iters, flow_caps, owned
     offer_p, offer_c = _equal_share_offers(provider, consumer, live, perf)
     r = jnp.minimum(jnp.minimum(offer_p, offer_c), p_l)
     return jnp.where(live, r, 0.0)
@@ -78,10 +79,18 @@ def equal_share_rates(
 # Max-min fairness via progressive filling
 # ---------------------------------------------------------------------------
 
+def _share(perf, committed, cnt):
+    """A spreader's equal share of its spare capacity among its ``cnt``
+    unfrozen flows (``_BIG`` where none is unfrozen)."""
+    avail = jnp.maximum(perf - committed, 0.0)
+    return jnp.where(cnt > 0, avail / jnp.maximum(cnt, 1.0), _BIG)
+
+
 def _jnp_fill_stats(provider, consumer, r, live, unfrozen, perf):
     """One progressive-filling round of segmented stats (pure-jnp reference).
 
-    Returns per-flow increment headroom ``df`` (inf for frozen flows).
+    Returns the per-spreader shares ``(dp, dc)`` on the provider and the
+    consumer side.
     """
     S = perf.shape[0]
     rl = jnp.where(live, r, 0.0)
@@ -95,13 +104,22 @@ def _jnp_fill_stats(provider, consumer, r, live, unfrozen, perf):
     data = jnp.stack([jnp.concatenate([rl, rl]),
                       jnp.concatenate([uf, uf])], axis=-1)
     stats = _segment_sum(data, ids, 2 * S)
-    committed_p, cnt_p = stats[:S, 0], stats[:S, 1]
-    committed_c, cnt_c = stats[S:, 0], stats[S:, 1]
-    avail_p = jnp.maximum(perf - committed_p, 0.0)
-    avail_c = jnp.maximum(perf - committed_c, 0.0)
-    dp = jnp.where(cnt_p > 0, avail_p / jnp.maximum(cnt_p, 1.0), _BIG)
-    dc = jnp.where(cnt_c > 0, avail_c / jnp.maximum(cnt_c, 1.0), _BIG)
-    return dp, dc
+    return (_share(perf, stats[:S, 0], stats[:S, 1]),
+            _share(perf, stats[S:, 0], stats[S:, 1]))
+
+
+def _provider_fill_stats(provider, r, live, unfrozen, perf, perf_c):
+    """:func:`_jnp_fill_stats` where every live flow's consumer is its own
+    spreader: the provider shares ``dp`` from an F-row scatter, and each
+    flow's consumer share ``sc`` from its own committed rate and unfrozen
+    flag, with ``perf_c = perf[consumer]``.  A consumer segment that only
+    one flow names sums that flow's row and exact ``+0.0`` rows, so ``sc``
+    equals ``dc[consumer]`` bit for bit (DESIGN.md §7)."""
+    rl = jnp.where(live, r, 0.0)
+    uf = unfrozen.astype(jnp.float32)
+    stats = _segment_sum(jnp.stack([rl, uf], axis=-1), provider,
+                         perf.shape[0])
+    return _share(perf, stats[:, 0], stats[:, 1]), _share(perf_c, rl, uf)
 
 
 def maxmin_fill(
@@ -115,6 +133,7 @@ def maxmin_fill(
     backend: str = "jnp",
     rel_eps: float = 1e-5,
     flow_caps: bool = False,
+    owned: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Max-min fair rates by progressive filling, the rounds it took, and
     whether it stopped at ``max_iters`` with a flow still unfrozen.
@@ -142,6 +161,12 @@ def maxmin_fill(
     size or under ``flow_caps``; ``'jnp'`` uses segment_sum throughout.
     The fused kernel does not report its rounds: it returns a round count
     of 0 and no truncation.
+
+    ``owned`` (a traced bool, jnp backend only) says that no two live
+    flows name the same consumer, as in the engine when no image transfer
+    or migration is live: the rounds then reduce over the providers alone
+    and give the same bits (DESIGN.md §7).  ``None`` runs every round over
+    both sides.
     """
     if backend == "pallas":
         from repro.kernels import ops as _kops
@@ -153,22 +178,31 @@ def maxmin_fill(
                 max_iters=max_iters, rel_eps=rel_eps), jnp.int32(0), \
                 jnp.bool_(False)
         fill_stats = _kops.fill_stats_pallas
+        owned = None
     else:
         fill_stats = _jnp_fill_stats
 
     C = provider.shape[0]
     S = perf.shape[0]
-    r0 = jnp.zeros((C,), jnp.float32)
-    unfrozen0 = live
+    init = (jnp.int32(0), jnp.zeros((C,), jnp.float32), live)
 
     def cond(state):
         i, r, unfrozen = state
         return jnp.logical_and(i < max_iters, unfrozen.any())
 
-    def body(state):
+    def body(perf_c, state):
+        """One round; ``perf_c = perf[consumer]`` takes the provider-only
+        round (consumers that one flow each names), ``None`` the round
+        over both sides."""
         i, r, unfrozen = state
-        dp, dc = fill_stats(provider, consumer, r, live, unfrozen, perf)
-        sp, sc = dp[provider], dc[consumer]
+        own = perf_c is not None
+        if own:
+            dp, sc = _provider_fill_stats(provider, r, live, unfrozen, perf,
+                                          perf_c)
+            sp = dp[provider]
+        else:
+            dp, dc = fill_stats(provider, consumer, r, live, unfrozen, perf)
+            sp, sc = dp[provider], dc[consumer]
         room = jnp.maximum(p_l - r, 0.0)
         df = jnp.minimum(sp, sc)
         if not flow_caps:
@@ -185,18 +219,31 @@ def maxmin_fill(
             r = jnp.where(unfrozen, r + jnp.minimum(delta, room), r)
             # a spreader where a flow stopped at its cap below the
             # increment kept room: its other flows rise again next round
-            stopped = (unfrozen & (room < delta)).astype(jnp.float32)
-            spare = _segment_sum(jnp.concatenate([stopped, stopped]),
-                                 jnp.concatenate([provider, consumer + S]),
-                                 2 * S) > 0
+            stopped = unfrozen & (room < delta)
+            stop_f = stopped.astype(jnp.float32)
+            if own:
+                spare_p = (_segment_sum(stop_f, provider, S) > 0)[provider]
+                spare_c = stopped
+            else:
+                spare = _segment_sum(jnp.concatenate([stop_f, stop_f]),
+                                     jnp.concatenate([provider, consumer + S]),
+                                     2 * S) > 0
+                spare_p, spare_c = spare[provider], spare[S + consumer]
             tight = ((room <= level)
-                     | ((sp <= level) & ~spare[provider])
-                     | ((sc <= level) & ~spare[S + consumer]))
+                     | ((sp <= level) & ~spare_p)
+                     | ((sc <= level) & ~spare_c))
         unfrozen = unfrozen & ~tight
         return i + 1, r, unfrozen
 
-    rounds, r, unfrozen = jax.lax.while_loop(cond, body,
-                                             (jnp.int32(0), r0, unfrozen0))
+    def fill(own: bool):
+        perf_c = perf[consumer] if own else None
+        return jax.lax.while_loop(cond, functools.partial(body, perf_c), init)
+
+    if owned is None:
+        rounds, r, unfrozen = fill(False)
+    else:
+        rounds, r, unfrozen = jax.lax.cond(owned, lambda: fill(True),
+                                           lambda: fill(False))
     return jnp.where(live, r, 0.0), rounds, unfrozen.any()
 
 
@@ -212,9 +259,9 @@ def maxmin_rates(provider, consumer, p_l, live, perf, *, max_iters=64,
 # Low-level sharing-scheduler registry (paper §3.2.3 pluggable logic).
 # Every entry has the uniform signature
 # ``fn(provider, consumer, p_l, live, perf, *, backend, max_iters,
-# flow_caps) -> (rates, rounds, truncated)`` so the engine, the standalone
-# sharing loop, and rates_for all select by name through this one table
-# instead of string branches; ``rounds`` (i32) is the solve's
+# flow_caps, owned) -> (rates, rounds, truncated)`` so the engine, the
+# standalone sharing loop, and rates_for all select by name through this
+# one table instead of string branches; ``rounds`` (i32) is the solve's
 # progressive-filling rounds and ``truncated`` (bool) whether it stopped
 # at ``max_iters`` with a flow unfrozen, which the engine counts
 # (``LoopCounters.fill_rounds``, ``fill_truncated``); 0 and False for the

@@ -20,7 +20,9 @@ consumed), ``f_pr`` (drained flows), ``processed`` (provider utilisation
 counters).  Context delta: the full interval fact sheet (``r``, ``live``,
 ``thresh``, ``done``, ``dt``, ``t0``/``t_new``, ``has_event``, ``tick``,
 ``period``, ``compact``, ``compact_ok``) every later stage reads, and the
-solve's ``fill_rounds``.  The bucket tier comes in ``ctx.bucket``.
+solve's ``fill_rounds``, ``fill_truncated`` and ``provider_only``.  The
+bucket tier comes in ``ctx.bucket``, and whether every active flow's
+consumer is its own in ``ctx.own_consumers``.
 """
 from __future__ import annotations
 
@@ -118,6 +120,10 @@ def advance(ctx: StageCtx, st: CloudState):
     rate_fn = SCHEDULERS[spec.scheduler]
     # utilisation caps lie below their VM spreader's share (DESIGN.md §7)
     flow_caps = getattr(trace, "util", None) is not None
+    # no two active flows name one consumer: the jnp max-min solve reduces
+    # over the providers alone, to the same bits (DESIGN.md §7)
+    owned = (ctx.own_consumers
+             if (spec.scheduler, spec.backend) == ("maxmin", "jnp") else None)
 
     if ctx.bucket is not None:
         # ---- compacted fair-share solve (DESIGN.md §7) ------------------
@@ -135,7 +141,7 @@ def advance(ctx: StageCtx, st: CloudState):
         r_b, fill_rounds, truncated = rate_fn(
             cp.bprov, cp.bcons, f_pl_b, live_b, perf_b,
             backend=spec.backend, max_iters=spec.max_fill_iters,
-            flow_caps=flow_caps)
+            flow_caps=flow_caps, owned=owned)
         r = cpk.scatter_flows(cp, F, r_b)
         flow_cand = [f_pr_b / jnp.maximum(r_b, 1e-30),   # completion  [FB]
                      f_rel_b - st.t]                     # latency     [FB]
@@ -147,7 +153,7 @@ def advance(ctx: StageCtx, st: CloudState):
         r, fill_rounds, truncated = rate_fn(
             st.f_prov, st.f_cons, st.f_pl, live, perf,
             backend=spec.backend, max_iters=spec.max_fill_iters,
-            flow_caps=flow_caps)
+            flow_caps=flow_caps, owned=owned)
         flow_cand = [st.f_pr / jnp.maximum(r, 1e-30),    # completion   [F]
                      st.f_release - st.t]                # latency      [F]
         flow_mask = [live & (r > 0),
@@ -246,7 +252,8 @@ def advance(ctx: StageCtx, st: CloudState):
                        t0=st.t, t_new=t_new, has_event=has_event,
                        tick=tick, period=period, compact=cp,
                        compact_ok=None if cp is None else cp.ok,
-                       fill_rounds=fill_rounds, fill_truncated=truncated)
+                       fill_rounds=fill_rounds, fill_truncated=truncated,
+                       provider_only=owned)
     st = st._replace(t=t_new, t_c=t_c, n_events=st.n_events + 1,
                      meter_next=meter_next, f_pr=f_pr, processed=processed)
     return ctx, st

@@ -88,6 +88,14 @@ def termination(ctx: StageCtx, st: CloudState, snap) -> CloudState:
     return st._replace(running=(ctx.has_event | changed) & more & ~hit_stop)
 
 
+def own_consumers(spec, st: CloudState) -> jax.Array:
+    """Whether no active flow names a shared consumer, an image transfer's
+    or a migration's ``netin``: every consumer at or above ``layout.vm0``
+    is one flow's own VM or hidden spreader, so the fair-share solve may
+    reduce over the providers alone (DESIGN.md §7)."""
+    return ~jnp.any(st.f_active & (st.f_cons < spec.layout.vm0))
+
+
 # Coalesced event stepping (DESIGN.md §7): how many pipeline passes one
 # ``lax.while_loop`` body runs when ``spec.steps_per_iter == 0`` (auto).
 # Tuned by ``benchmarks/microbench_steps.py``: on XLA:CPU the while_loop
@@ -112,8 +120,8 @@ def make_body(spec, params, trace, t_stop, t_next=None, axis_name=None):
     :class:`LoopCounters` each pass reports through its context.
 
     ``axis_name`` names the ``vmap`` axis the body runs under, if any
-    (:data:`LANE_AXIS`): the bucket tier is then chosen for all lanes at
-    once.
+    (:data:`LANE_AXIS`): the bucket tier, and whether the fair-share solve
+    reduces over providers alone, are then chosen for all lanes at once.
 
     ``t_next`` (streaming windows only, DESIGN.md §8) is the first arrival
     of the next trace window; ``None`` — the monolithic engine — composes
@@ -176,7 +184,11 @@ def make_body(spec, params, trace, t_stop, t_next=None, axis_name=None):
                        t_next=t_next, arrival_sorted=arrival_sorted)
         snap = (st.task_state, st.vstage, st.pstate, st.f_active)
         n_active = jnp.sum(st.f_active, dtype=jnp.int32)
-        ctx = ctx._replace(live_flows=n_active)
+        # one verdict for every lane, so the solve's cond stays one branch
+        own = own_consumers(spec, st)
+        if axis_name is not None:
+            own = jax.lax.pmin(own.astype(jnp.int32), axis_name) > 0
+        ctx = ctx._replace(live_flows=n_active, own_consumers=own)
         if len(tiers) == 2:
             # Bucket tiers (DESIGN.md §7): the smallest that holds every
             # active flow, of every lane under vmap.  Either gives the

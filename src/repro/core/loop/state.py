@@ -130,7 +130,9 @@ class LoopCounters(NamedTuple):
     each iteration started with.  ``mem_bound`` counts dispatches whose
     fit on cores and memory chose another PM than a fit on cores alone;
     ``fill_truncated`` fair-share solves that stopped at
-    ``spec.max_fill_iters`` with a flow unfrozen.
+    ``spec.max_fill_iters`` with a flow unfrozen;
+    ``provider_only_solves`` the solves that reduced over providers alone,
+    no two active flows naming one consumer (DESIGN.md §7).
     """
 
     fill_rounds: jax.Array   # i32 progressive-filling rounds (fair share)
@@ -142,11 +144,12 @@ class LoopCounters(NamedTuple):
     live_flows: jax.Array    # i32 active flows, summed over iterations
     mem_bound: jax.Array     # i32 memory-bound dispatches
     fill_truncated: jax.Array  # i32 solves cut at max_fill_iters
+    provider_only_solves: jax.Array  # i32 solves over providers alone
 
     @classmethod
     def zero(cls) -> "LoopCounters":
         z = jnp.int32(0)
-        return cls(z, z, z, jnp.zeros((4,), jnp.int32), z, z, z, z, z)
+        return cls(z, z, z, jnp.zeros((4,), jnp.int32), z, z, z, z, z, z)
 
     @classmethod
     def of(cls, ctx: "StageCtx") -> "LoopCounters":
@@ -160,7 +163,7 @@ class LoopCounters(NamedTuple):
                               count(ctx.vm_gate)]),
                    count(ctx.small_bucket), count(ctx.dense_bucket),
                    count(ctx.live_flows), count(ctx.mem_bound),
-                   count(ctx.fill_truncated))
+                   count(ctx.fill_truncated), count(ctx.provider_only))
 
     def plus(self, other: "LoopCounters") -> "LoopCounters":
         return jax.tree.map(jnp.add, self, other)
@@ -201,6 +204,12 @@ class StageCtx(NamedTuple):
     # this pass (one of ``loop.compact.compact_tiers``, chosen by the
     # driver); ``None`` runs them dense.
     bucket: tuple[int, int] | None = None
+    # Whether no active flow names a shared consumer (an image transfer's
+    # or a migration's ``netin``), so every live flow's consumer is its own
+    # VM or hidden spreader: the fair-share solve may then reduce over the
+    # providers alone (DESIGN.md §7).  Set by the driver, one value for
+    # every lane under vmap; ``None`` runs the solve over both sides.
+    own_consumers: jax.Array | None = None
 
     # -- filled by the `advance` stage -----------------------------------
     compact: Any = None          # loop.compact.Compact of this iteration
@@ -226,6 +235,8 @@ class StageCtx(NamedTuple):
     fill_rounds: jax.Array | None = None  # i32 fair-share solve rounds
     fill_truncated: jax.Array | None = None  # bool the solve stopped at
     #                                          max_fill_iters unfinished
+    provider_only: jax.Array | None = None  # bool the solve reduced over
+    #                                         providers alone
 
     # -- filled by the `observe` stage -----------------------------------
     view: Any = None             # energy.SimView of [t0, t_new]

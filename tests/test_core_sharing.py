@@ -1,4 +1,7 @@
 """Unit + property tests for the unified resource sharing core (paper §3.2)."""
+import functools
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +11,9 @@ from hypothesis import given, settings, strategies as st
 from repro.core.fairshare import (equal_share_rates, maxmin_fill,
                                   maxmin_rates)
 from repro.core.influence import group_sizes, influence_labels
+from repro.core.loop import compact as cpk
+from repro.core.loop.driver import own_consumers
+from repro.core.machine import SpreaderLayout
 from repro.core.network import make_topology, transfers_problem
 from repro.core.sharing import SharingProblem, run_sharing, run_sharing_tau
 
@@ -198,6 +204,120 @@ def test_maxmin_flow_caps_general_property(data):
     if (p_l >= 1e30).all():
         np.testing.assert_array_equal(
             r, _fill(provider, consumer, p_l, perf, flow_caps=False)[0])
+
+
+# ---------------------------------------------------------------------------
+# provider-only rounds: consumers that one flow each names (DESIGN.md §7)
+# ---------------------------------------------------------------------------
+
+_P, _V = 16, 256
+_BIG_CAP = 3.0e38
+
+
+def _engine_flows(rng, beta_caps, transfers=0):
+    """A fair-share problem in the engine's spreader layout: VM flow ``i``
+    runs from its host's CPU to its own VM spreader, hidden flow ``V + j``
+    from PM ``j``'s CPU to its own hidden spreader; an inactive slot keeps
+    a stale consumer, as a finished image transfer leaves its ``netin``.
+    Caps are utilisation caps (``beta_caps``) or a few sparse ones; on
+    half the hosts the VMs' caps are raised until the host saturates.
+    ``transfers`` live VM flows become image transfers from the repository
+    into one PM's ``netin``, the one consumer flows share.  Returns the
+    spec and flow-state views the engine's predicate reads, and the
+    solve's inputs."""
+    lay = SpreaderLayout(_P, _V)
+    F = _V + _P
+    host = rng.randint(0, _P, _V)
+    cores = np.array([1.0, 2.0, 4.0, 8.0, 16.0])[rng.randint(0, 5, _V)]
+    prov = np.concatenate([lay.cpu0 + host, lay.cpu0 + np.arange(_P)])
+    cons = np.concatenate([lay.vm0 + np.arange(_V),
+                           lay.hidden0 + np.arange(_P)])
+    active = rng.rand(F) < 0.6
+    live = active & (rng.rand(F) < 0.9)
+    stale = np.where(rng.rand(F) < 0.5, lay.netin0 + rng.randint(0, _P, F), 0)
+    cons = np.where(active, cons, stale)
+    if beta_caps:
+        util = np.clip(rng.beta(0.6, 2.4, F), 0.01, 1.0)
+        p_l = util * np.concatenate([cores, np.full(_P, 12.8)])
+    else:
+        p_l = np.where(rng.rand(F) < 0.2, rng.uniform(0.1, 8.0, F), _BIG_CAP)
+    hot = rng.rand(_P) < 0.5
+    p_l[:_V] = np.where(hot[host], np.maximum(p_l[:_V], 0.9 * cores),
+                        p_l[:_V])
+    perf = np.zeros(lay.S)
+    perf[lay.cpu0:lay.netin0] = 64.0
+    perf[lay.netin0:lay.repo_out] = 125.0
+    perf[lay.repo_out] = perf[lay.repo_disk] = 250.0
+    perf[lay.vm0:lay.hidden0] = np.where(rng.rand(_V) < 0.95, cores, 0.0)
+    perf[lay.hidden0:] = 64.0
+    if transfers:
+        xfer = np.flatnonzero(live[:_V])[:transfers]
+        prov[xfer], cons[xfer] = lay.repo_out, lay.netin0 + host[xfer[0]]
+        p_l[xfer] = _BIG_CAP
+    spec = SimpleNamespace(n_pm=_P, n_vm=_V, layout=lay)
+    st = SimpleNamespace(f_active=jnp.asarray(active),
+                         f_prov=jnp.asarray(prov, jnp.int32),
+                         f_cons=jnp.asarray(cons, jnp.int32))
+    return (spec, st, jnp.asarray(live), jnp.asarray(p_l, jnp.float32),
+            jnp.asarray(perf, jnp.float32))
+
+
+def _solve_args(spec, st, live, p_l, perf, ids):
+    """The solve's inputs over dense ids, or over bucket slots as the
+    engine's compacted ``advance`` gathers them."""
+    if ids == "dense":
+        return st.f_prov, st.f_cons, p_l, live, perf
+    cp = cpk.build_compact(spec, st, 256, 512)
+    assert bool(cp.ok)
+    return (cp.bprov, cp.bcons, cpk.gather_flows(cp, p_l, 0.0),
+            cpk.gather_flows(cp, live, False),
+            jnp.take(perf, cp.sidx, mode="clip"))
+
+
+def _same_solve(a, b):
+    (ra, na, ta), (rb, nb, tb) = a, b
+    np.testing.assert_array_equal(np.asarray(ra).view(np.int32),
+                                  np.asarray(rb).view(np.int32))
+    assert int(na) == int(nb) and bool(ta) == bool(tb)
+
+
+@pytest.mark.parametrize("ids", ["dense", "bucket"])
+@pytest.mark.parametrize("flow_caps", [False, True])
+def test_provider_only_rounds_match_bitwise(flow_caps, ids):
+    """Owned VM and hidden consumers, shared PM providers: the
+    provider-only rounds give the rates, round count and truncation of
+    the rounds over both sides, bit for bit."""
+    solve = jax.jit(functools.partial(maxmin_fill, flow_caps=flow_caps))
+    rounds = []
+    for seed in range(12):
+        spec, st, *prob = _engine_flows(np.random.RandomState(seed),
+                                        beta_caps=seed % 2 == 0)
+        own = own_consumers(spec, st)
+        assert bool(own)
+        args = _solve_args(spec, st, *prob, ids)
+        want = solve(*args)
+        _same_solve(solve(*args, owned=own), want)
+        rounds.append(int(want[1]))
+    assert max(rounds) > 2
+
+
+@pytest.mark.parametrize("flow_caps", [False, True])
+def test_provider_only_rounds_not_taken_with_a_live_netin(flow_caps):
+    """Two image transfers share one PM's ``netin``: the engine's
+    predicate is false, so the solve keeps both sides, where the
+    provider-only round would give each transfer the whole link."""
+    spec, st, live, p_l, perf = _engine_flows(
+        np.random.RandomState(7), beta_caps=flow_caps, transfers=2)
+    own = own_consumers(spec, st)
+    assert not bool(own)
+    solve = jax.jit(functools.partial(maxmin_fill, flow_caps=flow_caps))
+    args = _solve_args(spec, st, live, p_l, perf, "dense")
+    want = solve(*args)
+    _same_solve(solve(*args, owned=own), want)
+    xfer = np.flatnonzero(np.asarray(st.f_prov) == spec.layout.repo_out)
+    np.testing.assert_allclose(np.asarray(want[0])[xfer], 62.5)
+    wrong = solve(*args, owned=jnp.bool_(True))[0]
+    np.testing.assert_allclose(np.asarray(wrong)[xfer], 125.0)
 
 
 def test_equal_share_simple():

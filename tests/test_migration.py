@@ -12,6 +12,7 @@ traces, all meter readings — see CHANGES.md PR 4); the tests here pin the
 behaviours that must keep holding without access to the old monolith.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -19,8 +20,11 @@ import numpy as np
 import pytest
 
 from repro.core import engine as eng
+from repro.core import fairshare, loop
 from repro.core import machine as mc
+from repro.core.arrays import KIND_HIDDEN
 from repro.core.energy import PM_OFF, PM_RUNNING, PM_SWITCHING_OFF
+from repro.core.loop.state import KIND_MIGRATE, LoopCounters
 
 
 def _answers(res):
@@ -286,4 +290,54 @@ def test_migrate_one_refuses_a_host_without_the_memory():
     assert int(np.asarray(st.vm_host)[d_vm]) == 1
     moved = migrate_one(spec, params, st, d_vm, 0, jnp.bool_(True))
     for a, b in zip(jax.tree.leaves(moved), jax.tree.leaves(st)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ------------------------------------------- provider-only fair-share solve
+
+@pytest.mark.parametrize("complex_power", [False, True])
+def test_owned_consumers_through_migrations(monkeypatch, complex_power):
+    """The provider-only solve (DESIGN.md §7) is exact because a consumer
+    at or above ``layout.vm0`` is one flow's own: at every step of a
+    consolidating run with memory and utilisation caps, each active flow
+    that names such a consumer names its own VM or hidden spreader.  The
+    run takes the provider-only solve in some passes, not while a
+    migration or an image transfer is live, and gives the answers of
+    solves that never take it."""
+    tr = _mem_trace(50.0)._replace(util=jnp.full((4,), 0.5, jnp.float32))
+    spec, params = eng.make_cloud(n_pm=2, n_vm=8, pm_cores=100.0,
+                                  pm_mem=256.0, pm_sched="consolidate",
+                                  complex_power=complex_power)
+    lay, V, P = spec.layout, spec.n_vm, spec.n_pm
+    own = np.concatenate([lay.vm0 + np.arange(V), lay.hidden0 + np.arange(P)])
+    body = jax.jit(loop.make_body(spec, params, tr, jnp.float32(jnp.inf)))
+    start = jax.jit(functools.partial(loop.management_pass, spec, params, tr))
+    carry = (start(eng.init_state(spec, tr, params)), jnp.bool_(True),
+             LoopCounters.zero())
+    kinds = set()
+    while bool(carry[0].running):
+        st = carry[0]
+        active = np.asarray(st.f_active)
+        cons = np.asarray(st.f_cons)
+        named = active & (cons >= lay.vm0)
+        np.testing.assert_array_equal(cons[named], own[named])
+        kinds |= set(np.asarray(st.f_kind)[active].tolist())
+        carry = body(carry)
+    assert KIND_MIGRATE in kinds
+    assert (KIND_HIDDEN in kinds) == complex_power
+
+    res = eng.simulate(spec, tr, params=params)
+    for a, b in zip(jax.tree.leaves(carry[2]), jax.tree.leaves(res.counters)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    n_own = int(res.counters.provider_only_solves)
+    assert 0 < n_own < int(res.n_events)
+
+    # the same engine with every solve over both sides
+    with monkeypatch.context() as m:
+        m.setitem(fairshare.SCHEDULERS, "maxmin",
+                  lambda *a, owned=None, **kw: fairshare.maxmin_fill(*a, **kw))
+        eng.simulate.clear_cache()
+        both = eng.simulate(spec, tr, params=params)
+    eng.simulate.clear_cache()
+    for a, b in zip(_answers(res), _answers(both)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -352,7 +352,9 @@ def _sweep_points(spec, n):
 def test_stream_batch_matches_sequential_bitwise():
     """Every lane of ``simulate_stream_batch`` is bit-identical to its own
     sequential ``simulate_stream`` call (vmap computes lanes independently;
-    heterogeneous policy codes stay traced data)."""
+    heterogeneous policy codes stay traced data), but for the
+    provider-only solves, which the lanes take together: a lane counts at
+    most its own run's."""
     from repro.experiments.shard import simulate_stream_batch
     spec, _ = engine.make_cloud(n_pm=2, n_vm=8, pm_cores=4.0)
     wt = chunk_trace(_ramp_trace(10), 5)
@@ -363,6 +365,10 @@ def test_stream_batch_matches_sequential_bitwise():
     for i, p in enumerate(pts):
         one = jax.block_until_ready(engine.simulate_stream(spec, wt, p))
         lane = jax.tree.map(lambda l: l[i], batch)
+        own = one.counters.provider_only_solves
+        assert lane.counters.provider_only_solves <= own, i
+        lane = lane._replace(counters=lane.counters._replace(
+            provider_only_solves=own))
         for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(lane)):
             np.testing.assert_array_equal(_bits(a), _bits(b))
 
@@ -370,7 +376,8 @@ def test_stream_batch_matches_sequential_bitwise():
 def test_stream_batch_two_devices_subprocess():
     """The ``shard_map`` branch of the batched window step: forced 2-host
     -device topology, even and padded (prime) batch sizes, every valid
-    lane bitwise vs sequential ``simulate_stream``."""
+    lane bitwise vs sequential ``simulate_stream``, but for the
+    provider-only solves, which a shard's lanes take together."""
     import os
     import pathlib
     import subprocess
@@ -407,6 +414,10 @@ for n in (4, 3):  # even split, then pad-and-mask (3 lanes over 2 devices)
     for i, p in enumerate(pts(n)):
         one = engine.simulate_stream(spec, wt, p)
         lane = jax.tree.map(lambda l: l[i], batch)
+        own = one.counters.provider_only_solves
+        assert lane.counters.provider_only_solves <= own, i
+        lane = lane._replace(counters=lane.counters._replace(
+            provider_only_solves=own))
         for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(lane)):
             np.testing.assert_array_equal(bits(a), bits(b))
 print("STREAM_SHARDED_BITWISE_OK")

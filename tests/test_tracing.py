@@ -161,11 +161,18 @@ def test_no_arrivals_no_rounds():
 
 
 def test_batched_counters_equal_sequential(grid_runs):
+    """Every lane counts what its sequential run counts, but for the
+    provider-only solves: under vmap a pass takes them only where no lane
+    has a shared consumer live, so a lane counts at most its own."""
     _, seq, batch = grid_runs
     for i, res in enumerate(seq):
         for k, v in _counts(res.counters).items():
-            np.testing.assert_array_equal(
-                v, _counts(batch.counters)[k][i], err_msg=f"lane {i} {k}")
+            got = _counts(batch.counters)[k][i]
+            if k == "provider_only_solves":
+                assert got <= v, (i, got, v)
+            else:
+                np.testing.assert_array_equal(v, got,
+                                              err_msg=f"lane {i} {k}")
 
 
 def test_compaction_keeps_the_counts():
@@ -297,8 +304,12 @@ for child in ("repro.shard.pad", "repro.launch", "repro.compact_check",
 assert got.counters.gate_opens.shape == (3, 4), got.counters
 for i, p in enumerate(pts):
     one = engine.simulate(spec, tr, p)
-    for a, b in zip(jax.tree.leaves(one.counters),
-                    jax.tree.leaves(got.counters)):
+    # a shard's lanes take the provider-only solves together
+    own = int(got.counters.provider_only_solves[i])
+    assert own <= int(one.counters.provider_only_solves)
+    for a, b in zip(
+            jax.tree.leaves(one.counters._replace(provider_only_solves=None)),
+            jax.tree.leaves(got.counters._replace(provider_only_solves=None))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b)[i])
 print("SHARDED_COUNTERS_OK")
 """
