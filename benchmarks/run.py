@@ -1,5 +1,5 @@
-"""Benchmark runner: one module per paper table/figure + the roofline and
-fleet-scheduling reports.  ``python -m benchmarks.run [--full]``."""
+"""Benchmark runner: one module per paper table/figure + the batched
+experiment trajectories.  ``python -m benchmarks.run [--full]``."""
 from __future__ import annotations
 
 import argparse
@@ -21,17 +21,14 @@ def main(argv=None) -> int:
 
     from benchmarks import (consolidation_bench, energy_overhead,
                             ensemble_bench, microbench_steps, pareto_bench,
-                            roofline, scaling, sched_bench, sharing_perf,
-                            streaming_bench, sweep_bench, traces_bench,
-                            validation)
+                            scaling, sharing_perf, streaming_bench,
+                            sweep_bench, traces_bench, validation)
     modules = {
         "validation": validation,        # Fig 7/8/9/10
         "sharing_perf": sharing_perf,    # Fig 12 / Table 3
         "scaling": scaling,              # Fig 13 / Fig 15
         "traces": traces_bench,          # Fig 14
         "energy_overhead": energy_overhead,  # Fig 16/17
-        "roofline": roofline,            # §Roofline
-        "sched": sched_bench,            # energy-aware fleet matrix
         "sweep": sweep_bench,            # batched 8-point scenario sweep
         "pareto": pareto_bench,          # Pareto-front experiment (sharded)
         "ensemble": ensemble_bench,      # trace-ensemble experiment (sharded)

@@ -1,12 +1,11 @@
-"""Energy-aware scheduling of LM training/serving jobs on a TPU-pod fleet —
-the paper's technique closed over this framework's own workloads.
+"""Energy-aware scheduling on DAS-2 grid workloads — the paper's §4
+methodology end to end.
 
-Reads the dry-run roofline artifacts (experiments/dryrun/) to characterise
-each (arch x shape) job, builds a mixed fleet trace, runs the scheduler
-*tournament* (the paper's matrix via repro.experiments.tournament), then a
-trace-*ensemble* experiment — mean ± CI per policy over seed-perturbed job
-mixes (docs/experiments.md) — then a live-migration policy demo (the
-in-loop consolidate/defrag/evacuate PM schedulers, registry citizens from
+Runs the scheduler *tournament* (every registered VM x PM policy pair,
+repro.experiments.tournament) on a GWA DAS-2 trace, then a trace-*ensemble*
+experiment — mean ± CI per policy over seed-perturbed DAS-2 traces
+(docs/experiments.md) — then a live-migration policy demo (the in-loop
+consolidate/defrag/evacuate PM schedulers, registry citizens from
 repro.sched.policies, DESIGN.md §5-§6) and a per-tenant bill from the
 per-VM Eq. 6 meters.
 
@@ -19,44 +18,30 @@ import numpy as np
 
 from repro.core import engine
 from repro.core.energy import tenant_energy
-from repro.experiments import ensemble
-from repro.sched import energy_aware as ea
+from repro.core.trace import gwa_like_trace
+from repro.experiments import ensemble, tournament
 
-print("=== energy-aware fleet scheduling " + "=" * 33)
-cells = ea.load_cells("experiments/dryrun")
-if not cells:
-    print("(no dry-run artifacts; using synthetic cell timings)")
-    cells = {
-        ("jamba-like", "train_4k"): ea.CellPerf("jamba-like", "train_4k",
-                                                0.9, 0.5, 0.4),
-        ("rwkv-like", "decode_32k"): ea.CellPerf("rwkv-like", "decode_32k",
-                                                 0.002, 0.03, 0.001),
-    }
-print(f"job models from {len(cells)} dry-run cells")
-for (arch, shape), c in sorted(cells.items())[:6]:
-    print(f"  {arch:24s} {shape:12s} step={c.step_s*1e3:9.2f} ms "
-          f"bottleneck={c.bottleneck:10s} util={c.utilisation:.2f}")
-
-jobs = ea.default_job_mix(cells, n_jobs=24, seed=2)
-trace = ea.job_trace(jobs, cells, arrival_spread_s=3600.0, seed=2)
-print(f"\nfleet: {trace.n} jobs over 8 pods "
-      f"({ea.POD_CHIPS} chips each)\n")
-# the scheduler tournament experiment: the whole VM x PM matrix is one
-# sharded simulate_batch call (repro.experiments.tournament)
-rows = ea.evaluate_schedulers(trace, n_pods=8)
+print("=== scheduler tournament on a DAS-2 trace " + "=" * 25)
+PM_CORES = 64.0
+trace = gwa_like_trace("das2", 300, max_cores=int(PM_CORES), seed=2)
+spec = engine.CloudSpec(n_pm=16, n_vm=128)
+base = engine.CloudParams(pm_cores=PM_CORES)
+print(f"cloud: {spec.n_pm} PMs x {PM_CORES:.0f} cores, {trace.n} tasks "
+      f"over {float(trace.arrival[-1]) / 3600:.1f} h\n")
+# the whole VM x PM matrix is one sharded simulate_batch call
+rows = tournament.run(spec, trace, base).rows
 # meter-stack columns: IT energy (whole-IaaS aggregate), the job-attributed
 # share (per-VM Eq. 6 meters), idle waste, and HVAC (indirect meter)
-print(f"{'VM sched':>14s} {'PM sched':>9s} {'IT kWh':>9s} {'job kWh':>9s} "
-      f"{'idle kWh':>9s} {'HVAC kWh':>9s} {'makespan h':>11s} "
-      f"{'mean wait h':>12s}")
+print(f"{'VM sched':>14s} {'PM sched':>11s} {'IT kWh':>8s} {'job kWh':>8s} "
+      f"{'idle kWh':>8s} {'HVAC kWh':>8s} {'makespan h':>11s} "
+      f"{'rejected':>9s}")
 for r in rows:
-    print(f"{r['vm_sched']:>14s} {r['pm_sched']:>9s} "
-          f"{r['energy_kwh']:9.1f} {r['job_kwh']:9.1f} "
-          f"{r['idle_kwh']:9.1f} {r['hvac_kwh']:9.1f} "
-          f"{r['makespan_s']/3600:11.2f} "
-          f"{r['mean_completion_s']/3600:12.2f}")
-# only compare policies that actually served the fleet (non-queuing cells
-# may reject jobs outright — cheap, but not by doing the work)
+    print(f"{r['vm_sched']:>14s} {r['pm_sched']:>11s} "
+          f"{r['energy_kwh']:8.2f} {r['job_kwh']:8.2f} "
+          f"{r['idle_kwh']:8.2f} {r['hvac_kwh']:8.2f} "
+          f"{r['makespan_s']/3600:11.2f} {r['jobs_rejected']:9d}")
+# only compare policies that actually served the trace (non-queuing cells
+# on sleeping PMs reject tasks outright — cheap, but not by doing the work)
 served = [r for r in rows if r["jobs_rejected"] == 0] or rows
 best = min(served, key=lambda r: r["energy_kwh"])
 worst = max(served, key=lambda r: r["energy_kwh"])
@@ -65,22 +50,21 @@ print(f"\nbest policy: {best['vm_sched']}+{best['pm_sched']} saves "
       f"{worst['vm_sched']}+{worst['pm_sched']}")
 
 # ------------------------------------------------------------------ ensemble
-# one job mix is an anecdote: re-sample it and report mean ± 95% CI per
+# one trace is an anecdote: re-sample it and report mean ± 95% CI per
 # policy (the trace-ensemble experiment, docs/experiments.md §5)
-print("\n=== ensemble: mean ± 95% CI over 4 seeded job mixes " + "=" * 14)
-traces = ensemble.job_mix_ensemble(cells, replicates=4, n_jobs=24,
-                                   arrival_spread_s=3600.0, seed0=10)
+print("\n=== ensemble: mean ± 95% CI over 4 seeded DAS-2 traces " + "=" * 10)
+traces = ensemble.gwa_ensemble("das2", 200, replicates=4, pm_cores=PM_CORES,
+                               seed0=10)
 policies = [("firstfit", "alwayson"), ("firstfit", "ondemand"),
             ("smallestfirst", "ondemand")]
-espec = engine.CloudSpec(n_pm=8, n_vm=max(int(traces[0].n), 8))
 er = ensemble.run_ensemble(
-    espec, traces,
-    [ea.fleet_params(vm_sched=v, pm_sched=p) for v, p in policies],
+    spec, traces,
+    [dataclasses.replace(base, vm_sched=v, pm_sched=p) for v, p in policies],
     labels=[{"policy": f"{v}+{p}"} for v, p in policies])
 for r in er.rows:
-    print(f"{r['policy']:>24s}  energy {r['energy_kwh_mean']:7.1f} "
-          f"± {r['energy_kwh_ci']:6.1f} kWh  idle {r['idle_kwh_mean']:6.1f} "
-          f"± {r['idle_kwh_ci']:5.1f} kWh  makespan "
+    print(f"{r['policy']:>24s}  energy {r['energy_kwh_mean']:6.2f} "
+          f"± {r['energy_kwh_ci']:5.2f} kWh  idle {r['idle_kwh_mean']:6.2f} "
+          f"± {r['idle_kwh_ci']:5.2f} kWh  makespan "
           f"{r['makespan_s_mean']/3600:5.2f} ± {r['makespan_s_ci']/3600:4.2f} h")
 
 # ---------------------------------------------------------------- migration

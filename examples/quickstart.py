@@ -1,26 +1,17 @@
-"""Quickstart: the three layers of the framework in two minutes (CPU).
+"""Quickstart: an energy-aware cloud simulation in under a minute (CPU).
 
-1. simulate an energy-aware cloud scenario (the paper's core),
-2. train a reduced LM for a few steps,
-3. serve it with batched decode.
+Simulates one cloud scenario, reads its hierarchical meter stack, and
+sweeps NIC bandwidth into an energy-vs-makespan Pareto frontier.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
-import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro import configs
 from repro.core import engine
 from repro.core.trace import synthetic_trace
 from repro.experiments import pareto
-from repro.data.pipeline import DataConfig, make_batch
-from repro.models import common as cm, lm
-from repro.serve.engine import Request, ServeEngine
-from repro.train import step as step_mod
 
-# ---------------------------------------------------------------- 1. simulate
-print("=== 1. DISSECT-CF cloud simulation " + "=" * 30)
+print("=== DISSECT-CF cloud simulation " + "=" * 33)
 # CloudSpec holds the static shape (jit-recompiles when it changes);
 # CloudParams holds every continuous knob + scheduler codes (traced data —
 # change or batch them freely under one compile).
@@ -54,27 +45,4 @@ for r in front.rows:  # '*' marks frontier membership
           f"energy {r['energy_kwh']:.2f} kWh  "
           f"makespan {r['makespan_s']:4.0f} s")
 
-# ------------------------------------------------------------------- 2. train
-print("=== 2. train a reduced jamba (mamba+MoE hybrid) " + "=" * 18)
-cfg = configs.get_reduced("jamba-v0.1-52b")
-state = step_mod.init_state(cfg, jax.random.PRNGKey(0))
-train = jax.jit(step_mod.make_train_step(cfg, peak_lr=5e-3, warmup_steps=5,
-                                         total_steps=20, xent_chunk=16))
-dcfg = DataConfig(vocab=cfg.vocab, seq_len=32, global_batch=4)
-for i in range(20):
-    batch = {k: jnp.asarray(v)
-             for k, v in make_batch(dcfg, i, model_cfg=cfg).items()}
-    state, m = train(state, batch)
-    if i % 5 == 0 or i == 19:
-        print(f"  step {i:2d}  loss {float(m['loss']):.3f}")
-
-# ------------------------------------------------------------------- 3. serve
-print("=== 3. batched serving " + "=" * 43)
-eng = ServeEngine(cfg, state["params"], batch_size=4, max_len=64, eos_id=-1)
-for rid in range(4):
-    eng.submit(Request(rid=rid, prompt=[1 + rid, 2, 3], max_new_tokens=8))
-stats = eng.run()
-print(f"  {stats['requests']} requests, {stats['tokens']} tokens, "
-      f"{stats['tokens_per_s']:.1f} tok/s, "
-      f"p50 latency {stats['p50_latency_s']*1e3:.0f} ms")
 print("quickstart OK")

@@ -12,8 +12,7 @@ Three API families, mirroring the paper:
   out of the spreader space (:func:`deregister_pm` abruptly kills hosted
   VMs, the paper's "violent deregistration" used for fault-injection).
 
-The facade is what user-side schedulers (and the energy-aware fleet
-scheduler in :mod:`repro.sched`) consume.
+The facade is what user-side schedulers consume.
 """
 from __future__ import annotations
 

@@ -1,99 +1,12 @@
-"""Deterministic synthetic token pipeline (sharded, resumable, infinite).
+"""Deterministic synthetic workload streams for the streaming engine.
 
-Production shape without external data: a counter-keyed PRNG stream yields
-Zipf-distributed tokens (vocabulary statistics roughly matching natural
-text), so every batch is a pure function of ``(seed, step)`` —
-
-* **resumable**: restart at step k reproduces batch k exactly (the loader
-  state *is* the step counter, checkpointed for free);
-* **host-shardable**: each host materialises only its slice of the global
-  batch (``host_slice``), then ``jax.device_put`` with the batch sharding
-  assembles the global array — the standard multi-host input path;
-* **arch-aware**: emits the extra modality inputs (VLM patch embeddings,
-  enc-dec frame embeddings) as deterministic pseudo-features.
-
-The LM objective is next-token prediction over the synthetic stream with a
-planted bigram structure, so training loss measurably decreases — which is
-what the integration tests assert.
+Every window is a pure function of ``(seed, family, window index)``, so a
+replay is reproducible and resumable at any window without materialising
+the trace before it.
 """
 from __future__ import annotations
 
-import dataclasses
-
 import numpy as np
-
-from repro.configs.shapes import VLM_PATCHES
-
-
-@dataclasses.dataclass(frozen=True)
-class DataConfig:
-    vocab: int
-    seq_len: int
-    global_batch: int
-    seed: int = 0
-    zipf_a: float = 1.2
-    planted_period: int = 4     # every nth token is predictable from t-1
-
-
-def _rng_for(cfg: DataConfig, step: int, host: int) -> np.random.Generator:
-    key = (cfg.seed & 0xFFFFFFFF) << 96 | (step & 0xFFFFFFFF) << 64 \
-        | (host & 0xFFFFFFFF) << 32 | 0xD15C
-    return np.random.Generator(np.random.Philox(key=key % (1 << 128)))
-
-
-def _zipf_tokens(rng, shape, vocab, a):
-    # inverse-CDF zipf truncated to vocab (dense, vectorised)
-    u = rng.random(shape)
-    ranks = np.exp(u * np.log(vocab))  # log-uniform ~ zipf-ish tail
-    return np.minimum(ranks.astype(np.int64), vocab - 1).astype(np.int32)
-
-
-def make_batch(cfg: DataConfig, step: int, *, host: int = 0,
-               n_hosts: int = 1, model_cfg=None) -> dict[str, np.ndarray]:
-    """Host-local slice of global batch ``step`` (numpy, ready to shard)."""
-    assert cfg.global_batch % n_hosts == 0
-    b = cfg.global_batch // n_hosts
-    rng = _rng_for(cfg, step, host)
-    T = cfg.seq_len
-
-    fam = getattr(model_cfg, "family", "dense") if model_cfg else "dense"
-    d_model = getattr(model_cfg, "d_model", 0)
-
-    if fam == "vlm":
-        P = min(VLM_PATCHES, max(T // 4, 1))
-        text_len = T - P
-        toks = _zipf_tokens(rng, (b, text_len), cfg.vocab, cfg.zipf_a)
-        _plant(toks, cfg)
-        patches = rng.standard_normal((b, P, d_model)).astype(np.float32)
-        targets = np.concatenate(
-            [np.zeros((b, P), np.int32),
-             np.concatenate([toks[:, 1:], toks[:, :1]], axis=1)], axis=1)
-        mask = np.concatenate(
-            [np.zeros((b, P), np.float32),
-             np.ones((b, text_len), np.float32)], axis=1)
-        mask[:, -1] = 0.0
-        return {"tokens": toks, "patches": patches, "targets": targets,
-                "loss_mask": mask}
-
-    toks = _zipf_tokens(rng, (b, T), cfg.vocab, cfg.zipf_a)
-    _plant(toks, cfg)
-    targets = np.concatenate([toks[:, 1:], toks[:, :1]], axis=1)
-    mask = np.ones((b, T), np.float32)
-    mask[:, -1] = 0.0
-    batch = {"tokens": toks, "targets": targets.astype(np.int32),
-             "loss_mask": mask}
-    if fam == "encdec":
-        batch["frames"] = rng.standard_normal((b, T, d_model)).astype(
-            np.float32)
-    return batch
-
-
-def _plant(toks: np.ndarray, cfg: DataConfig) -> None:
-    """Plant a learnable bigram: token at planted positions = f(prev)."""
-    p = cfg.planted_period
-    idx = np.arange(toks.shape[1])
-    sel = (idx % p == p - 1) & (idx > 0)
-    toks[:, sel] = (toks[:, np.roll(idx, 1)[sel]] * 31 + 7) % cfg.vocab
 
 
 def gwa_window_stream(family: str, n_tasks: int, window: int, *,
@@ -106,11 +19,11 @@ def gwa_window_stream(family: str, n_tasks: int, window: int, *,
     :class:`~repro.core.engine.Trace` windows one at a time — the full
     ``n_tasks`` trace is never materialised, so a datacenter-year workload
     streams through :func:`repro.core.engine.simulate_stream` in O(window)
-    host memory.  Same counter-keyed determinism protocol as
-    :func:`make_batch`: window ``k``'s draws come from a Philox stream
-    keyed on ``(seed, family, k)``; only the arrival-time offset (a
-    float64 scalar) carries across windows, so arrivals are globally
-    sorted.  The last window is padded and masked (``gid == -1``).
+    host memory.  Counter-keyed determinism: window ``k``'s draws come
+    from a Philox stream keyed on ``(seed, family, k)``; only the
+    arrival-time offset (a float64 scalar) carries across windows, so
+    arrivals are globally sorted.  The last window is padded and masked
+    (``gid == -1``).
     """
     import zlib
 
@@ -155,23 +68,3 @@ def gwa_window_stream(family: str, n_tasks: int, window: int, *,
             gid=padded(np.arange(start, start + n), -1, np.int32),
         )
 
-
-class DataIterator:
-    """Stateful convenience wrapper (state = step counter)."""
-
-    def __init__(self, cfg: DataConfig, *, model_cfg=None, host: int = 0,
-                 n_hosts: int = 1, start_step: int = 0):
-        self.cfg = cfg
-        self.model_cfg = model_cfg
-        self.host = host
-        self.n_hosts = n_hosts
-        self.step = start_step
-
-    def __next__(self):
-        batch = make_batch(self.cfg, self.step, host=self.host,
-                           n_hosts=self.n_hosts, model_cfg=self.model_cfg)
-        self.step += 1
-        return batch
-
-    def __iter__(self):
-        return self

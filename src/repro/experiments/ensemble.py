@@ -4,7 +4,7 @@ One trace is an anecdote.  The paper's methodology (§4) and the GWA it
 draws from treat a workload as a *distribution*: to compare scheduler
 policies you re-sample the trace and report the mean and a confidence
 interval per policy.  This module builds seed-perturbed trace replicates
-(GWA-moment families or the fleet job mix), crosses them with a list of
+(GWA-moment families), crosses them with a list of
 parameter points, runs the whole (policy x replicate) ensemble as one
 (sharded) ``simulate_batch`` call, and reduces the meter-stack readings to
 ``mean / std / ci`` per policy (DESIGN.md §4).
@@ -32,19 +32,6 @@ def gwa_ensemble(family: str, n_tasks: int, replicates: int, *,
     :func:`~repro.core.engine.stack_traces`)."""
     return [gwa_like_trace(family, n_tasks, max_cores=int(pm_cores),
                            seed=seed0 + r)
-            for r in range(replicates)]
-
-
-def job_mix_ensemble(cells: dict, replicates: int, *, n_jobs: int = 24,
-                     arrival_spread_s: float = 1800.0, seed0: int = 0
-                     ) -> list[engine.Trace]:
-    """Seed-perturbed fleet job mixes (the
-    :func:`repro.sched.energy_aware.default_job_mix` workload)."""
-    from repro.sched import energy_aware as ea
-    return [ea.job_trace(ea.default_job_mix(cells, n_jobs=n_jobs,
-                                            seed=seed0 + r),
-                         cells, arrival_spread_s=arrival_spread_s,
-                         seed=seed0 + r)
             for r in range(replicates)]
 
 

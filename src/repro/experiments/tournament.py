@@ -8,10 +8,8 @@ paper's 3x2, or every registered pair at much larger cloud sizes — runs
 as a single (sharded) ``simulate_batch`` call and is scored from the
 meter stack (DESIGN.md §4).  The default axes come straight from
 :func:`repro.sched.registry.names`: registering a policy makes it a
-tournament citizen with no further wiring.
-:func:`repro.sched.energy_aware.evaluate_schedulers` is a thin wrapper
-over :func:`run` — this is the one code path for scheduler comparison,
-not a demo.
+tournament citizen with no further wiring.  This is the one code path
+for scheduler comparison, not a demo.
 """
 from __future__ import annotations
 

@@ -40,18 +40,3 @@ def masked_min_pallas(cand, mask):
     from . import horizon
     return horizon.masked_min(cand, mask, interpret=_interpret())
 
-
-def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
-                    prefix_len=0, q_offset=0, scale=None):
-    """Block-wise attention (see kernels/attention.py)."""
-    from . import attention
-    return attention.flash_attention(
-        q, k, v, causal=causal, window=window, softcap=softcap,
-        prefix_len=prefix_len, q_offset=q_offset, scale=scale,
-        interpret=_interpret())
-
-
-def linear_scan(a, x, h0=None):
-    """Chunked diagonal linear recurrence (see kernels/ssm.py)."""
-    from . import ssm
-    return ssm.linear_scan(a, x, h0, interpret=_interpret())

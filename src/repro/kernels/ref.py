@@ -77,67 +77,3 @@ def masked_min_ref(cand: jax.Array, mask: jax.Array) -> jax.Array:
     the engine's fused event-horizon reduction (loop/advance.py)."""
     return jnp.min(jnp.where(mask, cand, _BIG))
 
-
-# ---------------------------------------------------------------------------
-# attention (used by the LM stack): GQA + causal/window/softcap/prefix-LM
-# ---------------------------------------------------------------------------
-
-def attention_ref(
-    q: jax.Array,          # [B, Tq, Hq, D]
-    k: jax.Array,          # [B, Tk, Hkv, D]
-    v: jax.Array,          # [B, Tk, Hkv, D]
-    *,
-    causal: bool = True,
-    window: int = 0,        # >0: sliding window (tokens attend back w-1)
-    softcap: float = 0.0,   # >0: tanh logit soft-capping (gemma2)
-    prefix_len: int = 0,    # >0: bidirectional prefix (paligemma)
-    scale: float | None = None,
-    q_offset: int = 0,      # absolute position of q[0] (decode with cache)
-) -> jax.Array:
-    B, Tq, Hq, D = q.shape
-    _, Tk, Hkv, _ = k.shape
-    g = Hq // Hkv
-    scale = (D ** -0.5) if scale is None else scale
-    qr = q.reshape(B, Tq, Hkv, g, D)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qr.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    if softcap > 0:
-        logits = softcap * jnp.tanh(logits / softcap)
-    qpos = jnp.arange(Tq) + q_offset
-    kpos = jnp.arange(Tk)
-    mask = jnp.ones((Tq, Tk), bool)
-    if causal:
-        mask = kpos[None, :] <= qpos[:, None]
-    if window > 0:
-        mask = mask & (kpos[None, :] > qpos[:, None] - window)
-    if prefix_len > 0:
-        mask = mask | (kpos[None, :] < prefix_len)
-    logits = jnp.where(mask[None, None, None], logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
-    return out.reshape(B, Tq, Hq, D).astype(q.dtype)
-
-
-# ---------------------------------------------------------------------------
-# diagonal linear recurrence (mamba/rwkv6 time-mixing backbone)
-# ---------------------------------------------------------------------------
-
-def linear_scan_ref(a: jax.Array, x: jax.Array,
-                    h0: jax.Array | None = None) -> jax.Array:
-    """h_t = a_t * h_{t-1} + x_t over axis 1; returns all h_t.
-
-    Shapes: a, x: [B, T, D]; h0: [B, D] (zeros if None).  f32 accumulation.
-    """
-    B, T, D = x.shape
-    if h0 is None:
-        h0 = jnp.zeros((B, D), jnp.float32)
-
-    def step(h, ax):
-        a_t, x_t = ax
-        h = a_t * h + x_t
-        return h, h
-
-    a32 = jnp.swapaxes(a.astype(jnp.float32), 0, 1)
-    x32 = jnp.swapaxes(x.astype(jnp.float32), 0, 1)
-    _, hs = jax.lax.scan(step, h0.astype(jnp.float32), (a32, x32))
-    return jnp.swapaxes(hs, 0, 1).astype(x.dtype)

@@ -2,17 +2,13 @@
 in interpret mode (CPU) over shape/dtype/feature grids."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.attention import flash_attention
 from repro.kernels.horizon import NB, masked_min
 from repro.kernels.maxmin import fill_stats, maxmin_solve
-from repro.kernels.ssm import linear_scan
-from repro.models.attention import chunked_attention, naive_attention
 
 
 # ---------------------------------------------------------------------------
@@ -200,128 +196,3 @@ def test_masked_min_single_lane_survivor_at_block_edge():
                          interpret=True)
         assert float(got) == -3.25, N
 
-
-# ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
-
-CASES = [
-    dict(B=1, Tq=16, Tk=16, Hq=2, Hkv=2, D=8, causal=True),
-    dict(B=2, Tq=33, Tk=33, Hq=4, Hkv=2, D=16, causal=True),        # GQA+pad
-    dict(B=1, Tq=64, Tk=64, Hq=2, Hkv=1, D=32, causal=True,
-         window=16),                                                 # local
-    dict(B=1, Tq=48, Tk=48, Hq=2, Hkv=2, D=16, causal=True,
-         softcap=30.0),                                              # gemma2
-    dict(B=1, Tq=40, Tk=40, Hq=2, Hkv=1, D=16, causal=True,
-         prefix_len=8),                                              # vlm
-    dict(B=2, Tq=24, Tk=24, Hq=2, Hkv=2, D=8, causal=False),        # encoder
-]
-
-
-@pytest.mark.parametrize("case", CASES)
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_matches_ref(case, dtype):
-    case = dict(case)
-    B, Tq, Tk = case.pop("B"), case.pop("Tq"), case.pop("Tk")
-    Hq, Hkv, D = case.pop("Hq"), case.pop("Hkv"), case.pop("D")
-    key = jax.random.PRNGKey(hash(str(case)) % 2**31)
-    ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (B, Tq, Hq, D), dtype)
-    k = jax.random.normal(ks[1], (B, Tk, Hkv, D), dtype)
-    v = jax.random.normal(ks[2], (B, Tk, Hkv, D), dtype)
-    want = ref.attention_ref(q, k, v, **case)
-    got = flash_attention(q, k, v, interpret=True, block_q=16, block_k=128,
-                          **case)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=tol, atol=tol)
-
-
-@pytest.mark.parametrize("case", CASES)
-def test_chunked_attention_matches_naive(case):
-    """The model's jnp flash path (used by the dry-run) vs naive scores."""
-    case = dict(case)
-    B, Tq, Tk = case.pop("B"), case.pop("Tq"), case.pop("Tk")
-    Hq, Hkv, D = case.pop("Hq"), case.pop("Hkv"), case.pop("D")
-    key = jax.random.PRNGKey(0)
-    ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (B, Tq, Hq, D))
-    k = jax.random.normal(ks[1], (B, Tk, Hkv, D))
-    v = jax.random.normal(ks[2], (B, Tk, Hkv, D))
-    want = naive_attention(q, k, v, **case)
-    got = chunked_attention(q, k, v, q_chunk=16, k_chunk=16, **case)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_chunked_attention_kv_len_decode():
-    """Traced kv_len (decode against preallocated cache) masks the tail."""
-    key = jax.random.PRNGKey(1)
-    ks = jax.random.split(key, 3)
-    B, S, H, D = 2, 32, 2, 8
-    q = jax.random.normal(ks[0], (B, 1, H, D))
-    k = jax.random.normal(ks[1], (B, S, H, D))
-    v = jax.random.normal(ks[2], (B, S, H, D))
-    n = 20
-    got = chunked_attention(q, k, v, causal=True, q_offset=n - 1,
-                            kv_len=jnp.asarray(n), q_chunk=8, k_chunk=8)
-    want = naive_attention(q, k[:, :n], v[:, :n], causal=True,
-                           q_offset=n - 1)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-
-# ---------------------------------------------------------------------------
-# linear scan (mamba / rwkv backbone)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("B,T,D", [(1, 8, 16), (2, 33, 64), (3, 100, 128),
-                                   (2, 256, 384)])
-def test_linear_scan_matches_ref(B, T, D):
-    rng = np.random.RandomState(B * 100 + T)
-    a = jnp.asarray(rng.uniform(0.7, 1.0, (B, T, D)).astype(np.float32))
-    x = jnp.asarray(rng.standard_normal((B, T, D)).astype(np.float32))
-    h0 = jnp.asarray(rng.standard_normal((B, D)).astype(np.float32))
-    want = ref.linear_scan_ref(a, x, h0)
-    got, h_last = linear_scan(a, x, h0, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(h_last),
-                               np.asarray(want[:, -1], np.float32),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_linear_scan_zero_decay_is_cumsum():
-    B, T, D = 1, 16, 8
-    a = jnp.ones((B, T, D))
-    x = jnp.ones((B, T, D))
-    got, _ = linear_scan(a, x, None, interpret=True)
-    want = jnp.cumsum(x, axis=1)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)
-
-
-# ---------------------------------------------------------------------------
-# WKV: chunked-matmul (GLA-style) vs sequential scan
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("B,T,H,K", [(1, 8, 2, 4), (2, 19, 3, 8),
-                                     (1, 64, 2, 16)])
-def test_wkv_matmul_matches_scan(B, T, H, K):
-    from repro.models.rwkv import _wkv_chunks, _wkv_chunks_matmul
-    rng = np.random.RandomState(T)
-    V = K
-    r = jnp.asarray(rng.standard_normal((B, T, H, K)).astype(np.float32))
-    k = jnp.asarray(rng.standard_normal((B, T, H, K)).astype(np.float32))
-    v = jnp.asarray(rng.standard_normal((B, T, H, V)).astype(np.float32))
-    # decays within the clamp region (w >= e^-8), incl. strong decay
-    w = jnp.asarray(np.exp(-rng.uniform(0.001, 7.5, (B, T, H, K)))
-                    .astype(np.float32))
-    u = jnp.asarray(rng.standard_normal((H, K)).astype(np.float32))
-    s0 = jnp.asarray(rng.standard_normal((B, H, K, V)).astype(np.float32))
-    y1, s1 = _wkv_chunks(r, k, v, w, u, s0, chunk=16)
-    y2, s2 = _wkv_chunks_matmul(r, k, v, w, u, s0)
-    np.testing.assert_allclose(np.asarray(y2), np.asarray(y1),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(s2), np.asarray(s1),
-                               rtol=2e-4, atol=2e-4)
